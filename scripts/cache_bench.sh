@@ -15,12 +15,13 @@
 # cold_vs_warm_warmup down-bad) exits non-zero exactly like a
 # throughput one. See PERFORMANCE.md "Reading a cache bench".
 #
-# Usage: scripts/cache_bench.sh [cache_dir]
+# Usage: scripts/cache_bench.sh
+# (the cache lives at excache.cache_root(): $JAX_COMPILATION_CACHE_DIR
+# when set, else the checkout's .graftcache)
 set -euo pipefail
 cd "$(dirname "$0")/.."
 
 RUNS="${GRAFTSCOPE_RUNS:-runs.jsonl}"
-export GRAFTCACHE_DIR="${1:-${GRAFTCACHE_DIR:-.graftcache}}"
 
 JAX_PLATFORMS=cpu python bench.py --cache cold
 JAX_PLATFORMS=cpu python bench.py --cache warm

@@ -3,7 +3,7 @@
 #
 # Runs `bench.py --forge`: a COLD fleet+trainer start in a fresh
 # subprocess, the forge farm (`obs.forge.run_forge` worker pool)
-# populating the forge_smoke/ namespace of GRAFTCACHE_DIR, then the
+# populating the forge_smoke/ namespace of the cache root, then the
 # FORGED start in another fresh subprocess. The gate then (a) fails
 # loudly unless the forged arm performed ZERO fresh compiles
 # (engine_compiles all-zero AND train_cache_hit — the executable farm
@@ -15,12 +15,13 @@
 # exits non-zero exactly like a throughput one. See PERFORMANCE.md
 # "Reading a forge bench".
 #
-# Usage: scripts/forge_bench.sh [cache_dir]
+# Usage: scripts/forge_bench.sh
+# (the cache lives at excache.cache_root(): $JAX_COMPILATION_CACHE_DIR
+# when set, else the checkout's .graftcache)
 set -euo pipefail
 cd "$(dirname "$0")/.."
 
 RUNS="${GRAFTSCOPE_RUNS:-runs.jsonl}"
-export GRAFTCACHE_DIR="${1:-${GRAFTCACHE_DIR:-.graftcache}}"
 
 JAX_PLATFORMS=cpu python bench.py --forge
 

@@ -8,7 +8,7 @@ statically: a `BucketedEngine`/`SessionEngine` construction whose
 `traffic_bucket_ladder(...)`, an attribute read, arbitrary arithmetic)
 describes rungs the compile farm cannot enumerate — and a rung forge
 can't enumerate is a rung the farm can't warm: its first live request
-pays the 20-40 s tunnel compile the farm exists to kill.
+pays the compile the farm exists to kill.
 
 * `warmup-unforgeable` — an engine construction site whose `buckets=`
   value is not spec-derivable. Accepted as derivable: no `buckets=` at
@@ -146,7 +146,7 @@ engine_lib.register(engine_lib.Rule(
              "graftforge cannot enumerate those rungs from\n"
              "the config/specs, so the compile farm cannot\n"
              "warm them and their first live request pays\n"
-             "the 20-40 s tunnel compile; literal ladders,\n"
+             "the compile; literal ladders,\n"
              "bucket_ladder(...), module-level literal\n"
              "constants, and `**splat` sites are accepted\n"
              "(route live ladder changes through\n"
@@ -155,7 +155,7 @@ engine_lib.register(engine_lib.Rule(
                  "`buckets=` is computed at runtime — graftforge cannot "
                  "enumerate those rungs from the config/specs, so the "
                  "compile farm cannot warm them and their first live "
-                 "request pays the 20–40 s tunnel compile (literal "
+                 "request pays the compile (literal "
                  "ladders, `bucket_ladder(...)`, module-level literal "
                  "constants, and `**splat` sites accepted; route live "
                  "ladder changes through `ServingFleet.rollout("

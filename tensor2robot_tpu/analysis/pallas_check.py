@@ -1,23 +1,15 @@
-"""graftlint: Pallas kernel-tier fallback discipline.
+"""graftlint: Pallas kernel-tier interpret seam.
 
-Pallas is the one dependency tier this repo treats as OPTIONAL at every
-call site: kernels must degrade to their XLA reference composition when
-pallas cannot import (CPU-only deployments, toolchain skew) and must be
-runnable under `interpret=True` so CPU tier-1/bench exercise the real
-kernel body (`ops/attention.py`'s flash tier and `ops/decode_kernels.py`
-set the pattern — soft import + `pallas_available()` + an `interpret`
-seam). A `pl.pallas_call` added without that discipline turns every
-import of its module into a hard pallas dependency and every CPU run
-into a lowering error ("Only interpret mode is supported on CPU
-backend") instead of a measured fallback:
+Pallas ships inside the one jax this repo runs on, so kernel modules
+import it plainly (`ops/attention.py`, `ops/decode_kernels.py`). What a
+kernel still owes the CPU suite is an `interpret` seam, so CPU tier-1
+exercises the real kernel body instead of dying at lowering ("Only
+interpret mode is supported on CPU backend"):
 
 * `pallas-missing-fallback` — a `pallas_call(...)` /
-  `pl.pallas_call(...)` call site in a module that (a) imports pallas
-  UNGUARDED (no `try:`-wrapped import, so there is no XLA fallback seam
-  to take when the import fails), or (b) does not thread an
-  `interpret=` argument through the call (a `**splat` at the call site
-  is accepted — not statically analyzable), so CPU smoke cannot run the
-  kernel in interpreter mode.
+  `pl.pallas_call(...)` call site that does not thread an `interpret=`
+  argument through the call (a `**splat` at the call site is accepted —
+  not statically analyzable).
 
 Pure AST analysis, backend-free like every graftlint rule. Suppress
 with a trailing `# graftlint: disable=pallas-missing-fallback`.
@@ -37,44 +29,6 @@ __all__ = ["check_python_source", "check_python_file"]
 _RULE = "pallas-missing-fallback"
 
 
-def _is_pallas_import(node: ast.AST) -> bool:
-  """True for any statement that imports pallas (`import
-  jax.experimental.pallas ...`, `from jax.experimental import pallas`,
-  `from jax.experimental.pallas import tpu`)."""
-  if isinstance(node, ast.Import):
-    return any("pallas" in (alias.name or "") for alias in node.names)
-  if isinstance(node, ast.ImportFrom):
-    module = node.module or ""
-    if "pallas" in module:
-      return True
-    return module.startswith("jax.experimental") and any(
-        alias.name == "pallas" for alias in node.names)
-  return False
-
-
-def _has_guarded_pallas_import(tree: ast.Module) -> bool:
-  """True when every pallas import in the module sits under a `try:`
-  (the soft-import fallback seam); False when any is unguarded or when
-  the module never imports pallas at module scope (a function-local
-  import still raises at call time — same missing seam)."""
-  guarded = False
-  for node in ast.walk(tree):
-    if isinstance(node, ast.Try):
-      for stmt in ast.walk(node):
-        if _is_pallas_import(stmt):
-          guarded = True
-  # Any pallas import NOT inside a Try is unguarded.
-  trys = [n for n in ast.walk(tree) if isinstance(n, ast.Try)]
-  in_try = set()
-  for t in trys:
-    for stmt in ast.walk(t):
-      in_try.add(id(stmt))
-  for node in ast.walk(tree):
-    if _is_pallas_import(node) and id(node) not in in_try:
-      return False
-  return guarded
-
-
 def _is_pallas_call(func: ast.AST) -> bool:
   if isinstance(func, ast.Name):
     return func.id == "pallas_call"
@@ -87,21 +41,12 @@ def _check_tree(path: str, tree: ast.Module) -> List[Finding]:
   """Findings for one parsed module (shared by the standalone path and
   the engine's whole-tree check)."""
   findings: List[Finding] = []
-  guarded = _has_guarded_pallas_import(tree)
   for node in ast.walk(tree):
     if not (isinstance(node, ast.Call) and _is_pallas_call(node.func)):
       continue
     end = getattr(node, "end_lineno", node.lineno) or node.lineno
-    if not guarded:
-      findings.append(Finding(
-          path=path, line=node.lineno, rule=_RULE, end_line=end,
-          message=("pallas_call in a module without a try-guarded "
-                   "pallas import — there is no XLA fallback seam when "
-                   "pallas cannot import; soft-import pallas and gate "
-                   "the kernel tier on it (the ops/attention.py / "
-                   "ops/decode_kernels.py pattern)")))
-    elif not any(kw.arg == "interpret" or kw.arg is None
-                 for kw in node.keywords):
+    if not any(kw.arg == "interpret" or kw.arg is None
+               for kw in node.keywords):
       findings.append(Finding(
           path=path, line=node.lineno, rule=_RULE, end_line=end,
           message=("pallas_call without an `interpret=` seam — CPU "
@@ -132,13 +77,9 @@ engine_lib.register(engine_lib.Rule(
     name="pallas", kind="py", scope=".py", family="pallas",
     infos=(engine_lib.RuleInfo(
         id=_RULE,
-        doc=("a `pallas_call` site lacks the kernel-tier\n"
-             "fallback discipline: its module imports pallas\n"
-             "unguarded (no XLA fallback seam when the import\n"
-             "fails) or the call threads no `interpret=` seam\n"
-             "(CPU smoke cannot run the kernel body);\n"
+        doc=("a `pallas_call` site threads no `interpret=`\n"
+             "seam (CPU tier-1 cannot run the kernel body);\n"
              "a `**splat` call site is accepted"),
-        meaning=("a `pallas_call` site has no XLA fallback seam or no "
-                 "`interpret=` guard for CPU runs (`**splat` "
-                 "accepted)")),),
+        meaning=("a `pallas_call` site has no `interpret=` seam for "
+                 "CPU runs (`**splat` accepted)")),),
     check=lambda ctx: _check_tree(ctx.path, ctx.tree)))

@@ -37,7 +37,7 @@ order and the assembler consumes them FIFO), so the overlapped loader
 is BYTE-IDENTICAL to the serial chain over the same record stream —
 tests/test_overlap.py pins that, eval mode included. The device-side
 consumer is `parallel.mesh.DevicePrefetcher`, which keeps its
-tunnel-safe close/phase discipline; every stage here is host-only and
+joining close/phase discipline; every stage here is host-only and
 therefore safe to stop at any point.
 
 Thread discipline (mechanized by the graftlint `thread-stage-*` rules):

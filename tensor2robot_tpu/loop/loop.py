@@ -53,6 +53,7 @@ from tensor2robot_tpu.loop import actor as actor_lib
 from tensor2robot_tpu.loop import publish as publish_lib
 from tensor2robot_tpu.loop import replay as replay_lib
 from tensor2robot_tpu.loop import supervisor as supervisor_lib
+from tensor2robot_tpu.obs import excache as excache_lib
 from tensor2robot_tpu.obs import graftrace
 from tensor2robot_tpu.obs import metrics as obs_metrics
 from tensor2robot_tpu.obs import runlog as runlog_lib
@@ -120,10 +121,10 @@ class GraftLoop:
     # deserialize one forged entry set) and the learner's train rounds.
     # `graftscope forge configs/loop_qtopt.gin --model-dir <dir>`
     # populates it BEFORE the loop starts, so the first serve and the
-    # first round both start compile-free ("auto" = <model_dir>/excache,
+    # first round both start compile-free ("auto" = excache.cache_root(),
     # the same resolution train_eval uses; None/"" disables).
     if executable_cache_dir == "auto":
-      executable_cache_dir = os.path.join(self._model_dir, "excache")
+      executable_cache_dir = excache_lib.cache_root()
     self._executable_cache_dir = executable_cache_dir or None
     # BEFORE any replica is built: CheckpointPredictor resolves its
     # polling directory at construction — if `<model_dir>/checkpoints`
@@ -408,11 +409,8 @@ class GraftLoop:
           max_train_steps=target,
           checkpoint_every_n_steps=self._steps_per_round,
           log_every_n_steps=1,
-          # The loop-wide cache (graftforge seam): the round's train
-          # step rides whatever tiers the toolchain admits — gated to
-          # counters-only while excache.DONATING_MESH_SAFE_FROM is
-          # unset (the donating-mesh step skips both tiers on this
-          # jax), compile-free rounds the moment the pin flips.
+          # The loop-wide cache (graftforge seam): later rounds load
+          # the round-1 train step instead of compiling it again.
           executable_cache_dir=self._executable_cache_dir,
           mesh_shape=(1, 1, 1),
           reset_run_telemetry=False,

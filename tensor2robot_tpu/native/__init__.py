@@ -4,15 +4,22 @@
 used by the data layer; `batch_stager.cc` the GIL-free batched record
 staging plane (interleave + shuffle + batch assembly on worker threads);
 `example_parser.cc` the columnar Example parser. The shared library is
-built on first import with g++ (cached next to the source); every caller
-has a pure-Python fallback, so environments without a toolchain still
-work.
+built on first use with g++ from the committed sources into a gitignored
+`libt2r_native-<hash>.so` beside them, where `<hash>` is a content hash
+of those sources: a changed source builds a new library, and a copy of
+the tree (which keeps no mtimes and no `.so`) builds its own. Where
+there is no g++ every caller has a pure-Python fallback (`load()`
+returns None, logged once); a g++ that REFUSES the committed sources is
+a bug and raises. `require()` is for callers that must not fall back.
 """
 
 from __future__ import annotations
 
 import ctypes
+import glob
+import hashlib
 import os
+import shutil
 import subprocess
 import threading
 from typing import Iterator, List, Optional
@@ -23,50 +30,93 @@ _SOURCES = [os.path.join(_DIR, "tfrecord_io.cc"),
             os.path.join(_DIR, "batch_stager.cc")]
 _JPEG_SOURCE = os.path.join(_DIR, "jpeg_decode.cc")
 _HEADERS = [os.path.join(_DIR, "record_framing.h")]
-_LIB_PATH = os.path.join(_DIR, "libt2r_native.so")
 _LOCK = threading.Lock()
 _LIB: Optional[ctypes.CDLL] = None
-_LOAD_FAILED = False
+_LOAD_ERROR: Optional[str] = None
+_NO_TOOLCHAIN = "g++ not found"
 
 
-def _build() -> bool:
+class NativeBuildError(RuntimeError):
+  """The native library could not be built or loaded."""
+
+
+def _lib_path() -> str:
+  """`<dir>/libt2r_native-<content hash of the sources>.so`."""
+  digest = hashlib.sha256()
+  for src in [*_SOURCES, _JPEG_SOURCE, *_HEADERS]:
+    digest.update(os.path.basename(src).encode())
+    with open(src, "rb") as f:
+      digest.update(f.read())
+  return os.path.join(os.path.dirname(_SOURCES[0]),
+                      f"libt2r_native-{digest.hexdigest()[:16]}.so")
+
+
+def _build(lib_path: str) -> Optional[str]:
+  """Builds `lib_path`; returns None, or the compiler's complaint."""
   # Preferred build includes the libjpeg-backed batch decoder; if the
   # toolchain lacks jpeglib.h / -ljpeg, fall back to building without it
   # (the reader/parser/stager fast paths must not depend on libjpeg).
   # -lpthread in BOTH attempts: the stager spawns std::threads.
+  # Built under a per-process name and renamed into place, so two
+  # processes building at once (test workers) each publish a whole file.
+  tmp = f"{lib_path}.tmp.{os.getpid()}"
   base = ["g++", "-O3", "-shared", "-fPIC", "-std=c++17"]
   attempts = [
-      base + [*_SOURCES, _JPEG_SOURCE, "-o", _LIB_PATH, "-ljpeg",
-              "-lpthread"],
-      base + [*_SOURCES, "-o", _LIB_PATH, "-lpthread"],
+      base + [*_SOURCES, _JPEG_SOURCE, "-o", tmp, "-ljpeg", "-lpthread"],
+      base + [*_SOURCES, "-o", tmp, "-lpthread"],
   ]
+  error = None
   for cmd in attempts:
     try:
       subprocess.run(cmd, check=True, capture_output=True, timeout=180)
-      return True
-    except Exception:
+    except subprocess.CalledProcessError as e:
+      error = e.stderr.decode(errors="replace")[-2000:]
       continue
-  return False
+    except (OSError, subprocess.TimeoutExpired) as e:
+      error = f"{type(e).__name__}: {e}"
+      continue
+    os.replace(tmp, lib_path)
+    for stale in glob.glob(os.path.join(os.path.dirname(lib_path),
+                                        "libt2r_native*.so")):
+      if stale != lib_path:
+        try:
+          os.unlink(stale)
+        except OSError:
+          pass
+    return None
+  return error
 
 
 def load() -> Optional[ctypes.CDLL]:
-  """Returns the native library, building it if needed; None if
-  unavailable."""
-  global _LIB, _LOAD_FAILED
+  """Returns the native library, building it if its sources changed.
+  None (logged once) where there is no g++; raises `NativeBuildError`
+  when g++ refuses the committed sources or the result does not load."""
+  global _LIB, _LOAD_ERROR
   with _LOCK:
-    if _LIB is not None or _LOAD_FAILED:
+    if _LIB is not None:
       return _LIB
-    if not os.path.isfile(_LIB_PATH) or any(
-        os.path.getmtime(_LIB_PATH) < os.path.getmtime(src)
-        for src in [*_SOURCES, _JPEG_SOURCE, *_HEADERS]):
-      if not _build():
-        _LOAD_FAILED = True
-        return None
-    try:
-      lib = ctypes.CDLL(_LIB_PATH)
-    except OSError:
-      _LOAD_FAILED = True
+    if _LOAD_ERROR == _NO_TOOLCHAIN:
       return None
+    if _LOAD_ERROR is not None:
+      raise NativeBuildError(_LOAD_ERROR)
+    lib_path = _lib_path()
+    if not os.path.isfile(lib_path):
+      if shutil.which("g++") is None:
+        from absl import logging
+
+        _LOAD_ERROR = _NO_TOOLCHAIN
+        logging.warning("native: no g++ on this machine; the data layer "
+                        "runs its pure-Python paths.")
+        return None
+      error = _build(lib_path)
+      if error is not None:
+        _LOAD_ERROR = f"g++ could not build {lib_path}:\n{error}"
+        raise NativeBuildError(_LOAD_ERROR)
+    try:
+      lib = ctypes.CDLL(lib_path)
+    except OSError as e:
+      _LOAD_ERROR = f"cannot load {lib_path}: {e}"
+      raise NativeBuildError(_LOAD_ERROR) from e
     lib.t2r_crc32c.restype = ctypes.c_uint32
     lib.t2r_crc32c.argtypes = [ctypes.c_char_p, ctypes.c_int64]
     lib.t2r_masked_crc32c.restype = ctypes.c_uint32
@@ -148,6 +198,14 @@ def available() -> bool:
   return load() is not None
 
 
+def require() -> ctypes.CDLL:
+  """`load()` for callers with no fallback: raises where it gives None."""
+  lib = load()
+  if lib is None:
+    raise NativeBuildError(_LOAD_ERROR or "native library unavailable")
+  return lib
+
+
 def masked_crc32c(data: bytes) -> Optional[int]:
   lib = load()
   if lib is None:
@@ -219,9 +277,9 @@ class RecordStager:
   (the arena is copied out of the native buffer in ONE memcpy and owned
   by Python), or None at end of stream. Corruption/IO failures raise
   IOError, matching both `iter_records` paths. `close()` (or `with`)
-  stops and JOINS the worker threads — the tunnel-safety discipline of
-  CLAUDE.md applies to any thread owner, and an abandoned stager would
-  leak readers blocked on full queues.
+  stops and JOINS the worker threads — every thread owner joins what it
+  started, and an abandoned stager would leak readers blocked on full
+  queues.
 
   Telemetry (`data/stage_ms` etc.) lives one level up in
   `data/stager.py`; this class stays a thin ctypes seam.

@@ -217,30 +217,6 @@ def analyze_jit(name: str, fn, *args,
   t1 = time.perf_counter()
 
   cache_key = None
-  cache_unsafe = False
-  unsafe_guard_error = False
-  try:
-    # Donating multi-device executables must not be DESERIALIZED on
-    # this jax at all — from the serialized-AOT tier (measured heap
-    # corruption, excache.aot_cache_unsafe) NOR from the XLA persistent
-    # compilation cache: a donating NamedSharding executable served out
-    # of a warm XLA cache and fed device_put/orbax-restored arrays
-    # SIGSEGVs the same way (measured: the trainer resume path —
-    # run 1 fills the cache, run 2 restores a checkpoint and crashes
-    # on its first dispatch). Such steps always compile fresh, with
-    # the XLA tier bypassed for exactly that compile.
-    cache_unsafe = excache_lib.aot_cache_unsafe(traced, args)
-  except Exception:  # noqa: BLE001 - guard trouble = no caching
-    cache_unsafe = True
-    unsafe_guard_error = True
-  if cache_unsafe and cache is not None:
-    # Distinct counters: a BROKEN guard must not read as "donated-mesh
-    # executable skipped" in runs.jsonl — they send a diff reader down
-    # entirely different trails.
-    reg.counter("cache/unsafe_guard_error" if unsafe_guard_error
-                else "cache/skipped_donated_mesh").inc()
-  if cache_unsafe:
-    cache = None
   if cache is not None:
     try:
       cache_key = excache_lib.cache_key(
@@ -277,13 +253,11 @@ def analyze_jit(name: str, fn, *args,
 
   lowered = traced.lower()
   t2 = time.perf_counter()
-  if (cache is not None and cache_key is not None) or cache_unsafe:
-    # Two reasons to compile WITHOUT the XLA persistent cache: an
-    # AOT-tier miss about to be stored (the artifact may come out of
-    # that cache non-serializable and the entry could never (re)fill —
-    # see excache.xla_cache_bypassed), and a donating-mesh executable
-    # (an XLA-cache LOAD of one heap-corrupts this jax — see the
-    # cache_unsafe guard above).
+  if cache is not None and cache_key is not None:
+    # An AOT-tier miss about to be stored compiles WITHOUT the XLA
+    # persistent cache: an executable served out of that cache does not
+    # survive the serialize round-trip, so the entry could never
+    # (re)fill — see excache.xla_cache_bypassed.
     with excache_lib.xla_cache_bypassed():
       compiled = lowered.compile()
   else:

@@ -27,13 +27,13 @@ from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
 from jax.sharding import Mesh, PartitionSpec
 
 from tensor2robot_tpu.parallel import mesh as mesh_lib
 
 __all__ = ["attention", "cached_attention", "flash_attention",
-           "ring_attention", "ulysses_attention",
-           "note_pallas_unavailable"]
+           "ring_attention", "ulysses_attention"]
 
 
 def _mask_value(dtype) -> jnp.ndarray:
@@ -264,43 +264,6 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
   dv_ref[:] = dv.astype(dv_ref.dtype)
 
 
-try:  # Pallas import kept soft so CPU-only deployments still import us.
-  from jax.experimental import pallas as pl
-  from jax.experimental.pallas import tpu as pltpu  # noqa: F401
-
-  _HAS_PALLAS = True
-  _PALLAS_IMPORT_ERROR: Optional[str] = None
-except Exception as _pallas_import_exc:  # pragma: no cover
-  _HAS_PALLAS = False
-  _PALLAS_IMPORT_ERROR = (f"{type(_pallas_import_exc).__name__}: "
-                          f"{_pallas_import_exc}")
-
-# Sites that already emitted their one-time pallas-unavailable warning
-# (the use_native_stager discipline: a silent capability degrade is a
-# debugging trap — the flash tier used to fall back to the O(T^2)
-# reference with no trace of WHY).
-_PALLAS_WARNED_SITES = set()
-
-
-def note_pallas_unavailable(site: str) -> None:
-  """Records one pallas-unavailable degrade: bumps the
-  `ops/pallas_unavailable` counter every time and WARNs once per call
-  site with the captured import error, so a fleet quietly serving the
-  reference fallback is visible in metrics and logs instead of only in
-  its latency."""
-  from tensor2robot_tpu.obs import metrics as obs_metrics
-
-  obs_metrics.counter("ops/pallas_unavailable").inc()
-  if site not in _PALLAS_WARNED_SITES:
-    _PALLAS_WARNED_SITES.add(site)
-    from absl import logging
-
-    logging.warning(
-        "%s: pallas kernel tier unavailable (%s); falling back to the "
-        "XLA reference implementation.", site,
-        _PALLAS_IMPORT_ERROR or "import failed")
-
-
 def _flash_forward(q3, k3, v3, causal, block_q, block_k, valid_len,
                    interpret):
   bh, t, d = q3.shape
@@ -451,9 +414,6 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     auto_bq, auto_bk = _default_blocks(t)
     block_q = auto_bq if block_q is None else block_q
     block_k = auto_bk if block_k is None else block_k
-  if not _HAS_PALLAS:
-    note_pallas_unavailable("flash_attention")
-    return attention(q, k, v, causal=causal)
   if k.shape[2] != t:
     return attention(q, k, v, causal=causal)
   if interpret is None:

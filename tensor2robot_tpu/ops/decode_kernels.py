@@ -29,8 +29,7 @@ autoregressive caching is the blueprint):
   their masked write lands the OLD row value on the null slot.
 * `reference_decode_attention` — the XLA composition
   (gather -> `.at[rows, index].set` append -> `cached_attention` ->
-  masked scatter) the kernel is numerics-pinned against; also the
-  fallback when Pallas is unavailable.
+  masked scatter) the kernel is numerics-pinned against.
 
 Numerics contract: identical unmasked score set as `cached_attention`
 over the post-append cache (arena positions strictly below the lane's
@@ -54,33 +53,12 @@ from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from tensor2robot_tpu.ops import attention as attention_ops
 
-__all__ = ["pallas_available", "pallas_unavailable_reason",
-           "fused_decode_attention", "reference_decode_attention"]
-
-try:  # Soft import: CPU-only deployments must still import this module.
-  from jax.experimental import pallas as pl
-  from jax.experimental.pallas import tpu as pltpu
-
-  _HAS_PALLAS = True
-  _PALLAS_IMPORT_ERROR: Optional[str] = None
-except Exception as e:  # pragma: no cover - depends on the installed jax
-  _HAS_PALLAS = False
-  _PALLAS_IMPORT_ERROR = f"{type(e).__name__}: {e}"
-
-
-def pallas_available() -> bool:
-  """True when the Pallas kernel tier can lower at all (import worked)."""
-  return _HAS_PALLAS
-
-
-def pallas_unavailable_reason() -> Optional[str]:
-  """Why `pallas_available()` is False (None when it is True) — the
-  engine's auto-gate surfaces this instead of silently degrading."""
-  return _PALLAS_IMPORT_ERROR
-
+__all__ = ["fused_decode_attention", "reference_decode_attention"]
 
 def reference_decode_attention(q: jnp.ndarray, k_new: jnp.ndarray,
                                v_new: jnp.ndarray, k_arena: jnp.ndarray,
@@ -219,13 +197,8 @@ def fused_decode_attention(q: jnp.ndarray, k_new: jnp.ndarray,
 
   Returns (out [B, H, D], k_arena', v_arena') with the arenas updated
   only at each live lane's (slot, index) row — alias-updated in place
-  when the caller donates them. Falls back to the XLA reference
-  composition when Pallas is unavailable (`pallas_available()`).
+  when the caller donates them.
   """
-  if not _HAS_PALLAS:
-    attention_ops.note_pallas_unavailable("fused_decode_attention")
-    return reference_decode_attention(q, k_new, v_new, k_arena, v_arena,
-                                      slots, index, mask)
   if interpret is None:
     # Resolve from the PROCESS backend at trace time. The serving
     # engine compiles its dispatch for the backend it executes on, so

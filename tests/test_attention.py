@@ -411,6 +411,7 @@ class TestSequenceParallelTrainStep:
     first = None
     for _ in range(30):
       state, metrics = step(state, f, l)
+      jax.block_until_ready(metrics)  # see conftest.py: one step in flight
       first = first if first is not None else float(metrics["loss"])
     assert np.isfinite(float(metrics["loss"]))
     assert float(metrics["loss"]) < first, (first, float(metrics["loss"]))

@@ -58,14 +58,6 @@ def _obs_seq(batch, seq_len, obs_size, seed=0):
       batch, seq_len, obs_size).astype(np.float32)
 
 
-def _require_pallas():
-  from tensor2robot_tpu.ops import decode_kernels as dk
-
-  if not dk.pallas_available():
-    pytest.skip(f"pallas unavailable: {dk.pallas_unavailable_reason()}")
-  return dk
-
-
 # ---------------------------------------------------------------------------
 # Kernel-level parity: fused vs the XLA reference composition.
 # ---------------------------------------------------------------------------
@@ -81,7 +73,8 @@ class TestFusedKernelParity:
     three outputs."""
     import jax.numpy as jnp
 
-    dk = _require_pallas()
+    from tensor2robot_tpu.ops import decode_kernels as dk
+
     s, b, h, d = 5, 3, 2, 4
     rs = np.random.RandomState(t * 31 + block_k)
     k_arena0 = rs.randn(s, t, h, d).astype(np.float32)
@@ -112,7 +105,8 @@ class TestFusedKernelParity:
     (duplicate writes through slot 0 are idempotent)."""
     import jax.numpy as jnp
 
-    dk = _require_pallas()
+    from tensor2robot_tpu.ops import decode_kernels as dk
+
     s, t, h, d = 3, 8, 2, 4
     rs = np.random.RandomState(7)
     k_arena0 = rs.randn(s, t, h, d).astype(np.float32)
@@ -148,7 +142,6 @@ class TestEngineKernelParity:
     forced-jitted engine and the stateless full-prefix forward at EVERY
     step, including padded partial buckets (3 live lanes in the
     4-bucket) and the horizon edge."""
-    _require_pallas()
     predictor = _make_predictor(sequence_length=t, **SEQ_BASE)
     with metrics_lib.isolated():
       kern = serving.SessionEngine(predictor=predictor, max_sessions=4,
@@ -195,7 +188,6 @@ class TestEngineKernelParity:
     """Open/step/close churn under slot pressure (evictions included)
     never grows the kernel engine's compile count past the warmed
     ladder, and nothing falls back to the plain jit."""
-    _require_pallas()
     predictor = _make_predictor(sequence_length=8, **SEQ_BASE)
     with metrics_lib.isolated():
       engine = serving.SessionEngine(predictor=predictor, max_sessions=3,
@@ -228,7 +220,6 @@ class TestEngineKernelParity:
     (no re-warm), and a fresh session matches the stateless forward
     under the NEW params — params flow through the dispatch's state
     argument, never the kernel closure."""
-    _require_pallas()
     import jax
 
     predictor = _make_predictor(sequence_length=8, **SEQ_BASE)
@@ -267,7 +258,6 @@ class TestEngineKernelParity:
     zero compiles, full loads, serving parity) and never cross-load
     into an xla-arm engine sharing the cache dir — the `pallas` key
     component keeps the two dispatch families distinct."""
-    _require_pallas()
     cache_dir = str(tmp_path / "excache")
     predictor = _make_predictor(sequence_length=8, **SEQ_BASE)
     with metrics_lib.isolated():
@@ -319,7 +309,6 @@ class TestDecodeKernelGate:
 
     if jax.default_backend() == "tpu":
       pytest.skip("auto resolves ON on a real TPU backend")
-    _require_pallas()
     predictor = _make_predictor(sequence_length=8, **SEQ_BASE)
     with metrics_lib.isolated():
       engine = serving.SessionEngine(predictor=predictor, max_sessions=2,
@@ -378,17 +367,14 @@ from tensor2robot_tpu.serving import session as session_lib
 def boom():
     raise AssertionError("backend thunk invoked on a forced path")
 
-assert session_lib.resolve_decode_kernel(False, True, None, True, boom)[0] \\
-    is False
-assert session_lib.resolve_decode_kernel(True, True, None, True, boom) \\
-    == (True, "on")
-assert session_lib.resolve_decode_kernel(None, False, "no pallas", True,
-                                         boom)[0] is False
-assert session_lib.resolve_decode_kernel(None, True, None, False,
-                                         boom)[1].startswith(
+assert session_lib.resolve_decode_kernel(False, True, boom)[0] is False
+assert session_lib.resolve_decode_kernel(True, True, boom) == (True, "on")
+assert session_lib.resolve_decode_kernel(None, False, boom)[1].startswith(
     "model-unsupported")
 assert session_lib.resolve_decode_kernel(
-    None, True, None, True, lambda: False)[1].startswith("auto-off")
+    None, True, lambda: False)[1].startswith("auto-off")
+assert session_lib.resolve_decode_kernel(
+    None, True, lambda: True) == (True, "on")
 
 # decode_kernel_mode on a backend-free bundle: binds + resolves with no
 # device work (auto + no arena seam declines before the backend thunk).

@@ -460,7 +460,9 @@ class TestFleetProbation:
     kwargs.setdefault("max_delay_s", 0.05)
     return retry_lib.RetryPolicy(**kwargs)
 
-  def _wait_healthy(self, fleet, want, timeout_s=5.0):
+  def _wait_healthy(self, fleet, want, timeout_s=60.0):
+    # The deadline only bounds a FAILURE (success returns at once): it
+    # must outlast thread starvation under six test workers.
     deadline = time.monotonic() + timeout_s
     while time.monotonic() < deadline:
       if len(fleet.healthy_replicas()) >= want:
@@ -599,7 +601,7 @@ class TestFleetProbation:
           predict=fleet.predict, make_request=lambda i: X1,
           num_arrivals=600, rate_hz=1500.0, profile="poisson", seed=3,
           max_client_threads=16)
-      chaos.join(timeout=10.0)
+      chaos.join(timeout=120.0)  # bounds a failure, see _wait_healthy
       assert not chaos.is_alive()
       assert outcome["evicted"] == fleet_lib.UNHEALTHY
       assert outcome["tick_ok"]

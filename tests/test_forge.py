@@ -1,7 +1,7 @@
 """Tests for graftforge (`obs/forge.py`): the ahead-of-time compile
-farm, its `graftscope forge` CLI, the version-keyed donating-mesh
-un-gate probe, the warmup load/compile split, the rollout ladder
-pre-forge, and the `warmup-unforgeable` lint rule.
+farm, its `graftscope forge` CLI, the donating-mesh round trip, the
+warmup load/compile split, the rollout ladder pre-forge, and the
+`warmup-unforgeable` lint rule.
 
 Contracts (ISSUE 15):
 
@@ -9,17 +9,16 @@ Contracts (ISSUE 15):
   lists every executable a research config deploys (bucket rungs x
   replicas, decode rungs + slot reset, train/eval steps with
   num_virtual_stages) without building a model or touching a backend,
-  and targets the toolchain gates are enumerated as unforgeable with
-  the reason attached;
+  and plain-jit targets are enumerated as unforgeable with the reason
+  attached;
 * a forge entry is BYTE-IDENTICAL in key to what the live process
   computes: process A runs `graftscope forge` against an empty cache,
   process B builds the fleet and pins `engine_compiles == [0, 0]`,
   `cache_loads == ladder x replicas`, served-output parity vs a
   cold-built fleet, and every loaded key present in the manifest;
-* the jax-0.4.37 donating-mesh skip is a VERSION-KEYED guard behind the
-  single `excache.DONATING_MESH_SAFE_FROM` pin — flipping that one
-  constant promotes the gated train targets and re-admits both cache
-  tiers together;
+* an executable that DONATES mesh-sharded inputs — the trainer's step —
+  round-trips through the cache and dispatches on placed arrays, so
+  train targets are forgeable;
 * `warmup_ms` splits into `warmup_load_ms`/`warmup_compile_ms` with
   per-rung provenance, so a forge regression is attributable;
 * `rollout(ladder=...)` pre-forges new rungs inside the drained window
@@ -116,24 +115,22 @@ class TestPlanEnumeration:
     assert serve["num_replicas"] == 2
     assert serve["placed"] is False
     train = families["train"]
-    assert train["forgeable"] is False  # gated on this jax
-    assert "donating-mesh" in train["reason"]
+    assert train["forgeable"] is True
     assert train["mesh_shape"] == [1, 1, 1]
     assert plan["model"] == {"kind": "configurable",
                              "name": "PoseEnvContinuousMCModel"}
 
-  def test_pipelined_train_plan_enumerated_but_gated(self):
+  def test_pipelined_train_plan_enumerated(self):
     plan = forge.plan_from_config([_cfg("train_pipelined_1f1b.gin")])
     (train,) = plan["targets"]
     assert train["family"] == "train"
     assert train["num_virtual_stages"] == 2  # the 1F1B chunking
     assert train["mesh_shape"] == [2, 4, 1]
-    assert train["forgeable"] is False
-    assert "DONATING_MESH_SAFE_FROM" in train["reason"]
+    assert train["forgeable"] is True
     assert plan["model"] == {"kind": "configurable",
                              "name": "PipelinedRegressionModel"}
     rendered = forge.format_plan(plan)
-    assert "UNFORGEABLE" in rendered and "v=2" in rendered
+    assert "UNFORGEABLE" not in rendered and "v=2" in rendered
 
   def test_unbound_mesh_shape_records_default_not_single_device(self):
     # train_eval builds the all-devices default mesh when mesh_shape is
@@ -178,76 +175,79 @@ class TestPlanEnumeration:
 
 
 # ---------------------------------------------------------------------------
-# Satellite: the version-keyed donating-mesh un-gate probe.
+# Satellite: the donating-mesh round trip (no gate on jax 0.9.0).
 # ---------------------------------------------------------------------------
 
 
-class TestDonatingMeshGate:
+class TestDonatingMeshRoundTrip:
+  """The repro the old version-keyed gate documented — (1) a serialize
+  round-trip of an executable that (2) DONATES inputs with a mesh-typed
+  sharding, (3) dispatched on device_put arrays — run in-suite: on
+  jax 0.9.0 it is clean, so the gate is gone and these executables are
+  cached like any other."""
 
-  def test_gate_active_while_pin_unset(self):
-    assert excache.DONATING_MESH_SAFE_FROM is None
-    assert excache.donating_mesh_cache_unsafe("0.4.37") is True
-    assert excache.donating_mesh_cache_unsafe("0.5.0") is True
-
-  def test_one_constant_flip_ungates_by_version(self, monkeypatch):
-    monkeypatch.setattr(excache, "DONATING_MESH_SAFE_FROM", "0.4.38")
-    assert excache.donating_mesh_cache_unsafe("0.4.37") is True
-    assert excache.donating_mesh_cache_unsafe("0.4.38") is False
-    assert excache.donating_mesh_cache_unsafe("0.4.38.dev1") is False
-    assert excache.donating_mesh_cache_unsafe("0.5.0") is False
-
-  def test_version_parse_lenient(self):
-    assert excache._version_tuple("0.4.37") == (0, 4, 37)
-    assert excache._version_tuple("0.5.0.dev1") == (0, 5, 0)
-    assert excache._version_tuple("garbage") == ()
-    # Unparseable stays gated — never un-gate by accident.
-    assert excache.donating_mesh_cache_unsafe("garbage") is True
-
-  def test_repro_conditions_documented_and_guard_consults_pin(
-      self, monkeypatch):
-    """THE standing jax-0.4.37 repro, mechanized as the guard's input
-    (ROADMAP item 5 / excache.DONATING_MESH_SAFE_FROM).
-
-    Repro conditions (measured on this host, jax 0.4.37 — do NOT run
-    the crash in-suite): (1) serialize_executable round-trip OR
-    XLA-persistent-cache load of an executable that (2) DONATES at
-    least one input whose sharding is mesh-typed (NamedSharding — even
-    a trivial (1,)-mesh), then (3) dispatch it on device_put/orbax-
-    restored arrays -> "corrupted double-linked list" / SIGSEGV.
-    Non-donating executables and SingleDeviceSharding donation are
-    stable over hundreds of calls. When a newer toolchain passes this
-    repro, set DONATING_MESH_SAFE_FROM to its version: this test pins
-    that the guard then admits exactly these executables, so the
-    existing per-component key-sensitivity tests re-verify both cache
-    tiers together."""
+  @pytest.mark.parametrize("n_devices", [1, 8])
+  def test_donating_mesh_executable_round_trips(self, tmp_path, n_devices):
     import jax
+    import jax.numpy as jnp
+
+    from tensor2robot_tpu.obs import xray
 
     mesh = jax.sharding.Mesh(
-        np.array(jax.devices()[:1]).reshape(1), ("data",))
-    sharding = jax.sharding.NamedSharding(mesh,
-                                          jax.sharding.PartitionSpec())
-    donated = jax.device_put(np.ones((4, 4), np.float32), sharding)
-    fn = jax.jit(lambda a: a + 1.0, donate_argnums=(0,))
-    traced = fn.trace(donated)
-    # Gate active (pin unset): the donating-mesh executable must skip
-    # the serialized tier.
-    assert excache.aot_cache_unsafe(traced, (donated,)) is True
-    # The un-gate: one constant at (or below) the running jax admits it.
-    monkeypatch.setattr(excache, "DONATING_MESH_SAFE_FROM",
-                        jax.__version__)
-    assert excache.aot_cache_unsafe(traced, (donated,)) is False
+        np.array(jax.devices()[:n_devices]).reshape(n_devices), ("data",))
+    repl = jax.sharding.NamedSharding(mesh, jax.sharding.PartitionSpec())
+    rows = jax.sharding.NamedSharding(mesh,
+                                      jax.sharding.PartitionSpec("data"))
+    fn = jax.jit(lambda w, x: (w - 0.1 * jnp.mean(x, axis=0), jnp.sum(x)),
+                 in_shardings=(repl, rows), out_shardings=(repl, repl),
+                 donate_argnums=(0,))
+    x = jax.device_put(np.ones((8, 4), np.float32), rows)
+    place = lambda: jax.device_put(np.zeros((4,), np.float32), repl)
+    cache = excache.ExecutableCache(str(tmp_path / "exc"))
+    _, cold = xray.analyze_jit("step", fn, place(), x, cache=cache)
+    assert cold["cache"]["stored"] is True
+    loaded, warm = xray.analyze_jit("step", fn, place(), x, cache=cache)
+    assert warm["cache"]["hit"] is True
+    w = place()
+    for _ in range(20):
+      w, total = loaded(w, x)  # each call donates the last result
+      jax.block_until_ready(w)  # see conftest.py: one step in flight
+    np.testing.assert_allclose(np.asarray(w), -2.0, rtol=1e-5)
+    assert float(total) == 32.0
 
-  def test_plan_promotes_gated_train_targets_on_ungate(self, monkeypatch):
-    monkeypatch.setattr(excache, "DONATING_MESH_SAFE_FROM", "0.0.1")
+  @pytest.mark.parametrize("device_index", [0, 3])
+  def test_loaded_executable_keeps_its_devices(self, tmp_path,
+                                               device_index):
+    """jax 0.9.0 loads over every local device unless told otherwise:
+    the sidecar records the executable's own devices, so a one-device
+    executable loads — and runs — on that device in an 8-device
+    process."""
+    import jax
+
+    from tensor2robot_tpu.obs import xray
+
+    device = jax.devices()[device_index]
+    fn = jax.jit(lambda a: a * 2.0)
+    a = jax.device_put(np.ones((4,), np.float32), device)
+    cache = excache.ExecutableCache(str(tmp_path / "exc"))
+    _, cold = xray.analyze_jit("double", fn, a, cache=cache)
+    (entry,) = cache.entries()
+    assert entry["device_ids"] == [device.id]
+    loaded, warm = xray.analyze_jit("double", fn, a, cache=cache)
+    assert warm["cache"]["hit"] is True
+    out = loaded(a)
+    assert out.devices() == {device}
+    np.testing.assert_allclose(np.asarray(out), 2.0)
+
+  def test_train_targets_are_forgeable(self):
     plan = forge.plan_from_config([_cfg("train_pipelined_1f1b.gin")])
     (train,) = plan["targets"]
     assert train["forgeable"] is True
     assert "reason" not in train
 
   def test_train_worker_keys_the_loop_scan_not_the_plain_step(self):
-    """The un-gated future's program-identity pin: a `loop_k` target
-    must trace `make_train_loop`'s [K, B] scan (trace-only verify path
-    — the gate never matters for key computation), which keys
+    """The program-identity pin: a `loop_k` target must trace
+    `make_train_loop`'s [K, B] scan (trace-only verify path), which keys
     DIFFERENTLY from the plain step; forging the plain step under the
     loop name would store an entry the live trainer never looks up."""
     import tensor2robot_tpu.utils.mocks  # noqa: F401 - registers the model
@@ -410,7 +410,7 @@ cache_dir = sys.argv[1]
 
 def make_fleet(cache):
   def make_replica(index, group):
-    model = flagship.make_flagship_model("cpu")
+    model = flagship.make_flagship_model("cpu", smoke=True)
     p = predictors_lib.CheckpointPredictor(model=model,
                                            model_dir="/nonexistent")
     p.init_randomly()
@@ -597,7 +597,8 @@ class TestForgeCLI:
 
   def test_plan_exits_zero_and_prints_enumeration(self, capsys):
     assert graftscope.main(
-        ["forge", _cfg("train_pipelined_1f1b.gin"), "--plan"]) == 0
+        ["forge", _cfg("train_pipelined_1f1b.gin"), "--plan",
+         "--binding", "train_eval_model.mode = 'train_and_evaluate'"]) == 0
     out = capsys.readouterr().out
     assert "UNFORGEABLE" in out and "train_step" in out
 
@@ -610,9 +611,13 @@ class TestForgeCLI:
          "/tmp/unused"]) == 2
     assert "no model source" in capsys.readouterr().err
 
-  def test_cache_dir_auto_requires_model_dir(self, capsys):
+  def test_cache_dir_auto_is_the_cache_root(self, capsys):
+    """`--cache-dir auto` (the default) is where trainer, servers and
+    bench look: `excache.cache_root()`, no model_dir involved."""
     assert graftscope.main(
-        ["forge", _cfg("serve_fleet.gin"), "--cache-dir", "auto"]) == 2
+        ["forge", _cfg("serve_session.gin"), "--model",
+         "SequenceRegressionModel", "--verify"]) == 1  # nothing forged yet
+    assert excache.cache_root() in capsys.readouterr().out
 
 
 # ---------------------------------------------------------------------------
@@ -693,7 +698,7 @@ assert "serve/engine" in rendered
 
 plan = forge.plan_from_config(
     ["tensor2robot_tpu/configs/train_pipelined_1f1b.gin"])
-assert plan["targets"][0]["forgeable"] is False  # version gate, no backend
+assert plan["targets"][0]["forgeable"] is True
 
 from tensor2robot_tpu.bin import graftscope
 assert graftscope.main(

@@ -651,15 +651,19 @@ def _run_child(tmp_path, role, parent_span, skew_ns):
 
 
 def test_two_subprocesses_with_skewed_clocks_merge_causally(tmp_path):
-  """Two REAL processes, the second's wall clock 3 s behind, the
-  second's event causally parented on the first's. The merged timeline
+  """Two REAL processes, the second's wall clock an hour behind, the
+  second's event causally parented on the first's. (An hour, not
+  seconds: the skew has to exceed the real time between the two events,
+  and under six test workers a child takes seconds to start — at 3 s the
+  downstream event could land AFTER the upstream one even skewed, and
+  there was nothing to repair.) The merged timeline
   must (a) come out causally ordered (the skew repair), (b) carry the
   synthesized flow link, (c) never have touched a JAX backend in
   either child (poisoned platform)."""
   upstream = _run_child(tmp_path, "upstream", "-", skew_ns=0)
   time.sleep(0.05)  # real elapsed time between cause and effect
   _run_child(tmp_path, "downstream", upstream,
-             skew_ns=-3_000_000_000)
+             skew_ns=-3_600_000_000_000)
   merged = aggregate_lib.merge_timeline(str(tmp_path))
   stats = merged["stats"]
   assert stats["shards"] == 2 and stats["processes"] == 2

@@ -759,3 +759,61 @@ class TestNativeJpegDecode:
     out = parsing.create_parse_fn(spec).parse_batch([record])
     np.testing.assert_array_equal(
         out["features/image"][0], codec.decode_image(color, channels=1))
+
+
+def test_library_is_rebuilt_when_a_source_changes(tmp_path, monkeypatch):
+  """The library's name carries a content hash of its sources: an edit
+  builds a new one (and drops the stale one), a touched mtime or a copy
+  of the tree builds nothing it already has, and a source g++ refuses
+  raises instead of falling back."""
+  import os
+  import shutil
+  import subprocess
+
+  if shutil.which("g++") is None:
+    pytest.skip("no g++")
+
+  def copied(path):
+    return shutil.copy(path, tmp_path)
+
+  monkeypatch.setattr(native, "_SOURCES",
+                      [copied(p) for p in native._SOURCES])
+  monkeypatch.setattr(native, "_JPEG_SOURCE", copied(native._JPEG_SOURCE))
+  monkeypatch.setattr(native, "_HEADERS",
+                      [copied(p) for p in native._HEADERS])
+  monkeypatch.setattr(native, "_LIB", None)
+  monkeypatch.setattr(native, "_LOAD_ERROR", None)
+  builds = []
+  real_run = subprocess.run
+
+  def counting_run(cmd, *args, **kwargs):
+    builds.append(cmd)
+    return real_run(cmd, *args, **kwargs)
+
+  monkeypatch.setattr(native.subprocess, "run", counting_run)
+  libs = lambda: sorted(p.name for p in tmp_path.glob("*.so"))
+
+  assert native.load() is not None
+  (first,) = libs()
+  assert len(builds) == 1
+  # Same content, newer mtime: nothing to build.
+  os.utime(native._SOURCES[0], None)
+  native._LIB = None
+  assert native.load() is not None
+  assert libs() == [first] and len(builds) == 1
+  # Changed content: a new library, the stale one gone.
+  with open(native._SOURCES[0], "a") as f:
+    f.write("\n// edited\n")
+  native._LIB = None
+  assert native.load() is not None
+  (second,) = libs()
+  assert second != first and len(builds) == 2
+  assert native.masked_crc32c(b"123456789") is not None
+  # A source the compiler refuses is an error, not a silent fallback.
+  with open(native._SOURCES[0], "a") as f:
+    f.write("\nthis is not C++\n")
+  native._LIB = None
+  with pytest.raises(native.NativeBuildError, match="g\\+\\+ could not"):
+    native.load()
+  with pytest.raises(native.NativeBuildError):
+    native.require()

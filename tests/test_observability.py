@@ -431,7 +431,8 @@ class TestDeviceTimingRule:
 
   def test_barrier_in_window_passes(self):
     for barrier in ("np.asarray(y)", "backend.sync(y)",
-                    "jax.device_get(y)", "y.item()"):
+                    "jax.device_get(y)", "y.item()",
+                    "jax.block_until_ready(y)"):
       src = _BAD_TIMING.replace(
           "  return time.perf_counter() - t0",
           f"  import numpy as np\n"
@@ -486,33 +487,28 @@ class TestDeviceTimingRule:
 
 
 # ---------------------------------------------------------------------------
-# ProfilerHook degrades gracefully when the profiler is unavailable.
+# ProfilerHook: a trace that cannot start is an error, not a downgrade.
 # ---------------------------------------------------------------------------
 
 
 class TestProfilerGuard:
 
-  def test_start_trace_failure_logs_once_and_disarms(self, tmp_path,
-                                                     monkeypatch):
+  def test_start_trace_failure_raises(self, tmp_path, monkeypatch):
+    """The user configured a trace: training on without it would hide
+    that the profiler is missing."""
     import jax
 
-    calls = []
-
     def boom(log_dir):
-      calls.append(log_dir)
-      raise RuntimeError("profiler service unreachable over tunnel")
+      raise RuntimeError("profiler service unreachable")
 
     monkeypatch.setattr(jax.profiler, "start_trace", boom)
     hook = profiler_lib.ProfilerHook(start_step=1, num_steps=2)
     ctx = type("Ctx", (), {"model_dir": str(tmp_path)})()
-    hook.after_step(ctx, 1, {})  # must NOT raise
-    hook.after_step(ctx, 1, {})  # disarmed: no retry
-    hook.after_step(ctx, 3, {})
-    hook.end(ctx)
-    assert len(calls) == 1
-    snap = metrics_lib.snapshot()
-    assert snap["counter/profiler/start_failures"] == 1.0
-    assert snap["gauge/profiler/trace_captured"] == 0.0
+    hook.after_step(ctx, 0, {})  # before the window: nothing starts
+    with pytest.raises(RuntimeError, match="profiler service"):
+      hook.after_step(ctx, 1, {})
+    hook.end(ctx)  # nothing is active: no stop, no trace reported
+    assert metrics_lib.snapshot()["gauge/profiler/trace_captured"] == 0.0
 
 
 # ---------------------------------------------------------------------------

@@ -37,6 +37,7 @@ def _train_steps(model, batch_size=8, steps=3, mesh=None):
       f = mesh_lib.put_host_batch(mesh, f)
       l = mesh_lib.put_host_batch(mesh, l)
     state, metrics = step(state, f, l)
+    jax.block_until_ready(metrics)  # see conftest.py: one step in flight
     batch = next(dataset)
   return state, metrics
 

@@ -10,7 +10,7 @@ Pins the session-serving semantics:
 * eviction under slot pressure (LRU victim, in-flight sessions immune,
   evicted session's next step raises; `admission='shed'` refuses);
 * `close_session()` with in-flight steps waits the dispatch out
-  (tunnel-safe join discipline);
+  (the join discipline);
 * `restore()` param hot-swap mid-episode keeps session state coherent;
 * graftcache warm start loads the decode ladder with zero compiles;
 * the open-loop session load shape (`loadgen.run_session_load`)
@@ -380,7 +380,7 @@ class TestEviction:
 
 
 # ---------------------------------------------------------------------------
-# close() with in-flight steps (tunnel-safe join discipline).
+# close() with in-flight steps (the join discipline).
 # ---------------------------------------------------------------------------
 
 

@@ -21,7 +21,7 @@ from tensor2robot_tpu.research.qtopt import flagship
 
 
 def _model_and_batches(k, batch=4):
-  model = flagship.make_flagship_model("cpu")
+  model = flagship.make_flagship_model("cpu", smoke=True)
   pre = model.preprocessor
   fs = [specs_lib.make_random_numpy(
       pre.get_out_feature_specification(modes.TRAIN),
@@ -84,6 +84,6 @@ def test_loop_under_mesh_matches_single_device():
 
 
 def test_loop_rejects_bad_num_steps():
-  model = flagship.make_flagship_model("cpu")
+  model = flagship.make_flagship_model("cpu", smoke=True)
   with pytest.raises(ValueError):
     ts.make_train_loop(model, 0)

@@ -242,6 +242,7 @@ class TestTrainStep:
       state, _ = step(state,
                       mesh_lib.put_host_batch(dp_mesh, b["features"]),
                       mesh_lib.put_host_batch(dp_mesh, b["labels"]))
+      jax.block_until_ready(state)  # see conftest.py: one step in flight
     acc_after = float(eval_step(state, f, l)["accuracy"])
     assert acc_after >= acc_before
     assert acc_after > 0.9
