@@ -227,7 +227,7 @@ def _trainer_summary(model_dir, final, clock, since_start) -> dict:
   walls = [b - a for a, b in zip(stamps, stamps[1:])]
   stats = [r for r in _read_jsonl(os.path.join(model_dir, "train",
                                                "metrics.jsonl"))
-           if "device_ms" in r and not r.get("compile")]
+           if "device_wait_ms" in r and not r.get("compile")]
   compile_records = {r["name"]: r for r in record.get("compile", [])}
   step_record = compile_records.get("train_step", {})
   return {
@@ -239,8 +239,8 @@ def _trainer_summary(model_dir, final, clock, since_start) -> dict:
       # Host clock between two steps' ends, each closed by
       # block_until_ready; includes the host making the next batch.
       "step_seconds_wall_median": _median(walls),
-      "stepstats_device_ms_median": _median(
-          [r["device_ms"] for r in stats]),
+      "stepstats_device_wait_ms_median": _median(
+          [r["device_wait_ms"] for r in stats]),
       "stepstats_data_wait_ms_median": _median(
           [r["data_wait_ms"] for r in stats]),
       "train_step_compile": {

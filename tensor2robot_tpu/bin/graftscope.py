@@ -99,8 +99,8 @@ __all__ = ["build_report", "render_postmortem", "main"]
 
 _SKIP_DIRS = {"checkpoints", "__pycache__", ".git"}
 # Per-step record signature written by obs.stepstats via StepStatsHook.
-_STEP_KEYS = ("data_wait_ms", "device_ms", "examples_per_sec")
-_BREAKDOWN_ROWS = ("step_ms", "device_ms", "data_wait_ms", "host_ms",
+_STEP_KEYS = ("data_wait_ms", "device_wait_ms", "examples_per_sec")
+_BREAKDOWN_ROWS = ("step_ms", "device_wait_ms", "data_wait_ms", "host_ms",
                    "dispatch_ms")
 
 
@@ -137,6 +137,8 @@ def _split_records(records: List[dict]
   step_records = []
   snapshot: Dict[str, float] = {}
   for record in records:
+    if "data_wait_ms" in record and "device_ms" in record:
+      record = runlog_lib.renamed_step_keys(record)  # written before PR 26
     if all(k in record for k in _STEP_KEYS):
       step_records.append(record)
     for key, value in record.items():
@@ -567,7 +569,7 @@ def _fmt_cell(value, width: int = 12) -> str:
   return f"{str(value):>{width}}"
 
 
-_STEP_COLUMNS = ("step_ms", "data_wait_ms", "device_ms",
+_STEP_COLUMNS = ("step_ms", "data_wait_ms", "device_wait_ms",
                  "examples_per_sec", "nonfinite_params")
 
 
@@ -575,7 +577,7 @@ def _postmortem_steps_lines(steps: List[dict], last_n: int) -> List[str]:
   if not steps:
     return ["recorded steps: none (did the run crash before the first "
             "stepstats window?)"]
-  shown = steps[-last_n:]
+  shown = [runlog_lib.renamed_step_keys(r) for r in steps[-last_n:]]
   lines = [f"last {len(shown)} recorded step window(s) "
            f"(of {len(steps)} in the ring buffer)"]
   columns = [c for c in _STEP_COLUMNS

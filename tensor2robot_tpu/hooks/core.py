@@ -25,12 +25,13 @@ from typing import Any, Callable, Dict, List, Mapping, Optional
 import jax
 import numpy as np
 
+from tensor2robot_tpu.obs import trace as trace_lib
 from tensor2robot_tpu.utils import config
 
-__all__ = ["Hook", "HookBuilder", "ConfigSaverHook", "GoldenValuesHook",
-           "VariableLoggerHook", "ExportHook", "DefaultHookBuilder",
-           "AsyncExportHookBuilder", "BestExportHook", "StepStatsHook",
-           "SentinelHook", "add_golden_outputs"]
+__all__ = ["Hook", "HookBuilder", "call_hooks", "ConfigSaverHook",
+           "GoldenValuesHook", "VariableLoggerHook", "ExportHook",
+           "DefaultHookBuilder", "AsyncExportHookBuilder", "BestExportHook",
+           "StepStatsHook", "SentinelHook", "add_golden_outputs"]
 
 
 class TrainContext:
@@ -78,6 +79,17 @@ class Hook:
 
   def end(self, ctx: TrainContext) -> None:
     pass
+
+
+def call_hooks(hooks: List[Hook], method: str, *args) -> None:
+  """Calls `method` on every hook in order, each inside a `train/hook`
+  span (args `hook` = the class name, `method`): the loop's hooks are
+  where a device's wait hides, and one span a hook names which."""
+  tracer = trace_lib.get_tracer()
+  for hook in hooks:
+    with tracer.span("train/hook", cat="train", hook=type(hook).__name__,
+                     method=method):
+      getattr(hook, method)(*args)
 
 
 class HookBuilder(abc.ABC):

@@ -87,7 +87,11 @@ __all__ = ["CACHE_VERSION", "cache_key", "key_components_from_traced",
 # invalidation for Pallas/Mosaic lowerings).
 # v3: the sidecar records the executable's device ids (`device_ids`),
 # which `load` hands to `deserialize_and_load(execution_devices=...)`.
-CACHE_VERSION = 3
+# v4: the train step and loop were renamed (`t2r_train_step`,
+# `t2r_train_loop_k<k>`). The key does not hold a function's name, so
+# an executable stored under the old name would come back as
+# `jit_step_fn` in a profiler trace: every older entry is retired once.
+CACHE_VERSION = 4
 
 # Where both cache tiers live (ISSUE 22 §4). `JAX_COMPILATION_CACHE_DIR`
 # places them from outside: jax's own persistent cache is then that

@@ -35,7 +35,8 @@ from tensor2robot_tpu.obs import metrics as metrics_lib
 
 __all__ = ["SCHEMA", "SCHEMA_VERSION", "RUNS_FILENAME", "new_run_id",
            "make_record", "append_record", "read_jsonl", "load_records",
-           "step_stats_summary", "overlap_summary", "key_metrics",
+           "step_stats_summary", "renamed_step_keys", "overlap_summary",
+           "key_metrics",
            "DEFAULT_THRESHOLDS",
            "diff_records", "format_diff", "trend_records", "format_trend",
            "resolve_run", "history_lines",
@@ -342,11 +343,24 @@ def read_jsonl(path: str, counter_name: str = "runlog/corrupt_lines",
   return records, skipped
 
 
+def renamed_step_keys(record: Dict[str, Any]) -> Dict[str, Any]:
+  """Stepstats' `device_ms` (dispatch + barrier wait: a wait of the
+  host's, no device time) became `device_wait_ms` in PR 26. A step record
+  or a step-stat summary written before is read under the new name, so
+  tables and diffs across the rename line up."""
+  return {("device_wait_ms" + key[len("device_ms"):]
+           if key == "device_ms" or key.startswith("device_ms_") else key):
+          value for key, value in record.items()}
+
+
 def load_records(path: str,
                  registry: Optional[metrics_lib.Registry] = None
                  ) -> List[Dict[str, Any]]:
   """Every parseable record in `path`, oldest first (see `read_jsonl`)."""
   records, _ = read_jsonl(path, registry=registry)
+  for record in records:
+    if isinstance(record.get("step_stats"), dict):
+      record["step_stats"] = renamed_step_keys(record["step_stats"])
   return records
 
 
@@ -354,13 +368,12 @@ def step_stats_summary(snapshot: Dict[str, float]) -> Dict[str, float]:
   """Run-record step-stat summary from a metrics-registry snapshot
   (the `stepstats/*` histograms `obs.stepstats` feeds every window)."""
   out: Dict[str, float] = {}
-  for hist, dst in (("step_ms", "step_ms"), ("device_ms", "device_ms"),
-                    ("data_wait_ms", "data_wait_ms"),
-                    ("examples_per_sec", "examples_per_sec")):
+  for hist in ("step_ms", "device_wait_ms", "data_wait_ms",
+               "examples_per_sec"):
     for stat in ("mean", "p50", "p90"):
       value = snapshot.get(f"hist/stepstats/{hist}/{stat}")
       if value is not None:
-        out[f"{dst}_{stat}"] = float(value)
+        out[f"{hist}_{stat}"] = float(value)
   count = snapshot.get("hist/stepstats/step_ms/count")
   if count is not None:
     out["windows"] = float(count)

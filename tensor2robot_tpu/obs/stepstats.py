@@ -17,15 +17,16 @@ Accounting per measured window (`every_n_steps` dispatches, default 1):
   `DevicePrefetcher`), the loop's `data_wait()` wraps only the DEQUEUE
   of an already-placed batch, so parse/preprocess/place work running in
   worker threads concurrently with device compute inflates NEITHER
-  `data_wait_ms` NOR `device_ms` (pinned by the synthetic
+  `data_wait_ms` NOR `device_wait_ms` (pinned by the synthetic
   overlapped-producer test in tests/test_overlap.py): a near-zero
   `data_wait_ms` with healthy throughput means the pipeline keeps up,
   and a growing one means the consumer outran it — read the
   `data/overlap_*` stage timings to see which stage binds;
-* `device_ms`     — un-overlapped device wait: dispatch-call time plus
-  the closing barrier fetch. Host staging that overlaps device compute
-  is deliberately NOT charged to the device — the split answers "what
-  is the loop's wall clock spent waiting on";
+* `device_wait_ms` — un-overlapped device wait: dispatch-call time plus
+  the closing barrier fetch (a wait of the host's, not a device time:
+  the device's own step time comes from a profiler trace). Host staging
+  that overlaps device compute is deliberately NOT charged to the device
+  — the split answers "what is the loop's wall clock spent waiting on";
 * `host_ms`       — the remainder (hooks, metric fetch, logging);
 * `step_ms`       — full window wall time / steps;
 * `examples_per_sec`, `compile` (first dispatch, or a dispatch-time
@@ -39,6 +40,13 @@ per-step vs log-cadence by backend. Importing this module never
 touches jax (backend access is lazy, from inside a live loop); the
 train-loop integration lives in `train_eval.py` +
 `hooks.core.StepStatsHook`.
+
+Spans (`obs.trace`, children of the loop's `train/iteration`):
+`train/data_wait`, `train/dispatch` (`train/compile_dispatch` marks the
+spikes), `train/barrier`, and `train/record` with its children
+`train/record/gauges` and one `train/record/observer` per observer:
+what the host does between a barrier's end and the next dispatch is
+what the device waits for.
 """
 
 from __future__ import annotations
@@ -69,21 +77,21 @@ _DISPATCH_HISTORY = 32
 class _WaitTimer:
   """Accumulates one staging window into the recorder (+ trace span)."""
 
-  __slots__ = ("_rec", "_start_ns")
+  __slots__ = ("_rec", "_start_ns", "_span")
 
   def __init__(self, rec: "StepStatsRecorder"):
     self._rec = rec
     self._start_ns = 0
+    self._span = None
 
   def __enter__(self) -> "_WaitTimer":
+    self._span = self._rec._tracer.open("train/data_wait", cat="train")
     self._start_ns = time.perf_counter_ns()
     return self
 
   def __exit__(self, exc_type, exc, tb) -> None:
-    dur_ns = time.perf_counter_ns() - self._start_ns
-    self._rec._data_wait_ns += dur_ns
-    self._rec._tracer.add_complete("train/data_wait", self._start_ns,
-                                   dur_ns, cat="train")
+    self._rec._data_wait_ns += time.perf_counter_ns() - self._start_ns
+    self._span.close()
 
 
 class _NullTimer:
@@ -148,6 +156,7 @@ class StepStatsRecorder:
     self._last_record_step: Optional[int] = None
     self._dispatch_history_ms: List[float] = []
     self._t_dispatch_ns = 0
+    self._dispatch_span = trace_lib.Span(None, "", "", None)
     self._compile_in_window = 0
     self._observers: List[Callable[[int, Dict[str, float]], Any]] = []
     self._last_barrier_nonfinite: Optional[float] = None
@@ -177,6 +186,7 @@ class StepStatsRecorder:
 
   def before_dispatch(self) -> None:
     if self._enabled:
+      self._dispatch_span = self._tracer.open("train/dispatch", cat="train")
       self._t_dispatch_ns = time.perf_counter_ns()
 
   def after_dispatch(self) -> None:
@@ -184,6 +194,7 @@ class StepStatsRecorder:
     if not self._enabled:
       return
     dur_ns = time.perf_counter_ns() - self._t_dispatch_ns
+    self._dispatch_span.close()
     self._dispatch_ns += dur_ns
     self._dispatches_in_window += 1
     dispatch_ms = dur_ns / 1e6
@@ -207,14 +218,14 @@ class StepStatsRecorder:
     self._steps_in_window += num_steps
     if self._steps_in_window < self._every_n:
       return
-    barrier_start_ns = time.perf_counter_ns()
-    fetched = self._barrier(state)
-    now_ns = time.perf_counter_ns()
+    with self._tracer.span("train/barrier", cat="train"):
+      barrier_start_ns = time.perf_counter_ns()
+      fetched = self._barrier(state)
+      now_ns = time.perf_counter_ns()
     self._barrier_ns += now_ns - barrier_start_ns
-    self._tracer.add_complete("train/barrier", barrier_start_ns,
-                              now_ns - barrier_start_ns, cat="train")
-    self._observe_barrier(fetched)
-    self._emit(step, now_ns)
+    with self._tracer.span("train/record", cat="train"):
+      self._observe_barrier(fetched)
+      self._emit(step, now_ns)
 
   def _observe_barrier(self, fetched: Any) -> None:
     """Piggybacks on the barrier's host fetch: non-finite divergence
@@ -233,13 +244,13 @@ class StepStatsRecorder:
     n = self._steps_in_window
     window_s = max((now_ns - self._window_start_ns) / 1e9, 1e-9)
     data_wait_ms = self._data_wait_ns / 1e6 / n
-    device_ms = (self._dispatch_ns + self._barrier_ns) / 1e6 / n
+    device_wait_ms = (self._dispatch_ns + self._barrier_ns) / 1e6 / n
     step_ms = window_s * 1e3 / n
     record: Dict[str, float] = {
         "step_ms": step_ms,
-        "device_ms": device_ms,
+        "device_wait_ms": device_wait_ms,
         "data_wait_ms": data_wait_ms,
-        "host_ms": max(step_ms - device_ms - data_wait_ms, 0.0),
+        "host_ms": max(step_ms - device_wait_ms - data_wait_ms, 0.0),
         "dispatch_ms": self._dispatch_ns / 1e6 / n,
         "examples_per_sec": n * self._batch_size / window_s,
         "compile": float(self._compile_in_window > 0),
@@ -253,22 +264,26 @@ class StepStatsRecorder:
     }
     if self._last_barrier_nonfinite is not None:
       record["nonfinite_params"] = self._last_barrier_nonfinite
-    record.update(self._read_device_gauges())
+    with self._tracer.span("train/record/gauges", cat="train"):
+      record.update(self._read_device_gauges())
     self._records.append((int(step), record))
     for observer in list(self._observers):
       try:
-        observer(int(step), record)
+        with self._tracer.span(
+            "train/record/observer", cat="train",
+            observer=getattr(observer, "__qualname__", None)
+            or type(observer).__name__):
+          observer(int(step), record)
       except Exception as e:  # noqa: BLE001 - drop a broken observer
         self._observers.remove(observer)
         print(f"stepstats: observer {observer!r} failed and was "
               f"detached ({type(e).__name__}: {e})", file=sys.stderr)
     reg = self._registry
     reg.histogram("stepstats/step_ms").record(step_ms)
-    reg.histogram("stepstats/device_ms").record(device_ms)
+    reg.histogram("stepstats/device_wait_ms").record(device_wait_ms)
     reg.histogram("stepstats/data_wait_ms").record(data_wait_ms)
     reg.histogram("stepstats/examples_per_sec").record(
         record["examples_per_sec"])
-    reg.gauge("stepstats/examples_per_sec").set(record["examples_per_sec"])
     first_step = int(step) - n + 1
     self._tracer.add_complete(
         "train/step_window", self._window_start_ns,

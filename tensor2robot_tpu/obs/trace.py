@@ -10,8 +10,27 @@ buffer per tracer (bounded memory, oldest events dropped), thread-aware
 trace-event format that `chrome://tracing` and https://ui.perfetto.dev
 load directly.
 
-Backend-free by construction: this module never imports jax and a
-disabled tracer costs a single attribute check per span
+One span tree: every event carries an `id`, the `parent` that was open
+on the same thread when it began (none at a thread's root) and, below a
+span that was given one (`train/iteration`), the `step`. So a reader can
+ask for a span's children and its self time without guessing from
+timestamps.
+
+Two clocks, laid over each other twice. While a `jax.profiler` session
+records host events, every span also opens a
+`jax.profiler.TraceAnnotation` of its own name, so it lies in the xplane
+beside the device ops, on the device trace's clock by construction. For
+traces that carry no host events, `enable()` records one anchor pair
+(`perf_counter_ns`, `time.time_ns`) and `save()` writes it under
+`metadata.clock_anchor`. The profiler counts nanoseconds from its
+session's start, and writes that start as wall-clock nanoseconds into
+the xplane (plane `Task Environment`, stat `profile_start_time`): with
+the anchor, a span at `ts` lies at
+`time_ns + ts * 1000 - perf_counter_ns - profile_start_time` there.
+
+Backend-free by construction: importing this module never imports jax
+(`jax.profiler` is imported at `enable()`, which starts no backend) and
+a disabled tracer costs a single attribute check per span
 (tests/test_observability.py runs it under a poisoned JAX_PLATFORMS).
 """
 
@@ -19,6 +38,7 @@ from __future__ import annotations
 
 import collections
 import functools
+import itertools
 import json
 import os
 import threading
@@ -53,10 +73,13 @@ class Span:
 
   Re-entrant use is wrong (one Span = one window); allocate via
   `Tracer.span`. A span created while the tracer is disabled is the
-  shared no-op instance and records nothing.
+  shared no-op instance and records nothing. Where a `with` would
+  force a loop body to be re-indented, `Tracer.open` enters one and
+  `close` leaves it; `close` may be called twice.
   """
 
-  __slots__ = ("_tracer", "_name", "_cat", "_args", "_start_ns")
+  __slots__ = ("_tracer", "_name", "_cat", "_args", "_start_ns", "_id",
+               "_parent", "_step", "_stack", "_annotation")
 
   def __init__(self, tracer: Optional["Tracer"], name: str, cat: str,
                args: Optional[Dict[str, Any]]):
@@ -65,17 +88,52 @@ class Span:
     self._cat = cat
     self._args = args
     self._start_ns = 0
+    self._id = 0
+    self._parent = None
+    self._step = args.get("step") if args else None
+    self._stack = None  # this thread's open spans, while this one is open
+    self._annotation = None
 
   def __enter__(self) -> "Span":
-    if self._tracer is not None:
+    tracer = self._tracer
+    if tracer is not None:
+      stack = tracer._open_spans()
+      if stack:
+        parent = stack[-1]
+        self._parent = parent._id
+        if self._step is None:
+          self._step = parent._step
+      self._id = next(tracer._ids)
+      stack.append(self)
+      self._stack = stack
+      annotation = tracer._annotation
+      if annotation is not None and annotation.is_enabled():
+        self._annotation = annotation(self._name)
+        self._annotation.__enter__()
       self._start_ns = time.perf_counter_ns()
     return self
 
   def __exit__(self, exc_type, exc, tb) -> None:
-    if self._tracer is not None:
-      end_ns = time.perf_counter_ns()
-      self._tracer._record(self._name, self._cat, self._start_ns,
-                           end_ns - self._start_ns, self._args)
+    stack = self._stack
+    if stack is None:
+      return
+    end_ns = time.perf_counter_ns()
+    self._stack = None
+    if self in stack:
+      # A child left open (an exception past its close) ends with its
+      # parent, so the thread's stack never names a dead span.
+      while stack[-1] is not self:
+        stack[-1].__exit__(None, None, None)
+      stack.pop()
+    if self._annotation is not None:
+      self._annotation.__exit__(None, None, None)
+      self._annotation = None
+    self._tracer._record(self._name, self._cat, self._start_ns,
+                         end_ns - self._start_ns, self._args,
+                         self._id, self._parent, self._step)
+
+  def close(self) -> None:
+    self.__exit__(None, None, None)
 
 
 _NULL_SPAN = Span(None, "", "", None)
@@ -117,6 +175,10 @@ class Tracer:
     self._lock = threading.Lock()
     self._thread_names: Dict[int, str] = {}
     self._enabled = False
+    self._ids = itertools.count(1)  # next() is atomic under the GIL
+    self._local = threading.local()  # .stack: this thread's open spans
+    self._annotation = None  # jax.profiler.TraceAnnotation, from enable()
+    self._anchor: Optional[Dict[str, int]] = None
     # Cached: one getpid() syscall per EVENT is measurable on the
     # serving hot path. Refreshed after fork (register_at_fork below).
     self._pid = os.getpid()
@@ -138,7 +200,25 @@ class Tracer:
   def buffered_bytes(self) -> int:
     return self._bytes
 
+  @property
+  def anchor(self) -> Optional[Dict[str, int]]:
+    """One reading of both clocks at the last `enable()`:
+    `{"perf_counter_ns", "time_ns"}` (module docstring)."""
+    return self._anchor
+
   def enable(self) -> None:
+    if self._annotation is None:
+      try:
+        import jax.profiler  # lazy: importing this module stays jax-free
+
+        self._annotation = jax.profiler.TraceAnnotation
+      except Exception:  # noqa: BLE001 - no jax, no profiler to write into
+        self._annotation = None
+    before = time.perf_counter_ns()
+    wall = time.time_ns()
+    after = time.perf_counter_ns()
+    self._anchor = {"perf_counter_ns": (before + after) // 2,
+                    "time_ns": wall}
     self._enabled = True
 
   def disable(self) -> None:
@@ -151,14 +231,50 @@ class Tracer:
       self._bytes = 0
       self._dropped = 0
       self._thread_names.clear()
+    # Every thread starts again at its root: a span that an exception
+    # left open must not adopt the next run's spans.
+    self._local = threading.local()
 
   # -- recording ------------------------------------------------------------
 
   def span(self, name: str, cat: str = "span", **args: Any) -> Span:
-    """Context manager timing a code window as one complete event."""
+    """Context manager timing a code window as one complete event. A
+    `step` argument is handed down to every span opened below it."""
     if not self._enabled:
       return _NULL_SPAN
     return Span(self, name, cat, args or None)
+
+  def open(self, name: str, cat: str = "span", **args: Any) -> Span:
+    """`span`, already entered: end it with `Span.close()`."""
+    return self.span(name, cat=cat, **args).__enter__()
+
+  def _open_spans(self) -> List[Span]:
+    try:
+      return self._local.stack
+    except AttributeError:
+      stack = self._local.stack = []
+      return stack
+
+  def _lineage(self):
+    """(`id`, `parent`, `step`) of an event that is no `Span`: its
+    parent is the span open on this thread as it is recorded."""
+    stack = self._open_spans()
+    if not stack:
+      return next(self._ids), None, None
+    return next(self._ids), stack[-1]._id, stack[-1]._step
+
+  def _event(self, name: str, cat: str, ph: str, ts_ns: int,
+             args: Optional[Dict[str, Any]], span_id: int,
+             parent: Optional[int], step: Optional[int]) -> Dict[str, Any]:
+    event = {"name": name, "cat": cat, "ph": ph, "ts": ts_ns / _NS_PER_US,
+             "pid": self._pid, "tid": threading.get_ident(), "id": span_id}
+    if parent is not None:
+      event["parent"] = parent
+    if step is not None:
+      event["step"] = step
+    if args:
+      event["args"] = args
+    return event
 
   def traced(self, name: Optional[str] = None, cat: str = "span"):
     """Decorator form of `span` (one event per call)."""
@@ -179,11 +295,10 @@ class Tracer:
     """Zero-duration marker event."""
     if not self._enabled:
       return
-    now = time.perf_counter_ns()
-    self._append({"name": name, "cat": cat, "ph": "i",
-                  "ts": now / _NS_PER_US, "s": "t",
-                  "pid": self._pid, "tid": threading.get_ident(),
-                  **({"args": args} if args else {})})
+    event = self._event(name, cat, "i", time.perf_counter_ns(), args or None,
+                        *self._lineage())
+    event["s"] = "t"
+    self._append(event)
 
   def add_complete(self, name: str, start_ns: int, dur_ns: int,
                    cat: str = "span",
@@ -192,15 +307,14 @@ class Tracer:
     the caller — e.g. stepstats' barrier-bounded step windows)."""
     if not self._enabled:
       return
-    self._record(name, cat, start_ns, dur_ns, args)
+    self._record(name, cat, start_ns, dur_ns, args, *self._lineage())
 
   def _record(self, name: str, cat: str, start_ns: int, dur_ns: int,
-              args: Optional[Dict[str, Any]]) -> None:
-    self._append({"name": name, "cat": cat, "ph": "X",
-                  "ts": start_ns / _NS_PER_US,
-                  "dur": max(dur_ns, 0) / _NS_PER_US,
-                  "pid": self._pid, "tid": threading.get_ident(),
-                  **({"args": args} if args else {})})
+              args: Optional[Dict[str, Any]], span_id: int,
+              parent: Optional[int], step: Optional[int]) -> None:
+    event = self._event(name, cat, "X", start_ns, args, span_id, parent, step)
+    event["dur"] = max(dur_ns, 0) / _NS_PER_US
+    self._append(event)
 
   def _append(self, event: Dict[str, Any]) -> None:
     provider = _CONTEXT_PROVIDER
@@ -245,9 +359,13 @@ class Tracer:
 
     Open the file in Perfetto (https://ui.perfetto.dev) or
     chrome://tracing — both consume this format unmodified.
+    `metadata.clock_anchor` lays it over a profiler trace: an event at
+    `ts` microseconds happened at wall-clock nanosecond
+    `time_ns + ts * 1000 - perf_counter_ns` (module docstring).
     """
     os.makedirs(os.path.dirname(path) or ".", exist_ok=True)
-    payload = {"traceEvents": self.events(), "displayTimeUnit": "ms"}
+    payload = {"traceEvents": self.events(), "displayTimeUnit": "ms",
+               "metadata": {"clock_anchor": self._anchor}}
     tmp = path + ".tmp"
     with open(tmp, "w") as f:
       json.dump(payload, f)

@@ -46,6 +46,7 @@ import time
 from typing import Any, Dict, List, Optional, Tuple
 
 from tensor2robot_tpu.obs import metrics as metrics_lib
+from tensor2robot_tpu.obs import trace as trace_lib
 from tensor2robot_tpu.utils import backend as backend_lib
 
 __all__ = ["analyze_jit", "XrayedFunction", "memory_accounting",
@@ -365,9 +366,19 @@ class XrayedFunction:
       if self._compiled is not None or self._failed:
         return
       try:
+        start_ns = time.perf_counter_ns()
         self._compiled, self._record = analyze_jit(
             self._name, self._fn, *args, registry=self._registry,
             cache=self._cache)
+        # The compile-or-cache-load of the first call, as a child of the
+        # caller's span (the trainer's first `train/dispatch`); the
+        # record holds its trace, lower and compile seconds.
+        trace_lib.get_tracer().add_complete(
+            "xray/analyze", start_ns, time.perf_counter_ns() - start_ns,
+            cat="xray", args={
+                "executable": self._name,
+                "cache_hit": bool(
+                    (self._record.get("cache") or {}).get("hit"))})
       except Exception as e:  # noqa: BLE001 - degrade, never break the call
         self._failed = True
         self._registry.counter("xray/analyze_failures").inc()

@@ -287,6 +287,7 @@ def _flash_forward(q3, k3, v3, causal, block_q, block_k, valid_len,
           jax.ShapeDtypeStruct((bh, t, 1), jnp.float32),
       ],
       interpret=interpret,
+      name="flash_fwd",
   )(q3, k3, v3)
   return out, lse
 
@@ -329,6 +330,7 @@ def _flash_bwd(causal, block_q, block_k, valid_len, interpret, residuals,
       out_specs=pl.BlockSpec((None, block_q, d), lambda b, qb: (b, qb, 0)),
       out_shape=jax.ShapeDtypeStruct((bh, t, d), q3.dtype),
       interpret=interpret,
+      name="flash_bwd_dq",
   )(q3, k3, v3, g, lse, delta)
   dkv_kernel = functools.partial(
       _flash_bwd_dkv_kernel, block_q=block_q, causal=causal,
@@ -353,6 +355,7 @@ def _flash_bwd(causal, block_q, block_k, valid_len, interpret, residuals,
           jax.ShapeDtypeStruct((bh, t, d), v3.dtype),
       ],
       interpret=interpret,
+      name="flash_bwd_dkv",
   )(q3, k3, v3, g, lse, delta)
   return dq, dk, dv
 

@@ -244,6 +244,7 @@ class DevicePrefetcher:
     import weakref
 
     from tensor2robot_tpu.obs import metrics as obs_metrics
+    from tensor2robot_tpu.obs import trace as trace_lib
 
     if depth < 1:
       raise ValueError(f"depth must be >= 1, got {depth}")
@@ -280,7 +281,31 @@ class DevicePrefetcher:
     sentinel = self._STOP
     place_hist = obs_metrics.histogram("data/overlap_place_ms")
     depth_gauge = obs_metrics.gauge("data/overlap_device_queue_depth")
+    tracer = trace_lib.get_tracer()
     perf_counter_ns = time_lib.perf_counter_ns
+
+    def _host_batches():
+      """`dataset`, each `next` of it inside a `data/next_host` span."""
+      source = iter(dataset)
+      while True:
+        with tracer.span("data/next_host", cat="data"):
+          try:
+            batch = next(source)
+          except StopIteration:
+            return
+        yield batch
+
+    def _place(batch):
+      """`place_fn`, timed: the `data/place` span (arg `bytes`) beside the
+      histogram. The batch's leaves are walked only for a live tracer."""
+      nbytes = sum(int(getattr(leaf, "nbytes", 0))
+                   for leaf in jax.tree_util.tree_leaves(batch)
+                   ) if tracer.enabled else 0
+      with tracer.span("data/place", cat="data", bytes=nbytes):
+        t0 = perf_counter_ns()
+        placed = place_fn(batch)
+        place_hist.record((perf_counter_ns() - t0) * 1e-6)
+      return placed
 
     # The workers close over locals only — never `self` — so an
     # abandoned-without-close() prefetcher is actually collectable (a
@@ -299,16 +324,14 @@ class DevicePrefetcher:
       # next(dataset) then place_fn — the pre-ROADMAP-6 shape, kept for
       # A/Bs and for place_fns that must not overlap their source.
       try:
-        for batch in dataset:
+        for batch in _host_batches():
           if stop.is_set():
             # Checked between next(dataset) and place_fn so a stop
             # requested while the source was producing skips the device
             # transfer and exits without touching the queue.
             return
           phase[0] = "transfer"
-          t0 = perf_counter_ns()
-          placed = place_fn(batch)
-          place_hist.record((perf_counter_ns() - t0) * 1e-6)
+          placed = _place(batch)
           phase[0] = "queue"
           while not stop.is_set():
             try:
@@ -348,7 +371,7 @@ class DevicePrefetcher:
 
     def _feeder():
       try:
-        for batch in dataset:
+        for batch in _host_batches():
           if stop.is_set():
             return
           if not _hq_put(batch):
@@ -372,9 +395,7 @@ class DevicePrefetcher:
             _put_final(item)
             return
           phase[0] = "transfer"
-          t0 = perf_counter_ns()
-          placed = place_fn(item)
-          place_hist.record((perf_counter_ns() - t0) * 1e-6)
+          placed = _place(item)
           phase[0] = "queue"
           while not stop.is_set():
             try:

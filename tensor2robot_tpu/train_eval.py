@@ -240,7 +240,7 @@ def train_eval_model(
   returns each inner step's scalars).
 
   `step_stats_every_n_steps` > 0 turns on graftscope step telemetry
-  (`obs.stepstats`): per-step `data_wait_ms` / `device_ms` /
+  (`obs.stepstats`): per-step `data_wait_ms` / `device_wait_ms` /
   `examples_per_sec` records in `metrics.jsonl` plus a Perfetto trace
   (`trace.graftscope.json`), emitted via an auto-appended
   `StepStatsHook`. Each measured window ends in a barrier (a host
@@ -304,108 +304,122 @@ def train_eval_model(
     raise ValueError(f"Unknown train_eval mode {mode!r}")
   _maybe_pin_cpu(model)
   os.makedirs(model_dir, exist_ok=True)
-  # graftcache (obs.excache) — armed for EVERY mode, independent of the
-  # step-stats telemetry gate: the XLA compilation-cache tier covers
-  # every plain-jit compile (state init, eval steps, prediction), and
-  # the serialized-AOT tier plugs into the XrayedFunction wrapping below
-  # when telemetry is on. "auto" is the one root every process of this
-  # checkout shares (`excache.cache_root`), so restarts warm up by
-  # themselves whatever their model_dir.
-  executable_cache = None
-  if executable_cache_dir:
-    executable_cache = excache_lib.ExecutableCache(
-        excache_lib.cache_root() if executable_cache_dir == "auto"
-        else executable_cache_dir)
-    excache_lib.enable_xla_cache()
-  if mesh is None:
-    kwargs = {"axis_names": tuple(mesh_axis_names)} if mesh_axis_names \
-        else {}
-    mesh = mesh_lib.create_mesh(mesh_shape=mesh_shape, **kwargs)
-  if hasattr(model, "set_mesh"):
-    # Models whose module runs explicit collectives (e.g. the pipelined
-    # trunk's shard_map schedule) need the mesh before create_module.
-    model.set_mesh(mesh)
-  print_specification(model)
-
-  writer = summaries_lib.SummaryWriter(os.path.join(model_dir,
-                                                    "train" if "train" in mode
-                                                    else "eval"))
-  hooks: List[hooks_lib.Hook] = []
-  for builder in hook_builders or []:
-    hooks.extend(builder.create_hooks(model, model_dir))
-  for gen in export_generators or []:
-    hooks.append(hooks_lib.ExportHook(export_generator=gen,
-                                      num_versions=export_num_versions))
-
-  manager = checkpoints_lib.CheckpointManager(
-      os.path.join(model_dir, CHECKPOINT_DIRNAME),
-      max_to_keep=keep_checkpoints,
-      save_interval_steps=1)
-
-  # -- data + state bring-up -----------------------------------------------
   needs_train = mode in ("train", "train_and_evaluate")
   needs_eval = mode != "train"
   if needs_train and input_generator_train is None:
     raise ValueError("input_generator_train is required for training.")
   if needs_eval and input_generator_eval is None:
     raise ValueError("input_generator_eval is required for evaluation.")
-  # Host-overlap tuning flows trainer -> generator -> RecordBatchPipeline
-  # (generators without a record pipeline accept and ignore it).
-  for gen in (input_generator_train, input_generator_eval):
-    if gen is not None and hasattr(gen, "set_overlap_options"):
-      gen.set_overlap_options(num_parallel_parses=host_overlap_workers,
-                              overlap_queue_mb=host_overlap_queue_mb)
-  if step_stats_every_n_steps is None:
-    # Per-step barriers are ~free on CPU; on an accelerator each
-    # measured window's host fetch serializes the dispatch/prefetch
-    # overlap, so default to the log cadence there.
-    step_stats_every_n_steps = (
-        1 if jax.devices()[0].platform == "cpu"
-        else max(int(log_every_n_steps), 1))
-  step_stats = stepstats_lib.StepStatsRecorder(
-      batch_size=(input_generator_train.batch_size if needs_train else 0),
-      every_n_steps=step_stats_every_n_steps if needs_train else 0)
-  if step_stats.enabled and reset_run_telemetry:
+  # Step telemetry is on for every training run unless the cadence is 0
+  # (`step_stats.enabled` below says the same once the recorder exists).
+  telemetry = needs_train and (step_stats_every_n_steps is None
+                               or int(step_stats_every_n_steps) > 0)
+  tracer = trace_lib.get_tracer()
+  tracer_preenabled = tracer.enabled
+  if telemetry and reset_run_telemetry:
     # Per-run telemetry: clear the process-global trace buffer, metrics
     # registry and xray compile-record collector so the saved trace,
-    # final snapshot and run record cover exactly this run (the tracer
-    # itself is enabled inside the train loop's try so any exit path
-    # disables it again). This MUST precede data-pipeline spin-up: the
-    # overlapped loader and prefetcher cache their histogram objects at
-    # construction, and a later registry reset would orphan them — the
-    # run's data/overlap_* stage attribution would silently vanish from
-    # the final snapshot. `reset_run_telemetry=False` is for embeddings
-    # where the process-global registry belongs to a LONGER-lived owner
-    # than this run — the graftloop learner trains in rounds inside a
-    # live actor/serving process, and a per-round reset would wipe the
-    # loop's own counters (episodes, sheds, staleness) mid-flight.
+    # final snapshot and run record cover exactly this run. This MUST
+    # precede data-pipeline spin-up: the overlapped loader and prefetcher
+    # cache their histogram objects at construction, and a later registry
+    # reset would orphan them — the run's data/overlap_* stage attribution
+    # would silently vanish from the final snapshot.
+    # `reset_run_telemetry=False` is for embeddings where the
+    # process-global registry belongs to a LONGER-lived owner than this
+    # run — the graftloop learner trains in rounds inside a live
+    # actor/serving process, and a per-round reset would wipe the loop's
+    # own counters (episodes, sheds, staleness) mid-flight.
     trace_lib.clear()
     metrics_registry_lib.reset()
     xray_lib.clear_records()
-  train_dataset = eval_dataset = None
-  if needs_train:
-    provide_input_generator_with_model_information(
-        input_generator_train, model, modes_lib.TRAIN)
-    train_dataset = input_generator_train.create_dataset(modes_lib.TRAIN)
-  # The loader behind the (possibly itertools-wrapped) train stream —
-  # closed in the loop's finally so its stage threads never outlive the
-  # run.
-  raw_train_dataset = train_dataset
-  if needs_eval:
-    provide_input_generator_with_model_information(
-        input_generator_eval, model, modes_lib.EVAL)
+  train_dataset = eval_dataset = raw_train_dataset = None
 
-  # Everything between data-pipeline spin-up and the train loop's
-  # own try/finally (which owns the loader from there on): a
-  # failure here — unreadable first batch, corrupted checkpoint
-  # restore, a step-factory trace error, a hook.begin crash —
-  # must close the loader's stage threads rather than leak them
-  # to GC (the zero-leaked-threads discipline the thread-stage
-  # lint rules mechanize). Eval-only modes return from inside
-  # this block normally; their train loader is None.
+  # All of bring-up, up to the train loop's own try/finally (which owns
+  # the loader and the tracer from there on): a failure here —
+  # unreadable first batch, corrupted checkpoint restore, a step-factory
+  # trace error, a hook.begin crash — must close the loader's stage
+  # threads rather than leak them to GC (the zero-leaked-threads
+  # discipline the thread-stage lint rules mechanize), and disarm the
+  # tracer. Eval-only modes return from inside this block normally;
+  # their train loader is None.
   try:
+    if telemetry:
+      # Armed from the start, so that bring-up has spans too (`setup/*`,
+      # in the order they run); the loop's finally disarms it.
+      trace_lib.enable()
+    # graftcache (obs.excache) — armed for EVERY mode, independent of
+    # the step-stats telemetry gate: the XLA compilation-cache tier
+    # covers every plain-jit compile (state init, eval steps,
+    # prediction), and the serialized-AOT tier plugs into the
+    # XrayedFunction wrapping below when telemetry is on. "auto" is the
+    # one root every process of this checkout shares
+    # (`excache.cache_root`), so restarts warm up by themselves
+    # whatever their model_dir.
+    executable_cache = None
+    if executable_cache_dir:
+      executable_cache = excache_lib.ExecutableCache(
+          excache_lib.cache_root() if executable_cache_dir == "auto"
+          else executable_cache_dir)
+      excache_lib.enable_xla_cache()
+    # The first touch of the backend: on a chip, its start-up.
+    if mesh is None:
+      kwargs = {"axis_names": tuple(mesh_axis_names)} \
+          if mesh_axis_names else {}
+      mesh = mesh_lib.create_mesh(mesh_shape=mesh_shape, **kwargs)
+    if hasattr(model, "set_mesh"):
+      # Models whose module runs explicit collectives (e.g. the
+      # pipelined trunk's shard_map schedule) need the mesh before
+      # create_module.
+      model.set_mesh(mesh)
+    print_specification(model)
+
+    with tracer.span("setup/writer", cat="setup"):
+      # Imports TensorFlow for the TensorBoard mirror, where it is there.
+      writer = summaries_lib.SummaryWriter(
+          os.path.join(model_dir, "train" if "train" in mode else "eval"))
+    hooks: List[hooks_lib.Hook] = []
+    for builder in hook_builders or []:
+      hooks.extend(builder.create_hooks(model, model_dir))
+    for gen in export_generators or []:
+      hooks.append(hooks_lib.ExportHook(export_generator=gen,
+                                        num_versions=export_num_versions))
+    manager = checkpoints_lib.CheckpointManager(
+        os.path.join(model_dir, CHECKPOINT_DIRNAME),
+        max_to_keep=keep_checkpoints,
+        save_interval_steps=1)
+
+    # -- data + state bring-up ---------------------------------------------
+    # Host-overlap tuning flows trainer -> generator -> RecordBatchPipeline
+    # (generators without a record pipeline accept and ignore it).
+    for gen in (input_generator_train, input_generator_eval):
+      if gen is not None and hasattr(gen, "set_overlap_options"):
+        gen.set_overlap_options(num_parallel_parses=host_overlap_workers,
+                                overlap_queue_mb=host_overlap_queue_mb)
+    if step_stats_every_n_steps is None:
+      # Per-step barriers are ~free on CPU; on an accelerator each
+      # measured window's host fetch serializes the dispatch/prefetch
+      # overlap, so default to the log cadence there.
+      step_stats_every_n_steps = (
+          1 if jax.devices()[0].platform == "cpu"
+          else max(int(log_every_n_steps), 1))
+    step_stats = stepstats_lib.StepStatsRecorder(
+        batch_size=(input_generator_train.batch_size if needs_train else 0),
+        every_n_steps=step_stats_every_n_steps if needs_train else 0)
+    if needs_train:
+      provide_input_generator_with_model_information(
+          input_generator_train, model, modes_lib.TRAIN)
+      train_dataset = input_generator_train.create_dataset(modes_lib.TRAIN)
+    # The loader behind the (possibly itertools-wrapped) train stream —
+    # closed in the loop's finally so its stage threads never outlive
+    # the run.
+    raw_train_dataset = train_dataset
+    if needs_eval:
+      provide_input_generator_with_model_information(
+          input_generator_eval, model, modes_lib.EVAL)
+
     if train_dataset is not None:
-      first_batch = next(train_dataset)
+      with tracer.span("setup/first_batch", cat="setup"):
+        first_batch = next(train_dataset)
       sample_features = first_batch["features"]
     else:
       # Eval-only modes: synthesize an init batch from the preprocessor's
@@ -415,31 +429,34 @@ def train_eval_model(
           model.preprocessor.get_out_feature_specification(modes_lib.EVAL),
           batch_size=input_generator_eval.batch_size, seed=seed)
 
-    state, shardings = ts.create_train_state(
-        model, jax.random.PRNGKey(seed), sample_features, mesh=mesh,
-        rules=partition_rules)
-    restored_step = manager.latest_step()
-    if restored_step is None and model.init_checkpoint:
-      # Warm start from a foreign checkpoint (pretrained towers etc.);
-      # only on fresh runs — a resume keeps its own weights.
-      merged, restored_paths = checkpoints_lib.warm_start_params(
-          jax.device_get(state.params), model.init_checkpoint,
-          filter_fn=model.init_checkpoint_filter)
-      state = state.replace(params=jax.device_put(
-          merged, jax.tree_util.tree_map(lambda x: x.sharding, state.params)))
-      logging.info("Warm-started %d param arrays from %s",
-                   len(restored_paths), model.init_checkpoint)
-    if restored_step is not None:
-      abstract = jax.tree_util.tree_map(
-          lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype,
-                                         sharding=x.sharding), state)
-      # step=None verified walk, NOT restore(latest_step()): a torn or
-      # corrupt newest step (crash mid-save — the canonical restart
-      # case) quarantines and falls back to the newest intact step; the
-      # explicit-step form would raise CheckpointCorruptionError here.
-      state = manager.restore(abstract_state=abstract)
-      logging.info("Resumed from checkpoint step %d",
-                   manager.last_restored_step)
+    with tracer.span("setup/create_state", cat="setup"):
+      state, shardings = ts.create_train_state(
+          model, jax.random.PRNGKey(seed), sample_features, mesh=mesh,
+          rules=partition_rules)
+    with tracer.span("setup/restore", cat="setup"):
+      restored_step = manager.latest_step()
+      if restored_step is None and model.init_checkpoint:
+        # Warm start from a foreign checkpoint (pretrained towers etc.);
+        # only on fresh runs — a resume keeps its own weights.
+        merged, restored_paths = checkpoints_lib.warm_start_params(
+            jax.device_get(state.params), model.init_checkpoint,
+            filter_fn=model.init_checkpoint_filter)
+        state = state.replace(params=jax.device_put(
+            merged,
+            jax.tree_util.tree_map(lambda x: x.sharding, state.params)))
+        logging.info("Warm-started %d param arrays from %s",
+                     len(restored_paths), model.init_checkpoint)
+      if restored_step is not None:
+        abstract = jax.tree_util.tree_map(
+            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype,
+                                           sharding=x.sharding), state)
+        # step=None verified walk, NOT restore(latest_step()): a torn or
+        # corrupt newest step (crash mid-save — the canonical restart
+        # case) quarantines and falls back to the newest intact step; the
+        # explicit-step form would raise CheckpointCorruptionError here.
+        state = manager.restore(abstract_state=abstract)
+        logging.info("Resumed from checkpoint step %d",
+                     manager.last_restored_step)
 
     run_memory: dict = {}
     sentinel = flight_recorder = None
@@ -483,9 +500,11 @@ def train_eval_model(
         step_stats.add_observer(sentinel.observe_step_record)
         hooks.append(hooks_lib.SentinelHook())
       try:
-        run_memory = xray_lib.memory_accounting(
-            state, batch=first_batch,
-            num_data_shards=int(mesh.shape.get("data", mesh.devices.size)))
+        with tracer.span("setup/memory_accounting", cat="setup"):
+          run_memory = xray_lib.memory_accounting(
+              state, batch=first_batch,
+              num_data_shards=int(mesh.shape.get("data",
+                                                 mesh.devices.size)))
       except Exception:  # noqa: BLE001 - telemetry never kills a run
         logging.exception("graftscope-xray: memory accounting failed")
 
@@ -496,8 +515,8 @@ def train_eval_model(
                                              else None),
                                  sentinel=sentinel,
                                  flight_recorder=flight_recorder)
-    for hook in hooks:
-      hook.begin(ctx)
+    with tracer.span("setup/hooks_begin", cat="setup"):
+      hooks_lib.call_hooks(hooks, "begin", ctx)
 
     final_metrics: dict = {}
     saved_steps = set(manager.all_steps())
@@ -505,10 +524,10 @@ def train_eval_model(
     def _checkpoint(step: int, force: bool = False) -> None:
       if step in saved_steps:
         return
-      if manager.save(step, state, force=force):
-        saved_steps.add(step)
-        for hook in hooks:
-          hook.after_checkpoint(ctx, step)
+      with tracer.span("train/checkpoint", cat="train"):
+        if manager.save(step, state, force=force):
+          saved_steps.add(step)
+          hooks_lib.call_hooks(hooks, "after_checkpoint", ctx, step)
 
     # -- evaluate-only modes --------------------------------------------------
     batch_spec = getattr(model, "batch_partition_spec", None)
@@ -596,34 +615,37 @@ def train_eval_model(
       return final_metrics
 
     # -- training loop --------------------------------------------------------
-    train_step = ts.make_train_step(model, mesh=mesh, shardings=shardings,
-                                    batch_spec=batch_spec)
-    loop_k = max(1, int(iterations_per_loop))
-    train_loop = loop_spec = None
-    if loop_k > 1:
-      train_loop = ts.make_train_loop(model, loop_k, mesh=mesh,
-                                      shardings=shardings,
+    with tracer.span("setup/make_steps", cat="setup"):
+      train_step = ts.make_train_step(model, mesh=mesh, shardings=shardings,
                                       batch_spec=batch_spec)
-      loop_spec = ts.loop_batch_spec(batch_spec)
-    if step_stats.enabled:
-      # Compile telemetry (obs.xray): the first dispatch AOT-compiles
-      # through analyze_jit — per-executable compile time, jaxpr size,
-      # donation bytes, XLA cost/memory analysis into the run record —
-      # and every later call runs the SAME executable (no double compile;
-      # any failure degrades to the plain jitted fn).
-      train_step = xray_lib.XrayedFunction("train_step", train_step,
-                                           cache=executable_cache)
-      if train_loop is not None:
-        train_loop = xray_lib.XrayedFunction(f"train_loop_k{loop_k}",
-                                             train_loop,
+      loop_k = max(1, int(iterations_per_loop))
+      train_loop = loop_spec = None
+      if loop_k > 1:
+        train_loop = ts.make_train_loop(model, loop_k, mesh=mesh,
+                                        shardings=shardings,
+                                        batch_spec=batch_spec)
+        loop_spec = ts.loop_batch_spec(batch_spec)
+      if step_stats.enabled:
+        # Compile telemetry (obs.xray): the first dispatch AOT-compiles
+        # through analyze_jit — per-executable compile time, jaxpr size,
+        # donation bytes, XLA cost/memory analysis into the run record —
+        # and every later call runs the SAME executable (no double compile;
+        # any failure degrades to the plain jitted fn).
+        train_step = xray_lib.XrayedFunction("train_step", train_step,
                                              cache=executable_cache)
-    eval_step = None
-    if mode == "train_and_evaluate":
-      eval_step = ts.make_eval_step(model, mesh=mesh, shardings=shardings,
-                                    batch_spec=batch_spec,
-                                    use_ema=use_ema_for_eval)
+        if train_loop is not None:
+          train_loop = xray_lib.XrayedFunction(f"train_loop_k{loop_k}",
+                                               train_loop,
+                                               cache=executable_cache)
+      eval_step = None
+      if mode == "train_and_evaluate":
+        eval_step = ts.make_eval_step(model, mesh=mesh, shardings=shardings,
+                                      batch_spec=batch_spec,
+                                      use_ema=use_ema_for_eval)
 
   except BaseException:
+    if telemetry and not tracer_preenabled:
+      trace_lib.disable()
     _close_dataset(raw_train_dataset)
     raise
   step = int(state.step)
@@ -720,10 +742,8 @@ def train_eval_model(
     return (mesh_lib.place_batch(
         mesh, batch, batch_spec=loop_spec if k > 1 else batch_spec), k)
 
-  tracer_preenabled = trace_lib.get_tracer().enabled
+  iteration_span = None  # the open `train/iteration`, for the finally
   try:
-    if step_stats.enabled:
-      trace_lib.enable()
     if flight_recorder is not None:
       # Arms the host-state-only SIGTERM handler and (when configured) the
       # hang watchdog for exactly the loop's lifetime.
@@ -765,6 +785,11 @@ def train_eval_model(
         flight_recorder.touch()
       features, labels = placed
       prev_step = step
+      # One parent span an iteration; every span the loop, stepstats, the
+      # hooks and the summary writer open until its close is below it and
+      # carries its `step` (the last step this dispatch trains).
+      iteration_span = tracer.open("train/iteration", cat="train",
+                                   step=step + placed_k, k=placed_k)
       step_stats.before_dispatch()
       if placed_k > 1:
         state, stacked = train_loop(state, features, labels)
@@ -801,7 +826,7 @@ def train_eval_model(
           stream_exhausted = True
       # Measured-window close (barrier at the stepstats cadence) sits
       # AFTER next-batch staging — overlap preserved — and BEFORE the
-      # per-step metrics fetch, so device_ms absorbs the device wait
+      # per-step metrics fetch, so device_wait_ms absorbs the device wait
       # and the fetch below stays cheap.
       step_stats.end_step(step, state, num_steps=step - prev_step)
       if step - prev_step > 1:
@@ -812,30 +837,33 @@ def train_eval_model(
       else:
         per_step = [metrics]
       for i, m in enumerate(per_step):
-        for hook in hooks:
-          hook.after_step(ctx, prev_step + i + 1, m)
+        hooks_lib.call_hooks(hooks, "after_step", ctx, prev_step + i + 1, m)
       metrics = per_step[-1]
       if _crossed(log_every_n_steps, prev_step, step) \
           or step == max_train_steps:
-        scalars = {k: float(np.asarray(v)) for k, v in metrics.items()}
-        if faultlab_lib.maybe_fire(faultlab_lib.TRAIN_NONFINITE) is not None:
-          # Chaos seam: poison the host-side loss scalar exactly where
-          # a real divergence would surface — the sentinel's non-finite
-          # detector and the rewind below see the same signal either way.
-          scalars["loss"] = float("nan")
-        if sentinel is not None:
-          # The scalars were JUST fetched for logging anyway — the
-          # non-finite check rides that fetch for free (the hook path
-          # skips live device arrays by design).
-          sentinel.observe_metrics(step, scalars)
-        writer.write_scalars(step, scalars)
-        now = time.time()
-        logging.info("step %d: loss=%.5f (%.1f steps/s)", step,
-                     scalars.get("loss", float("nan")),
-                     (step - last_log_step) / max(now - last_log, 1e-6))
-        last_log = now
-        last_log_step = step
-        final_metrics = scalars
+        with tracer.span("train/log", cat="train"):
+          with tracer.span("train/log/fetch", cat="train"):
+            scalars = {k: float(np.asarray(v)) for k, v in metrics.items()}
+          if faultlab_lib.maybe_fire(
+              faultlab_lib.TRAIN_NONFINITE) is not None:
+            # Chaos seam: poison the host-side loss scalar exactly where
+            # a real divergence would surface — the sentinel's non-finite
+            # detector and the rewind below see the same signal either
+            # way.
+            scalars["loss"] = float("nan")
+          if sentinel is not None:
+            # The scalars were JUST fetched for logging anyway — the
+            # non-finite check rides that fetch for free (the hook path
+            # skips live device arrays by design).
+            sentinel.observe_metrics(step, scalars)
+          writer.write_scalars(step, scalars)
+          now = time.time()
+          logging.info("step %d: loss=%.5f (%.1f steps/s)", step,
+                       scalars.get("loss", float("nan")),
+                       (step - last_log_step) / max(now - last_log, 1e-6))
+          last_log = now
+          last_log_step = step
+          final_metrics = scalars
       if rewind_state["pending"]:
         # Divergence rewind (docstring): restore the newest VERIFIED
         # checkpoint and continue, instead of dying on a NaN. Sits
@@ -844,6 +872,7 @@ def train_eval_model(
         # is already on disk (flight-recorder sink runs first).
         rewind_state["pending"] = False
         rewind_state["count"] += 1
+        rewind_span = tracer.open("train/rewind", cat="train")
         rewind_started = time.perf_counter()
         # Commit in-flight async saves first: the newest checkpoint may
         # still be a tmp-named dir, invisible to the verified walk, and
@@ -882,13 +911,12 @@ def train_eval_model(
         saved_steps.intersection_update(manager.all_steps())
         rewind_state["targets"].append(step)
         metrics_registry_lib.counter("train/rewinds").inc()
-        for hook in hooks:
-          # Rewind coordination (graftloop): hooks learn the learner
-          # stepped back to `step` — a publish hook must drop pending
-          # publishes above it (those steps are quarantined or about to
-          # be re-trained) while collection keeps serving the last
-          # verified version.
-          hook.after_rewind(ctx, step)
+        # Rewind coordination (graftloop): hooks learn the learner
+        # stepped back to `step` — a publish hook must drop pending
+        # publishes above it (those steps are quarantined or about to
+        # be re-trained) while collection keeps serving the last
+        # verified version.
+        hooks_lib.call_hooks(hooks, "after_rewind", ctx, step)
         # Fresh, deterministically re-seeded stream: a rewound run and
         # a clean resume from the same checkpoint consume the same
         # records (the chaos bench's numerical-parity pin).
@@ -916,6 +944,8 @@ def train_eval_model(
           sentinel.reset_nonfinite_latch()
         if flight_recorder is not None:
           flight_recorder.touch()  # a restore is legitimate non-train time
+        rewind_span.close()
+        iteration_span.close()
         continue
       if _crossed(checkpoint_every_n_steps, prev_step, step):
         _checkpoint(step)
@@ -936,19 +966,21 @@ def train_eval_model(
                      and now - last_eval_time < eval_throttle_secs)
         if not throttled:
           last_eval_time = now
-          eval_loop = _eval_loop()  # compile (or fetch) BEFORE the
-          # dataset spins up its loader threads: a compile failure must
-          # not leak a just-created loader.
-          eval_dataset = input_generator_eval.create_dataset(modes_lib.EVAL)
-          eval_metrics = _run_eval(eval_step, state, eval_dataset, mesh,
-                                   eval_steps, batch_spec,
-                                   prefetch_depth=device_prefetch_depth,
-                                   eval_loop=eval_loop,
-                                   eval_loop_k=eval_loop_k)
-          writer.write_scalars(step, {f"eval/{k}": v
-                                      for k, v in eval_metrics.items()})
-          for hook in hooks:
-            hook.after_eval(ctx, step, eval_metrics)
+          with tracer.span("train/eval", cat="train"):
+            eval_loop = _eval_loop()  # compile (or fetch) BEFORE the
+            # dataset spins up its loader threads: a compile failure must
+            # not leak a just-created loader.
+            eval_dataset = input_generator_eval.create_dataset(
+                modes_lib.EVAL)
+            eval_metrics = _run_eval(eval_step, state, eval_dataset, mesh,
+                                     eval_steps, batch_spec,
+                                     prefetch_depth=device_prefetch_depth,
+                                     eval_loop=eval_loop,
+                                     eval_loop_k=eval_loop_k)
+            writer.write_scalars(step, {f"eval/{k}": v
+                                        for k, v in eval_metrics.items()})
+            hooks_lib.call_hooks(hooks, "after_eval", ctx, step,
+                                 eval_metrics)
           logging.info("eval @%d: %s", step, eval_metrics)
           final_metrics.update(
               {f"eval/{k}": v for k, v in eval_metrics.items()})
@@ -957,6 +989,7 @@ def train_eval_model(
             # watchdog so only a REAL stall past the timeout dumps.
             # (Pick watchdog_timeout_secs above the longest eval.)
             flight_recorder.touch()
+      iteration_span.close()
       if stream_exhausted:
         raise StopIteration(
             f"finite train stream exhausted after step {step}")
@@ -978,6 +1011,8 @@ def train_eval_model(
     # StepStatsHook.end's save on the normal path).
     if flight_recorder is not None:
       flight_recorder.close()  # disarm watchdog + restore SIGTERM
+    if iteration_span is not None:
+      iteration_span.close()  # a raise left it open; closed twice is fine
     if step_stats.enabled and not tracer_preenabled:
       # Only disarm a tracer THIS run armed: when a longer-lived owner
       # enabled it before entry (the graftloop's graftrace exporter

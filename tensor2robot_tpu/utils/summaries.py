@@ -27,6 +27,7 @@ from typing import Dict, Mapping, Optional, Set
 import numpy as np
 
 from tensor2robot_tpu.obs import metrics as obs_metrics
+from tensor2robot_tpu.obs import trace as trace_lib
 
 __all__ = ["SummaryWriter"]
 
@@ -43,6 +44,14 @@ class SummaryWriter:
         import tensorflow as tf  # heavyweight; optional mirror only
 
         self._tb = tf.summary.create_file_writer(log_dir)
+        with self._tb.as_default(), tf.summary.record_if(False):
+          # TensorFlow resolves `tf.summary.scalar` lazily: the first call
+          # imports ~110 modules and walks every installed distribution's
+          # metadata, 0.5-1.4 s on the v5e's host (PERF.md, PR 26). Paid
+          # here, with the rest of a run's set-up, and not behind the
+          # trainer's first stepstats record, where the device waits for
+          # it. Nothing is written.
+          tf.summary.scalar("first_use", 0.0, step=0)
       except Exception:  # pragma: no cover - TF missing or broken
         self._tb = None
 
@@ -87,18 +96,22 @@ class SummaryWriter:
     return out
 
   def write_scalars(self, step: int, scalars: Mapping[str, float]) -> None:
-    record: Dict[str, float] = {"step": int(step), "time": time.time()}
-    record.update(self._clean(scalars))
-    self._file.write(json.dumps(record) + "\n")
-    self._file.flush()
-    if self._tb is not None:
-      with self._tb.as_default():
-        import tensorflow as tf
+    tracer = trace_lib.get_tracer()
+    with tracer.span("summary/write", cat="summary"):
+      with tracer.span("summary/jsonl", cat="summary"):
+        record: Dict[str, float] = {"step": int(step), "time": time.time()}
+        record.update(self._clean(scalars))
+        self._file.write(json.dumps(record) + "\n")
+        self._file.flush()
+      if self._tb is not None:
+        with tracer.span("summary/tensorboard", cat="summary"), \
+            self._tb.as_default():
+          import tensorflow as tf
 
-        for key, value in record.items():
-          if key not in ("step", "time"):
-            tf.summary.scalar(key, value, step=int(step))
-        self._tb.flush()
+          for key, value in record.items():
+            if key not in ("step", "time"):
+              tf.summary.scalar(key, value, step=int(step))
+          self._tb.flush()
 
   def close(self) -> None:
     if not self._file.closed:

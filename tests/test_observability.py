@@ -10,7 +10,7 @@ Contracts:
 * histogram percentiles match numpy exactly while the reservoir holds
   every observation;
 * a CPU-mesh `train_eval_model` run writes per-step `data_wait_ms`,
-  `device_ms` and `examples_per_sec` records to `metrics.jsonl`, saves
+  `device_wait_ms` and `examples_per_sec` records to `metrics.jsonl`, saves
   a trace, and `python -m tensor2robot_tpu.bin.graftscope <model_dir>`
   renders a non-empty report from them;
 * `tensor2robot_tpu.obs` (and the CLI) import and run under a poisoned
@@ -141,6 +141,134 @@ class TestTracer:
     spans = [e for e in tracer.events() if e["ph"] == "X"]
     assert len(spans) == 10
     assert spans[-1]["name"] == "s49"  # oldest dropped, newest kept
+
+  def test_events_carry_id_parent_and_step(self):
+    tracer = trace_lib.Tracer()
+    tracer.enable()
+    with tracer.span("root"):
+      pass
+    with tracer.span("train/iteration", step=7, k=1):
+      with tracer.span("train/dispatch"):
+        with tracer.span("xray/analyze"):
+          pass
+      tracer.add_complete("train/compile_dispatch", 10, 5)
+      tracer.instant("mark")
+    events = {e["name"]: e for e in tracer.events() if e["ph"] != "M"}
+    ids = [e["id"] for e in events.values()]
+    assert len(set(ids)) == len(ids) == 6
+    assert "parent" not in events["root"] and "step" not in events["root"]
+    iteration = events["train/iteration"]
+    assert "parent" not in iteration and iteration["step"] == 7
+    assert iteration["args"] == {"step": 7, "k": 1}
+    assert events["train/dispatch"]["parent"] == iteration["id"]
+    assert events["xray/analyze"]["parent"] == events["train/dispatch"]["id"]
+    # Externally timed windows and instants hang under the open span too.
+    assert events["train/compile_dispatch"]["parent"] == iteration["id"]
+    assert events["mark"]["parent"] == iteration["id"]
+    for name in ("train/dispatch", "xray/analyze", "train/compile_dispatch",
+                 "mark"):
+      assert events[name]["step"] == 7, name
+
+  def test_a_thread_starts_at_its_own_root(self):
+    tracer = trace_lib.Tracer()
+    tracer.enable()
+
+    def work():
+      with tracer.span("data/place", bytes=3):
+        with tracer.span("data/inner"):
+          pass
+
+    with tracer.span("train/iteration", step=1):
+      t = threading.Thread(target=work, name="device-prefetch")
+      t.start()
+      t.join()
+    events = {e["name"]: e for e in tracer.events() if e["ph"] == "X"}
+    # The worker's span began while the loop's was open, on another
+    # thread: it is a root there, and has no step.
+    assert "parent" not in events["data/place"]
+    assert "step" not in events["data/place"]
+    assert events["data/inner"]["parent"] == events["data/place"]["id"]
+
+  def test_open_close_and_a_child_left_open(self):
+    tracer = trace_lib.Tracer()
+    tracer.enable()
+    iteration = tracer.open("train/iteration", step=2)
+    tracer.open("train/dispatch")      # an exception skipped its close
+    iteration.close()
+    iteration.close()                  # the loop's finally: no second event
+    with tracer.span("next"):
+      pass
+    events = [e for e in tracer.events() if e["ph"] == "X"]
+    assert sorted(e["name"] for e in events) == [
+        "next", "train/dispatch", "train/iteration"]
+    by_name = {e["name"]: e for e in events}
+    assert by_name["train/dispatch"]["parent"] == \
+        by_name["train/iteration"]["id"]
+    assert "parent" not in by_name["next"]   # the stack is clean again
+    # A disabled tracer hands out the shared no-op span.
+    tracer.disable()
+    tracer.open("off").close()
+    assert len([e for e in tracer.events() if e["ph"] == "X"]) == 3
+
+  def test_clear_forgets_spans_left_open(self):
+    tracer = trace_lib.Tracer()
+    tracer.enable()
+    tracer.open("abandoned")
+    tracer.clear()
+    with tracer.span("fresh"):
+      pass
+    (event,) = [e for e in tracer.events() if e["ph"] == "X"]
+    assert event["name"] == "fresh" and "parent" not in event
+
+  def test_save_writes_the_clock_anchor(self, tmp_path):
+    tracer = trace_lib.Tracer()
+    assert tracer.anchor is None
+    before = (time.perf_counter_ns(), time.time_ns())
+    tracer.enable()
+    after = (time.perf_counter_ns(), time.time_ns())
+    with tracer.span("a"):
+      pass
+    with open(tracer.save(str(tmp_path / "trace.json"))) as f:
+      payload = json.load(f)
+    anchor = payload["metadata"]["clock_anchor"]
+    assert anchor == tracer.anchor
+    assert before[0] <= anchor["perf_counter_ns"] <= after[0]
+    assert before[1] <= anchor["time_ns"] <= after[1]
+    tracer.clear()
+    assert tracer.anchor == anchor  # the anchor is the tracer's, not the ring's
+
+  def test_profiler_annotation_only_while_a_session_runs(self, tmp_path):
+    import glob
+
+    import jax
+
+    tracer = trace_lib.Tracer()
+    tracer.enable()
+    with tracer.span("before/session"):
+      pass
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+      with tracer.span("train/iteration", step=3):
+        with tracer.span("train/dispatch"):
+          pass
+      worker = threading.Thread(
+          target=lambda: tracer.open("data/place", bytes=8).close())
+      worker.start()
+      worker.join()
+    finally:
+      jax.profiler.stop_trace()
+    with tracer.span("after/session"):
+      pass
+    (path,) = glob.glob(str(tmp_path / "plugins" / "profile" / "*"
+                            / "*.xplane.pb"))
+    data = jax.profiler.ProfileData.from_file(path)
+    host = {e.name for plane in data.planes
+            if plane.name.startswith("/host:")
+            for line in plane.lines for e in line.events}
+    assert {"train/iteration", "train/dispatch", "data/place"} <= host
+    assert "before/session" not in host and "after/session" not in host
+    # The ring holds all five either way.
+    assert len([e for e in tracer.events() if e["ph"] == "X"]) == 5
 
   def test_traced_decorator(self):
     tracer = trace_lib.Tracer()
@@ -300,6 +428,31 @@ class TestSummaryWriter:
       text = f.read()
     assert "NaN" not in text and "Infinity" not in text
 
+  def test_tensorboard_first_use_is_paid_at_open(self, tmp_path):
+    """Opening the writer resolves TensorFlow's lazy summary ops (so the
+    first write imports nothing), records no span of its own (the trainer
+    wraps it in `setup/writer`) and writes nothing; a write is
+    `summary/write` with one child for the JSONL line and one for the
+    TensorBoard mirror."""
+    pytest.importorskip("tensorflow")
+    from tensorflow.python.summary.summary_iterator import summary_iterator
+
+    trace_lib.enable()
+    with summaries_lib.SummaryWriter(str(tmp_path)) as writer:
+      assert trace_lib.get_tracer().events() == []
+      loaded = set(sys.modules)
+      writer.write_scalars(3, {"loss": 0.5})
+      assert set(sys.modules) == loaded
+    events = {e["name"]: e for e in trace_lib.get_tracer().events()
+              if e["ph"] == "X"}
+    write = events["summary/write"]
+    assert events["summary/jsonl"]["parent"] == write["id"]
+    assert events["summary/tensorboard"]["parent"] == write["id"]
+    tags = [value.tag for path in tmp_path.glob("events.out.tfevents.*")
+            for event in summary_iterator(str(path))
+            for value in event.summary.value]
+    assert tags == ["loss"]
+
   def test_scalar_shapes_still_accepted(self, tmp_path):
     writer = summaries_lib.SummaryWriter(str(tmp_path),
                                          use_tensorboard=False)
@@ -338,11 +491,11 @@ class TestStepStats:
     assert [step for step, _ in records] == [1, 2, 3]
     assert barriers == ["fake-state"] * 3
     for _, r in records:
-      for key in ("data_wait_ms", "device_ms", "examples_per_sec",
+      for key in ("data_wait_ms", "device_wait_ms", "examples_per_sec",
                   "step_ms", "host_ms", "dispatch_ms", "compile"):
         assert key in r, r
       assert r["data_wait_ms"] >= 1.5      # the 2 ms staging sleep
-      assert r["device_ms"] >= 0.5         # the 1 ms dispatch sleep
+      assert r["device_wait_ms"] >= 0.5    # the 1 ms dispatch sleep
       assert r["step_ms"] >= r["data_wait_ms"]
       assert r["examples_per_sec"] > 0
     # First dispatch is always a compile event; steady steps are not.
@@ -398,9 +551,45 @@ class TestStepStats:
     self._run_steps(rec, 2)
     snap = metrics_lib.snapshot()
     assert snap["hist/stepstats/step_ms/count"] == 2.0
-    assert "gauge/stepstats/examples_per_sec" in snap
+    assert snap["hist/stepstats/examples_per_sec/count"] == 2.0
     names = {e["name"] for e in trace_lib.get_tracer().events()}
     assert {"train/step_window", "train/data_wait"} <= names
+
+  def test_record_path_is_a_span_tree(self):
+    trace_lib.enable()
+    rec = stepstats_lib.StepStatsRecorder(
+        batch_size=8, every_n_steps=2, barrier=lambda s: None,
+        device_gauges=False)
+
+    def watcher(step, record):
+      del step, record
+
+    rec.add_observer(watcher)
+    rec.start()
+    tracer = trace_lib.get_tracer()
+    for step in (1, 2):
+      with tracer.span("train/iteration", step=step):
+        self._run_steps(rec, 1)
+    events = [e for e in tracer.events() if e["ph"] == "X"]
+    by_id = {e["id"]: e for e in events}
+
+    def parent_name(event):
+      return by_id[event["parent"]]["name"] if "parent" in event else None
+
+    names = [e["name"] for e in events]
+    # Two dispatches and two waits, one barrier and one record (cadence 2).
+    assert names.count("train/dispatch") == names.count(
+        "train/data_wait") == 2
+    assert names.count("train/barrier") == names.count("train/record") == 1
+    for event in events:
+      if event["name"] in ("train/dispatch", "train/data_wait",
+                           "train/barrier", "train/record"):
+        assert parent_name(event) == "train/iteration", event
+      if event["name"] in ("train/record/gauges", "train/record/observer"):
+        assert parent_name(event) == "train/record", event
+    (observer,) = [e for e in events if e["name"] == "train/record/observer"]
+    assert observer["args"]["observer"].endswith("watcher")
+    assert observer["step"] == 2
 
 
 # ---------------------------------------------------------------------------
@@ -526,12 +715,12 @@ def _clean_config():
 class TestTrainLoopStepStats:
 
   def _train(self, model_dir, **kwargs):
+    kwargs.setdefault("checkpoint_every_n_steps", 100)
     return train_eval.train_eval_model(
         model=mocks.MockT2RModel(device_type="cpu"),
         model_dir=model_dir,
         mode="train",
         max_train_steps=6,
-        checkpoint_every_n_steps=100,
         input_generator_train=mocks.MockInputGenerator(batch_size=8),
         log_every_n_steps=2,
         **kwargs)
@@ -542,7 +731,7 @@ class TestTrainLoopStepStats:
     with open(path) as f:
       records = [json.loads(line) for line in f if line.strip()]
     return records, [r for r in records
-                     if all(k in r for k in ("data_wait_ms", "device_ms",
+                     if all(k in r for k in ("data_wait_ms", "device_wait_ms",
                                              "examples_per_sec"))]
 
   def test_train_run_emits_per_step_stepstats_trace_and_report(
@@ -550,10 +739,10 @@ class TestTrainLoopStepStats:
     model_dir = str(tmp_path / "run")
     self._train(model_dir)
     records, step_records = self._stepstats_records(model_dir)
-    # Acceptance: per-step data_wait_ms / device_ms / examples_per_sec.
+    # Acceptance: per-step data_wait_ms / device_wait_ms / examples_per_sec.
     assert [r["step"] for r in step_records] == [1, 2, 3, 4, 5, 6]
     for r in step_records:
-      assert r["data_wait_ms"] >= 0 and r["device_ms"] >= 0
+      assert r["data_wait_ms"] >= 0 and r["device_wait_ms"] >= 0
       assert r["examples_per_sec"] > 0
       assert math.isfinite(r["step_ms"])
     assert step_records[0]["compile"] == 1.0  # first dispatch compiles
@@ -582,10 +771,79 @@ class TestTrainLoopStepStats:
     assert graftscope.main([model_dir]) == 0
     out = capsys.readouterr().out
     assert "step-time breakdown" in out
-    assert "data_wait_ms" in out and "device_ms" in out
+    assert "data_wait_ms" in out and "device_wait_ms" in out
     assert "train/step_window" in out  # slowest-spans table
     assert "compile events: " in out
     assert "run history" in out and "xray compile telemetry" in out
+
+  def test_train_run_saves_one_span_tree(self, tmp_path):
+    """The saved trace is a tree: bring-up under `setup/*` once and in
+    order, one `train/iteration` a step with the loop's work below it,
+    the hooks and the summary writer named, the anchor beside it."""
+    model_dir = str(tmp_path / "run")
+    self._train(model_dir, checkpoint_every_n_steps=4)
+    with open(os.path.join(model_dir, "train",
+                           "trace.graftscope.json")) as f:
+      payload = json.load(f)
+    assert set(payload["metadata"]["clock_anchor"]) == {
+        "perf_counter_ns", "time_ns"}
+    events = [e for e in payload["traceEvents"] if e.get("ph") == "X"]
+    by_id = {e["id"]: e for e in events}
+    setup = [e["name"] for e in sorted(events, key=lambda e: e["ts"])
+             if e["name"].startswith("setup/")]
+    assert setup == ["setup/writer", "setup/first_batch",
+                     "setup/create_state", "setup/restore",
+                     "setup/memory_accounting", "setup/hooks_begin",
+                     "setup/make_steps"]
+    iterations = [e for e in events if e["name"] == "train/iteration"]
+    assert [e["step"] for e in iterations] == [1, 2, 3, 4, 5, 6]
+    assert all("parent" not in e for e in iterations)
+    for name in ("train/dispatch", "train/barrier", "train/record"):
+      chosen = [e for e in events if e["name"] == name]
+      assert [e["step"] for e in chosen] == [1, 2, 3, 4, 5, 6], name
+      assert all(by_id[e["parent"]]["name"] == "train/iteration"
+                 for e in chosen), name
+    # Every child lies inside its parent (the step window is an
+    # externally timed span over several iterations: the one exception).
+    for e in events:
+      if "parent" in e and e["name"] != "train/step_window":
+        parent = by_id[e["parent"]]
+        assert parent["ts"] <= e["ts"] + 1e-3, (e, parent)
+        assert e["ts"] + e["dur"] <= parent["ts"] + parent["dur"] + 1e-3, (
+            e, parent)
+    # Step 1's dispatch holds xray's compile-or-cache-load as its child
+    # (loaded where an earlier test of this process stored it).
+    first = next(e for e in events
+                 if e["name"] == "train/dispatch" and e["step"] == 1)
+    analyze = next(e for e in events if e["name"] == "xray/analyze")
+    assert analyze["parent"] == first["id"] and analyze["step"] == 1
+    assert analyze["args"]["executable"] == "train_step"
+    (record,) = [r for r in xray_lib.records() if r["name"] == "train_step"]
+    assert analyze["args"]["cache_hit"] == bool(
+        (record.get("cache") or {}).get("hit"))
+    hooks = [e for e in events if e["name"] == "train/hook"
+             and e["args"]["method"] == "after_step"]
+    assert {e["args"]["hook"] for e in hooks} == {"StepStatsHook",
+                                                  "SentinelHook"}
+    assert len(hooks) == 2 * 6
+    # The log (every 2 steps) fetches, then writes: jsonl and the mirror.
+    logs = [e for e in events if e["name"] == "train/log"]
+    assert [e["step"] for e in logs] == [2, 4, 6]
+    for log in logs:
+      below = {e["name"] for e in events if e.get("parent") == log["id"]}
+      assert {"train/log/fetch", "summary/write"} <= below
+    writes = [e for e in events if e["name"] == "summary/write"]
+    assert all({"summary/jsonl"} <= {c["name"] for c in events
+                                    if c.get("parent") == w["id"]}
+               for w in writes)
+    (checkpoint,) = [e for e in events if e["name"] == "train/checkpoint"]
+    assert checkpoint["step"] == 4
+    # The prefetcher's thread has its own roots.
+    places = [e for e in events if e["name"] == "data/place"]
+    assert places and all("parent" not in e for e in places)
+    assert all(e["args"]["bytes"] > 0 for e in places)
+    loop_tid = iterations[0]["tid"]
+    assert all(e["tid"] != loop_tid for e in places)
 
   def test_step_stats_disabled_leaves_stream_clean(self, tmp_path):
     model_dir = str(tmp_path / "off")
@@ -621,13 +879,45 @@ class TestTrainLoopStepStats:
     assert graftscope.main(["history", str(empty)]) == 2
     capsys.readouterr()
 
+  @pytest.mark.parametrize("key", ["device_ms", "device_wait_ms"])
+  def test_graftscope_reads_step_records_across_the_rename(
+      self, tmp_path, capsys, key):
+    """Stepstats' `device_ms` became `device_wait_ms`: a `metrics.jsonl`
+    written before the rename reads under the new name."""
+    log_dir = tmp_path / "run" / "train"
+    log_dir.mkdir(parents=True)
+    (log_dir / "metrics.jsonl").write_text("".join(
+        json.dumps({"step": step, "data_wait_ms": 1.0, key: 2.5,
+                    "examples_per_sec": 3.0, "step_ms": 4.0}) + "\n"
+        for step in (1, 2)))
+    assert graftscope.main([str(tmp_path / "run")]) == 0
+    out = capsys.readouterr().out
+    assert "step-time breakdown (2 records" in out
+    (row,) = [line for line in out.splitlines()
+              if line.strip().startswith("device_wait_ms")]
+    assert "2.50" in row
+
+  def test_run_record_step_stats_read_under_the_new_name(self, tmp_path):
+    runs = str(tmp_path / "runs.jsonl")
+    runlog_lib.append_record(runs, runlog_lib.make_record(
+        "train", step_stats={"device_ms_mean": 7.0, "step_ms_mean": 9.0}))
+    runlog_lib.append_record(runs, runlog_lib.make_record(
+        "train", step_stats=runlog_lib.step_stats_summary(
+            {"hist/stepstats/device_wait_ms/mean": 8.0,
+             "hist/stepstats/step_ms/mean": 9.0})))
+    old, new = runlog_lib.load_records(runs)
+    assert old["step_stats"]["device_wait_ms_mean"] == 7.0
+    assert "device_ms_mean" not in old["step_stats"]
+    assert new["step_stats"]["device_wait_ms_mean"] == 8.0
+    assert runlog_lib.diff_records(old, new)  # still comparable
+
   def test_graftscope_tolerates_corrupt_telemetry(self, tmp_path, capsys):
     """ISSUE 3 satellite: truncated/corrupt metrics.jsonl and
     trace.json content is skipped with a warning counter — the reader
     must still render a report from the surviving records."""
     log_dir = tmp_path / "run" / "train"
     log_dir.mkdir(parents=True)
-    good = {"step": 1, "data_wait_ms": 1.0, "device_ms": 2.0,
+    good = {"step": 1, "data_wait_ms": 1.0, "device_wait_ms": 2.0,
             "examples_per_sec": 3.0, "step_ms": 4.0}
     (log_dir / "metrics.jsonl").write_text(
         json.dumps(good) + "\n"
@@ -669,7 +959,7 @@ trace.save(sys.argv[1] + "/t/trace.graftscope.json")
 from tensor2robot_tpu.utils import summaries
 w = summaries.SummaryWriter(sys.argv[1] + "/t", use_tensorboard=False)
 w.write_scalars(1, dict(metrics.snapshot(),
-                        data_wait_ms=1.0, device_ms=2.0,
+                        data_wait_ms=1.0, device_wait_ms=2.0,
                         examples_per_sec=3.0))
 w.close()
 runs = sys.argv[1] + "/runs.jsonl"

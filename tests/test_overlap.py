@@ -314,6 +314,39 @@ class TestDevicePrefetcherGeneralized:
     assert [g[0] for g in got] == ["placed"] * 4
     np.testing.assert_array_equal(got[2][1]["x"], items[2]["x"])
 
+  @pytest.mark.parametrize("overlap_place", [False, True])
+  def test_spans_per_batch_only_under_a_live_tracer(self, overlap_place):
+    """`data/next_host` and `data/place` (arg `bytes`), one a batch and on
+    the workers' own threads; a tracer that is off records nothing, and
+    the placement histogram counts either way."""
+    from tensor2robot_tpu.obs import trace as trace_lib
+    from tensor2robot_tpu.parallel import mesh as mesh_lib
+
+    def run():
+      metrics_lib.reset()
+      items = [{"x": np.full((2,), i, np.float32)} for i in range(4)]
+      assert len(list(mesh_lib.DevicePrefetcher(
+          iter(items), place_fn=lambda b: b,
+          overlap_place=overlap_place))) == 4
+      assert metrics_lib.snapshot()[
+          "hist/data/overlap_place_ms/count"] == 4.0
+      return trace_lib.get_tracer().events()
+
+    trace_lib.clear()
+    assert run() == []
+    trace_lib.enable()
+    try:
+      events = [e for e in run() if e["ph"] == "X"]
+    finally:
+      trace_lib.disable()
+      trace_lib.clear()
+    places = [e for e in events if e["name"] == "data/place"]
+    assert [e["args"]["bytes"] for e in places] == [8] * 4
+    # One more pull than batches: the one that finds the source empty.
+    assert len([e for e in events if e["name"] == "data/next_host"]) == 5
+    assert all("parent" not in e and e["tid"] != threading.get_ident()
+               for e in events)
+
   def test_requires_mesh_or_place_fn(self):
     from tensor2robot_tpu.parallel import mesh as mesh_lib
 
@@ -455,11 +488,11 @@ class TestDevicePrefetcherGeneralized:
 
 class TestStepStatsOverlapAttribution:
   """ISSUE 9 satellite: host work that overlaps device compute must
-  inflate NEITHER data_wait_ms NOR device_ms. Synthetic overlapped
+  inflate NEITHER data_wait_ms NOR device_wait_ms. Synthetic overlapped
   producer: each batch costs PRODUCE_MS of background host work, each
   "device step" BARRIER_MS at the closing barrier; the loop's
   data_wait wraps only the dequeue, so in steady state it reads ~0 and
-  device_ms reads ~BARRIER_MS."""
+  device_wait_ms reads ~BARRIER_MS."""
 
   PRODUCE_MS = 40.0
   BARRIER_MS = 70.0
@@ -512,16 +545,16 @@ class TestStepStatsOverlapAttribution:
     # window to hide behind yet).
     steady = records[1:]
     mean_wait = np.mean([r["data_wait_ms"] for r in steady])
-    mean_device = np.mean([r["device_ms"] for r in steady])
+    mean_device = np.mean([r["device_wait_ms"] for r in steady])
     # The producer's PRODUCE_MS/batch of host work ran DURING the
     # barrier window: data_wait must show only the residual dequeue
     # wait, far below the actual host cost...
     assert mean_wait < 0.5 * self.PRODUCE_MS, [
         r["data_wait_ms"] for r in steady]
-    # ...and device_ms must reflect the barrier, not barrier + host.
+    # ...and device_wait_ms must reflect the barrier, not barrier + host.
     assert mean_device >= 0.7 * self.BARRIER_MS
     assert mean_device < self.BARRIER_MS + 0.5 * self.PRODUCE_MS, [
-        r["device_ms"] for r in steady]
+        r["device_wait_ms"] for r in steady]
 
   def test_starved_consumer_shows_data_wait(self):
     """Inverse contract: when the producer CANNOT keep up (no device
