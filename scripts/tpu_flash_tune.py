@@ -1,12 +1,13 @@
 """On-chip flash-attention block-size duel at the shipped shape.
 
-The round-5 run (old setup, 2026-07) measured the Mosaic kernel SLOWER than plain XLA
-attention in full-step wall-clock (T=4096: 27.7 vs 23.3 ms/step;
-T=8192: 86.0 vs 72.8) while moving ~10x fewer bytes at ~7% HBM util —
-stall-bound, not bandwidth-bound. Suspect: the default 128x128 blocks
-(tiny MXU matmuls, VPU-softmax dominated). This probe times the raw
-kernel fwd and fwd+bwd across block combinations on the real chip and
-prints the winner vs the XLA reference attention at the same shape.
+Times the raw kernels, forward and forward+backward, across block
+combinations on the real chip and prints the winner beside plain XLA
+attention at the same shape. It times whole calls on the host's clock: the
+layout copies XLA puts around a lone kernel are in its numbers (a third of
+them at bh 1024, T 2048; PERF.md section 6, PR 27), so it ranks blocks and
+does not price a kernel. `ops/attention._DEFAULT_BLOCKS` is set from the
+whole train step, and a kernel's own time is read from a device trace
+(`benchmarks/run.py --trace 1`, `breakdown.device_ops`).
 
 Usage (needs a TPU: `chiprun -- python scripts/tpu_flash_tune.py [T]`):
   python scripts/tpu_flash_tune.py [T]        # default 4096

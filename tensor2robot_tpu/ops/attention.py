@@ -23,7 +23,7 @@ from __future__ import annotations
 
 import functools
 import math
-from typing import Optional, Tuple
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -82,31 +82,71 @@ def cached_attention(q_t: jnp.ndarray, k_cache: jnp.ndarray,
 # -- online-softmax block update (shared by flash + ring) -------------------
 
 
+def _sum_rides(d: int) -> bool:
+  """Whether the softmax denominator is taken from the p.v product.
+
+  A column of ones beside v makes the product sum each row of p as it
+  goes. Where the head is no multiple of the MXU's 128 columns the product
+  has idle output columns and the sum costs nothing, while a reduction
+  over the score tile's lanes is a pass through the XLU; where the head
+  fills the columns, the 129th costs the product another pass of the MXU.
+  Flash forward alone on the v5e (PERF.md section 6, PR 27; T 2048,
+  512 x 512 tiles, causal, bf16, bh x d = 65,536), ms a call with the sum
+  riding / reduced: d 64 12.05 / 13.68, d 128 8.26 / 6.85, d 256 6.30 /
+  5.69. The riding sum adds p as the product sees it, rounded to v's
+  dtype, with float32 accumulation like the numerator it divides: in bf16
+  the log-sum-exp is then within one rounding (2^-9) of the exact one
+  however long the row (0.0018 at most, 0.0001-0.0003 rms at T 2048).
+  """
+  return d % 128 != 0
+
+
+def _online_init(q):
+  """(m, l, o) before the first block, for q [..., Tq, D]: m and l are
+  [..., Tq, 1]; o has a last column for the denominator where it rides,
+  and l then stays unused."""
+  d = q.shape[-1]
+  m = jnp.full(q.shape[:-1] + (1,), -jnp.inf, jnp.float32)
+  l = jnp.zeros(q.shape[:-1] + (1,), jnp.float32)
+  o = jnp.zeros(q.shape[:-1] + (d + 1 if _sum_rides(d) else d,),
+                jnp.float32)
+  return m, l, o
+
+
 def _online_block_update(q, k_blk, v_blk, m_prev, l_prev, o_prev,
                          score_mask=None):
   """Absorbs one K/V block into the running (max, denom, output).
 
-  q: [..., Tq, D]; k_blk/v_blk: [..., Tk, D];
-  m_prev/l_prev: [..., Tq]; o_prev: [..., Tq, D] (unnormalized
-  numerator). Returns updated (m, l, o).
+  q: [..., Tq, D]; k_blk/v_blk: [..., Tk, D]; m_prev, l_prev, o_prev (the
+  unnormalized numerator) as `_online_init` shapes them. Returns updated
+  (m, l, o).
   """
-  scale = 1.0 / math.sqrt(q.shape[-1])
+  d = q.shape[-1]
   s = jnp.einsum("...qd,...kd->...qk", q, k_blk,
-                 preferred_element_type=jnp.float32) * scale
+                 preferred_element_type=jnp.float32) * (1.0 / math.sqrt(d))
   if score_mask is not None:
     s = jnp.where(score_mask, s, _mask_value(s.dtype))
-  m_new = jnp.maximum(m_prev, s.max(axis=-1))
+  m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
   alpha = jnp.exp(m_prev - m_new)
-  p = jnp.exp(s - m_new[..., None])
-  l_new = l_prev * alpha + p.sum(axis=-1)
-  o_new = (o_prev * alpha[..., None]
-           + jnp.einsum("...qk,...kd->...qd", p.astype(v_blk.dtype),
-                        v_blk, preferred_element_type=jnp.float32))
+  p = jnp.exp(s - m_new)
+  if _sum_rides(d):
+    l_new = l_prev
+    v_blk = jnp.concatenate(
+        [v_blk, jnp.ones(v_blk.shape[:-1] + (1,), v_blk.dtype)], axis=-1)
+  else:
+    l_new = l_prev * alpha + p.sum(axis=-1, keepdims=True)
+  o_new = o_prev * alpha + jnp.einsum(
+      "...qk,...kd->...qd", p.astype(v_blk.dtype), v_blk,
+      preferred_element_type=jnp.float32)
   return m_new, l_new, o_new
 
 
-def _finalize(o, l):
-  return o / jnp.maximum(l[..., None], 1e-30)
+def _normalize(l, o, d: int):
+  """(attention output, denominator) from the last block's (l, o)."""
+  if _sum_rides(d):
+    l, o = o[..., d:], o[..., :d]
+  l = jnp.maximum(l, 1e-30)
+  return o / l, l
 
 
 # -- Pallas flash attention --------------------------------------------------
@@ -117,18 +157,35 @@ def _finalize(o, l):
 # producing dK/dV over the k-block grid) — the [T, T] score matrix never
 # materializes in HBM in either direction. Sequences that don't tile are
 # PADDED to the block size and masked (never a silent O(T^2) fallback).
+#
+# What a tile costs on the v5e (PERF.md section 6, PR 27; bh 1024,
+# T 2048, d 64, 512 x 512 tiles, causal; each piece taken out of the
+# kernel in turn): the matrix products, and the passes that cross lanes.
+# A product that makes a [block, block] tile from a contraction over d
+# (q.k^T, dO.v^T) takes 0.62 us a tile whichever operand is transposed,
+# one that contracts over a block into [block, d] 0.31 us; a row maximum
+# of the tile 0.23 us, a row sum 0.28 us, a transpose of it 0.18 us. The
+# scale, the mask, the exp and every other elementwise pass hide under
+# them (taking each out moved no kernel by more than 2.5 %; a loop split
+# into masked and unmasked tiles cost 10 %), and so does the operands'
+# width: Mosaic feeds the MXU bf16 from float32 operands at default
+# precision, bit for bit what a cast gives. So the kernels keep the
+# elementwise passes they had and lose what crosses lanes: dK/dV works
+# on the transposed tile, the forward's row sum rides the p.v product
+# where a head leaves it idle columns (`_sum_rides`), and `delta` no
+# longer travels as a lane-padded column.
 
 
 def _valid_mask(q_start, k_start, q_block, k_block, causal: bool,
-                valid_len: int, padded_len: int):
-  """Score-entry validity: causal triangle + key/query padding."""
+                valid_len: int, padded_len: int, q_axis: int = 0):
+  """Score-entry validity: causal triangle + key/query padding. The tile
+  is [q_block, k_block], or its transpose where `q_axis` is 1."""
   if not causal and valid_len == padded_len:
     return None
-  q_pos = q_start + jax.lax.broadcasted_iota(
-      jnp.int32, (q_block, k_block), 0)
-  k_pos = k_start + jax.lax.broadcasted_iota(
-      jnp.int32, (q_block, k_block), 1)
-  mask = jnp.ones((q_block, k_block), bool)
+  shape = (q_block, k_block) if q_axis == 0 else (k_block, q_block)
+  q_pos = q_start + jax.lax.broadcasted_iota(jnp.int32, shape, q_axis)
+  k_pos = k_start + jax.lax.broadcasted_iota(jnp.int32, shape, 1 - q_axis)
+  mask = jnp.ones(shape, bool)
   if causal:
     mask &= q_pos >= k_pos
   if valid_len != padded_len:
@@ -151,18 +208,15 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *,
         ((tq_idx + 1) * q_block + block_k - 1) // block_k)
 
   def body(kb, carry):
-    m, l, o = carry
     k_blk = k_ref[pl.ds(kb * block_k, block_k), :]
     v_blk = v_ref[pl.ds(kb * block_k, block_k), :]
     mask = _valid_mask(tq_idx * q_block, kb * block_k, q_block, block_k,
                        causal, valid_len, seq_len)
-    return _online_block_update(q, k_blk, v_blk, m, l, o, mask)
+    return _online_block_update(q, k_blk, v_blk, *carry, mask)
 
-  m0 = jnp.full((q_block,), -jnp.inf, jnp.float32)
-  l0 = jnp.zeros((q_block,), jnp.float32)
-  o0 = jnp.zeros((q_block, q.shape[-1]), jnp.float32)
-  m, l, o = jax.lax.fori_loop(0, num_k_blocks, body, (m0, l0, o0))
-  o_ref[:] = _finalize(o, l).astype(o_ref.dtype)
+  m, l, o = jax.lax.fori_loop(0, num_k_blocks, body, _online_init(q))
+  out, l = _normalize(l, o, q.shape[-1])
+  o_ref[:] = out.astype(o_ref.dtype)
   # logsumexp per query row, stored [T, 1]: the trailing unit lane dim
   # keeps the block shape inside Mosaic's (8, 128)-divisible-or-whole
   # tiling rule for EVERY block_q (a [T]-flat lse blocked at block_q
@@ -179,19 +233,32 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *,
   q_pos = tq_idx * q_block + jax.lax.broadcasted_iota(
       jnp.int32, (q_block, 1), 0)
   row_valid = q_pos < valid_len
-  lse = (m + jnp.log(jnp.maximum(l, 1e-30)))[:, None]
-  lse_ref[:] = jnp.where(row_valid, lse, 0.0)
+  lse_ref[:] = jnp.where(row_valid, m + jnp.log(l), 0.0)
 
 
-def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+def _delta(do, o):
+  """delta_i = sum_d dO_id * O_id (FlashAttention-2's backward precompute),
+  float32 [..., T, 1]. The one definition both backward kernels use: dQ
+  calls it on its own block, `_flash_bwd` on the whole for dK/dV."""
+  return jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1,
+                 keepdims=True)
+
+
+def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref,
                          dq_ref, *, block_k: int, causal: bool,
                          q_block: int, valid_len: int):
-  """dQ for one q block: dS = P * (dO.V^T - delta); dQ = scale * dS.K."""
+  """dQ for one q block: dS = P * (dO.V^T - delta); dQ = scale * dS.K.
+
+  `delta` is taken here from the block's own rows of dO and O, once a
+  program (+0.29 ms a call): as an operand it would be a [T, 1] column,
+  which XLA keeps padded to 128 lanes (1 GB written a call at the
+  benchmark's shape, 1.42 ms).
+  """
   scale = 1.0 / math.sqrt(q_ref.shape[-1])
   q = q_ref[:]
   do = do_ref[:].astype(jnp.float32)
   lse = lse_ref[:]      # [block_q, 1]
-  delta = delta_ref[:]  # [block_q, 1]
+  delta = _delta(do, o_ref[:])
   tq_idx = pl.program_id(1)
   seq_len = k_ref.shape[0]
   num_k_blocks = seq_len // block_k
@@ -223,7 +290,16 @@ def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                           dk_ref, dv_ref, *, block_q: int, causal: bool,
                           k_block: int, valid_len: int):
-  """dK/dV for one k block: dV = P^T.dO; dK = scale * dS^T.Q."""
+  """dK/dV for one k block: dV = P^T.dO; dK = scale * dS^T.Q.
+
+  Works on the transposed tile: S^T = K.Q^T and dP^T = V.dO^T come out of
+  the MXU as [k_block, block_q], so P^T and dS^T feed the two
+  accumulating products as they are (transposing P and dS cost two
+  passes through the XLU a tile, a fifth of the kernel). `lse` and
+  `delta` arrive as lane-dense rows, [T // block_q, 1, block_q]: a
+  sublane broadcast a tile, and 16 KB of VMEM at T 2048 where the [T, 1]
+  columns, padded to 128 lanes, held 1 MB each.
+  """
   scale = 1.0 / math.sqrt(q_ref.shape[-1])
   k_blk = k_ref[:]
   v_blk = v_ref[:]
@@ -239,22 +315,17 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     dk, dv = carry
     q_blk = q_ref[pl.ds(qb * block_q, block_q), :]
     do_blk = do_ref[pl.ds(qb * block_q, block_q), :].astype(jnp.float32)
-    lse_blk = lse_ref[pl.ds(qb * block_q, block_q), :]    # [block_q, 1]
-    delta_blk = delta_ref[pl.ds(qb * block_q, block_q), :]
-    s = jnp.matmul(q_blk, k_blk.T,
-                   preferred_element_type=jnp.float32) * scale
-    p = jnp.exp(s - lse_blk)
+    st = jnp.matmul(k_blk, q_blk.T,
+                    preferred_element_type=jnp.float32) * scale
+    pt = jnp.exp(st - lse_ref[qb])                    # row [1, block_q]
     mask = _valid_mask(qb * block_q, tk_idx * k_block, block_q, k_block,
-                       causal, valid_len, seq_len)
+                       causal, valid_len, seq_len, q_axis=1)
     if mask is not None:
-      p = jnp.where(mask, p, 0.0)
-    dv = dv + jnp.matmul(p.T, do_blk,
-                         preferred_element_type=jnp.float32)
-    dp = jnp.matmul(do_blk, v_blk.T,
-                    preferred_element_type=jnp.float32)
-    ds = p * (dp - delta_blk) * scale
-    dk = dk + jnp.matmul(ds.T, q_blk,
-                         preferred_element_type=jnp.float32)
+      pt = jnp.where(mask, pt, 0.0)
+    dv = dv + jnp.matmul(pt, do_blk, preferred_element_type=jnp.float32)
+    dpt = jnp.matmul(v_blk, do_blk.T, preferred_element_type=jnp.float32)
+    dst = pt * (dpt - delta_ref[qb]) * scale
+    dk = dk + jnp.matmul(dst, q_blk, preferred_element_type=jnp.float32)
     return dk, dv
 
   dk0 = jnp.zeros((k_block, k_blk.shape[-1]), jnp.float32)
@@ -310,9 +381,12 @@ def _flash_bwd(causal, block_q, block_k, valid_len, interpret, residuals,
                g):
   q3, k3, v3, out, lse = residuals
   bh, t, d = q3.shape
-  # delta_i = sum_d dO_id * O_id (FlashAttention-2 backward precompute).
-  delta = jnp.sum(g.astype(jnp.float32) * out.astype(jnp.float32),
-                  axis=-1, keepdims=True)  # [bh, t, 1], lse layout
+  # One row a q block for the dK/dV kernel (the block is the whole of the
+  # last two dims, so every block_q lowers, sub-128 ones too).
+  rows = (bh, t // block_q, 1, block_q)
+  # For dK/dV, which needs every q block's `delta` as a row and cannot
+  # take it from its own block as dQ does.
+  delta = _delta(g, out).reshape(rows)
   dq_kernel = functools.partial(
       _flash_bwd_dq_kernel, block_k=block_k, causal=causal,
       q_block=block_q, valid_len=valid_len)
@@ -324,17 +398,18 @@ def _flash_bwd(causal, block_q, block_k, valid_len, interpret, residuals,
           pl.BlockSpec((None, t, d), lambda b, qb: (b, 0, 0)),
           pl.BlockSpec((None, t, d), lambda b, qb: (b, 0, 0)),
           pl.BlockSpec((None, block_q, d), lambda b, qb: (b, qb, 0)),
-          pl.BlockSpec((None, block_q, 1), lambda b, qb: (b, qb, 0)),
+          pl.BlockSpec((None, block_q, d), lambda b, qb: (b, qb, 0)),
           pl.BlockSpec((None, block_q, 1), lambda b, qb: (b, qb, 0)),
       ],
       out_specs=pl.BlockSpec((None, block_q, d), lambda b, qb: (b, qb, 0)),
       out_shape=jax.ShapeDtypeStruct((bh, t, d), q3.dtype),
       interpret=interpret,
       name="flash_bwd_dq",
-  )(q3, k3, v3, g, lse, delta)
+  )(q3, k3, v3, g, out, lse)
   dkv_kernel = functools.partial(
       _flash_bwd_dkv_kernel, block_q=block_q, causal=causal,
       k_block=block_k, valid_len=valid_len)
+  rows_spec = pl.BlockSpec((None,) + rows[1:], lambda b, kb: (b, 0, 0, 0))
   dk, dv = pl.pallas_call(
       dkv_kernel,
       grid=(bh, t // block_k),
@@ -343,8 +418,8 @@ def _flash_bwd(causal, block_q, block_k, valid_len, interpret, residuals,
           pl.BlockSpec((None, block_k, d), lambda b, kb: (b, kb, 0)),
           pl.BlockSpec((None, block_k, d), lambda b, kb: (b, kb, 0)),
           pl.BlockSpec((None, t, d), lambda b, kb: (b, 0, 0)),
-          pl.BlockSpec((None, t, 1), lambda b, kb: (b, 0, 0)),
-          pl.BlockSpec((None, t, 1), lambda b, kb: (b, 0, 0)),
+          rows_spec,
+          rows_spec,
       ],
       out_specs=[
           pl.BlockSpec((None, block_k, d), lambda b, kb: (b, kb, 0)),
@@ -356,7 +431,7 @@ def _flash_bwd(causal, block_q, block_k, valid_len, interpret, residuals,
       ],
       interpret=interpret,
       name="flash_bwd_dkv",
-  )(q3, k3, v3, g, lse, delta)
+  )(q3, k3, v3, g, lse.reshape(rows), delta)
   return dq, dk, dv
 
 
@@ -377,23 +452,23 @@ def _pow2_floor(n: int) -> int:
 _MIN_BLOCK = 8
 
 
-def _default_blocks(t: int) -> Tuple[int, int]:
-  """Measured-winner block sizes (v5e, 2026-07-31 on-chip duel,
-  scripts/tpu_flash_tune.py): the original 128x128 default LOSES to
-  plain XLA attention in fwd+bwd wall-clock (T=4096: 9.59 vs 7.00 ms;
-  T=8192: 40.25 vs 28.20) — tiny matmuls leave the MXU idle and
-  VPU-softmax dominates. Tuned blocks flip it decisively:
-  T=4096 bq=bk=1024 -> 2.98 ms (2.35x over XLA); T=8192 bq=256 bk=512
-  -> 14.28 ms (1.97x). VMEM ceilings bound the blocks: BLOCK_Q >= 512
-  at T > 4096 dies in compile (bwd block temporaries exceed the 16 MB
-  scoped-VMEM stack; block_k=512 with bq=256 is fine and is the T=8192
-  winner), and 1024x1024 at T=4096, which fits standalone, overflows
-  by 312 KB inside the full train-step graph — so the T<=4096 default
-  stays one notch safer (512x512 = 3.61 ms standalone, still 1.94x
-  over XLA)."""
-  if t <= 4096:
-    return (512, 512)
-  return (256, 512)
+# Measured-winner block sizes (block_q, block_k) at every length the step
+# compiles at (v5e, 2026-10-01, PERF.md section 6, PR 27): the whole train
+# step of `configs/train_longcontext_flash.gin` (hidden 512, 8 heads x 64,
+# bf16), milliseconds a step by (block_q, block_k).
+#
+# T 2048 x 128 sequences: 512x512 178.3, 1024x1024 183.1, 512x1024 186.4,
+# 1024x512 186.7, 256x512 193.1, 512x256 197.1. T 4096 x 64 sequences:
+# 512x512 239.0, 512x1024 243.3, 1024x512 245.2, 256x512 267.8; 1024x1024
+# is refused inside the step (the dQ kernel runs out of scoped VMEM; alone
+# it compiles). T 8192 x 32 sequences: 512x512 356.7, 256x512 (the old
+# setup's winner there) 412.2; 512x1024 and 1024x512 are refused inside
+# the step (dK/dV and dQ out of scoped VMEM). Small tiles pay a tile's
+# fixed costs more often (256x256 takes 1.5x the time of 512x512 in every
+# kernel alone), large ones hold more float32 tile in VMEM than they save.
+# At T 16384 the step is refused whatever the blocks: the forward's
+# whole-T k and v no longer fit (ROADMAP B1).
+_DEFAULT_BLOCKS = (512, 512)
 
 
 def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
@@ -410,13 +485,11 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
   programs, the interpreter elsewhere (CPU tests). Cross-attention
   (Tq != Tk) falls back to the reference implementation (the kernels
   assume self-attention layout). `block_q`/`block_k` default to the
-  on-chip measured winners for the sequence length (`_default_blocks`).
+  on-chip measured winners (`_DEFAULT_BLOCKS`).
   """
   b, h, t, d = q.shape
-  if block_q is None or block_k is None:
-    auto_bq, auto_bk = _default_blocks(t)
-    block_q = auto_bq if block_q is None else block_q
-    block_k = auto_bk if block_k is None else block_k
+  block_q = _DEFAULT_BLOCKS[0] if block_q is None else block_q
+  block_k = _DEFAULT_BLOCKS[1] if block_k is None else block_k
   if k.shape[2] != t:
     return attention(q, k, v, causal=causal)
   if interpret is None:
@@ -590,9 +663,7 @@ def ring_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
   def local_fn(q_local, k_local, v_local):
     idx = jax.lax.axis_index(axis_name)
     tq = q_local.shape[2]
-    m = jnp.full(q_local.shape[:-1], -jnp.inf, jnp.float32)
-    l = jnp.zeros(q_local.shape[:-1], jnp.float32)
-    o = jnp.zeros(q_local.shape, jnp.float32)
+    m, l, o = _online_init(q_local)
     k_blk, v_blk = k_local, v_local
 
     def absorb(src, m, l, o, k_blk, v_blk):
@@ -633,7 +704,7 @@ def ring_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
         perm = [(i, (i + 1) % axis_size) for i in range(axis_size)]
         k_blk = jax.lax.ppermute(k_blk, axis_name, perm)
         v_blk = jax.lax.ppermute(v_blk, axis_name, perm)
-    return _finalize(o, l).astype(q_local.dtype)
+    return _normalize(l, o, q_local.shape[-1])[0].astype(q_local.dtype)
 
   sharded = mesh_lib.shard_map(
       local_fn, mesh=mesh,

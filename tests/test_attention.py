@@ -139,6 +139,137 @@ class TestFlashAttention:
     assert float(loss) < first * 0.5, (first, float(loss))
 
 
+def _cos_loss(fn):
+  """Scalar loss with nonuniform cotangents, accumulated in float32."""
+  def loss(q, k, v):
+    out = fn(q, k, v).astype(jnp.float32)
+    return (out * jnp.cos(out)).sum()
+  return loss
+
+
+class TestFlashKernelBodies:
+  """What PR 27 changed in the tile loops: dK/dV works on the transposed
+  tile with `lse`/`delta` as rows, and the softmax denominator rides the
+  p.v product where the head leaves the MXU idle columns (the block
+  update the flash forward shares with ring attention)."""
+
+  @pytest.mark.parametrize("causal", [False, True])
+  @pytest.mark.parametrize("valid_len,padded_len", [(64, 64), (50, 64),
+                                                    (33, 64)])
+  @pytest.mark.parametrize("bq,bk", [(8, 16), (16, 16), (32, 8)])
+  def test_transposed_mask_is_the_mask_transposed(self, causal, valid_len,
+                                                  padded_len, bq, bk):
+    for q_start in range(0, padded_len, bq):
+      for k_start in range(0, padded_len, bk):
+        plain = attn._valid_mask(q_start, k_start, bq, bk, causal,
+                                 valid_len, padded_len)
+        turned = attn._valid_mask(q_start, k_start, bq, bk, causal,
+                                  valid_len, padded_len, q_axis=1)
+        if plain is None:
+          assert turned is None
+          continue
+        assert turned.shape == (bk, bq)
+        np.testing.assert_array_equal(np.asarray(turned),
+                                      np.asarray(plain).T)
+
+  @pytest.mark.parametrize("t", [40, 50, 64])  # padded twice, tiling once
+  @pytest.mark.parametrize("bq,bk", [(16, 32), (32, 16), (8, 32), (32, 8)])
+  def test_unequal_blocks_causal_and_padded_gradients(self, t, bq, bk):
+    """block_q != block_k, causal and padded together, in float32 at the
+    tolerance of `test_gradients_match_reference`."""
+    q, k, v = _qkv(b=1, h=2, t=t, d=8, seed=3)
+
+    ref = _cos_loss(lambda q, k, v: attn.attention(q, k, v, causal=True))
+    flash = _cos_loss(lambda q, k, v: attn.flash_attention(
+        q, k, v, causal=True, block_q=bq, block_k=bk))
+    np.testing.assert_allclose(
+        np.asarray(attn.flash_attention(q, k, v, causal=True, block_q=bq,
+                                        block_k=bk)),
+        np.asarray(attn.attention(q, k, v, causal=True)),
+        atol=2e-5, rtol=2e-5)
+    g_ref = jax.grad(ref, argnums=(0, 1, 2))(q, k, v)
+    g_flash = jax.grad(flash, argnums=(0, 1, 2))(q, k, v)
+    for a, b in zip(g_flash, g_ref):
+      np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                 atol=5e-5, rtol=5e-4)
+
+  @pytest.mark.parametrize("d", [8, 64, 128])
+  @pytest.mark.parametrize("causal", [False, True])
+  def test_denominator_on_either_path(self, d, causal):
+    """d 8 and 64 leave the MXU idle columns (the row sum rides the
+    product), d 128 does not (the row sum is a reduction over the tile,
+    `attention._sum_rides`): the output, the log-sum-exp and the
+    gradients agree with the reference on both."""
+    q, k, v = _qkv(b=1, h=2, t=48, d=d, seed=5)
+    t_pad = 64
+    pad = ((0, 0), (0, t_pad - 48), (0, 0))
+    q3, k3, v3 = (jnp.pad(x.reshape(2, 48, d), pad) for x in (q, k, v))
+    out, lse = attn._flash_forward(q3, k3, v3, causal, 16, 32, 48, True)
+    scores = jnp.einsum("hqd,hkd->hqk", q[0], k[0]) / np.sqrt(d)
+    if causal:
+      scores = jnp.where(jnp.tril(jnp.ones((48, 48), bool)), scores,
+                         -jnp.inf)
+    np.testing.assert_allclose(
+        np.asarray(lse[:, :48, 0]),
+        np.asarray(jax.scipy.special.logsumexp(scores, axis=-1)),
+        atol=2e-5, rtol=2e-5)
+    assert not np.asarray(lse[:, 48:]).any()  # padded rows are pinned to 0
+    np.testing.assert_allclose(
+        np.asarray(out[:, :48]),
+        np.asarray(attn.attention(q, k, v, causal=causal)[0]),
+        atol=2e-5, rtol=2e-5)
+    g = jax.grad(lambda *a: attn.flash_attention(
+        *a, causal=causal, block_q=16, block_k=32).std(),
+                 argnums=(0, 1, 2))(q, k, v)
+    g_ref = jax.grad(lambda *a: attn.attention(*a, causal=causal).std(),
+                     argnums=(0, 1, 2))(q, k, v)
+    for a, b in zip(g, g_ref):
+      np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                 atol=5e-5, rtol=5e-4)
+
+  # The last case is the benchmark cell's row: T 2048 in tiles of 512.
+  @pytest.mark.parametrize("t,bq,bk", [(128, 32, 32), (128, 16, 64),
+                                       (2048, 512, 512)])
+  def test_bfloat16_within_the_reference_backends_own_gap(self, t, bq, bk):
+    """bf16 inputs: the kernels' distance from float32 attention is held
+    to the distance the `reference` backend itself has in bf16 (its
+    scores are bf16; the kernels keep theirs in float32), output and
+    every gradient, in the norm. The log-sum-exp is held to one bf16
+    rounding: its denominator sums p rounded to bf16 (it rides the p.v
+    product), each term within 2^-9 of itself, so the sum is within 2^-9
+    of itself however long the row."""
+    q, k, v = (x.astype(jnp.bfloat16)
+               for x in _qkv(b=1, h=2, t=t, d=64, seed=11))
+    _, lse = attn._flash_forward(
+        *(x.reshape(2, t, 64) for x in (q, k, v)), True, bq, bk, t, True)
+    scores = jnp.einsum("hqd,hkd->hqk", q[0].astype(jnp.float32),
+                        k[0].astype(jnp.float32)) / 8.0
+    scores = jnp.where(jnp.tril(jnp.ones((t, t), bool)), scores, -jnp.inf)
+    lse_gap = np.abs(np.asarray(lse[..., 0]) - np.asarray(
+        jax.scipy.special.logsumexp(scores, axis=-1)))
+    assert lse_gap.max() <= 2.0 ** -8, lse_gap.max()
+
+    def all_of(fn, *args):
+      return (fn(*args),) + jax.grad(_cos_loss(fn),
+                                     argnums=(0, 1, 2))(*args)
+
+    exact = all_of(lambda *a: attn.attention(*a, causal=True),
+                   *(x.astype(jnp.float32) for x in (q, k, v)))
+    reference = all_of(lambda *a: attn.attention(*a, causal=True), q, k, v)
+    flash = all_of(lambda *a: attn.flash_attention(
+        *a, causal=True, block_q=bq, block_k=bk), q, k, v)
+
+    def gap(got, want):
+      got, want = np.asarray(got, np.float32), np.asarray(want, np.float32)
+      return np.linalg.norm(got - want) / np.linalg.norm(want)
+
+    for name, f, r, e in zip(("out", "dq", "dk", "dv"), flash, reference,
+                             exact):
+      assert f.dtype == jnp.bfloat16
+      assert gap(f, e) <= 1.1 * gap(r, e), (name, gap(f, e),
+                                           gap(r, e))
+
+
 class TestRingAttention:
 
   @pytest.fixture(scope="class")
@@ -147,8 +278,9 @@ class TestRingAttention:
                                 axis_names=("data", "sp", "model"))
 
   @pytest.mark.parametrize("causal", [False, True])
-  def test_matches_reference(self, sp_mesh, causal):
-    q, k, v = _qkv(b=2, h=2, t=32, d=8)
+  @pytest.mark.parametrize("d", [8, 128])  # the denominator's two paths
+  def test_matches_reference(self, sp_mesh, causal, d):
+    q, k, v = _qkv(b=2, h=2, t=32, d=d)
     expected = attn.attention(q, k, v, causal=causal)
     got = attn.ring_attention(q, k, v, sp_mesh, causal=causal)
     np.testing.assert_allclose(np.asarray(got), np.asarray(expected),

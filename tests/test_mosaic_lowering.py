@@ -378,32 +378,31 @@ class TestShippedStepsCompileForV5e:
   parses, at their shipped size, state donated as the trainer donates
   it."""
 
-  @pytest.mark.parametrize("seq_len", [
-      4096,
-      # REFUSED at the default compiler options: in the whole model's
-      # step the dK/dV kernel asks for 20.75 MB of scoped VMEM against a
-      # limit of 16 MB, whatever the blocks (every (bq, bk) in
-      # {128, 256, 512}^2 was tried). Its whole-T operands are what
-      # costs: q and dO [8192, 64] bf16 plus lse and delta [8192, 1]
-      # f32, each of the last two padded to 128 lanes = 4 MB, all
-      # double-buffered. The bare loss graph above compiles at this T,
-      # and scripts/tpu_seq_timing.py compiles this step by raising
-      # `xla_tpu_scoped_vmem_limit_kib` to 65536 — an option the trainer
-      # does not pass, so `train_eval_model` cannot run T=8192 today.
-      # ROADMAP S6 owns the repair (stream q blocks through the grid).
-      pytest.param(8192, marks=pytest.mark.xfail(
-          strict=True, reason="dK/dV kernel: scoped VMEM 20.75M > 16M")),
-  ])
-  def test_longcontext_flash_train_step_compiles(self, seq_len,
+  # (sequence length, sequences a step; None = the config's own 2).
+  # T 4096 at 16 and 64 sequences was REFUSED until PR 27 ("Scoped
+  # allocation with size 16.05M and limit 16.00M"), and T 8192 at every
+  # batch (20.75 MB): the dK/dV kernel held `lse` and `delta` as whole-T
+  # [T, 1] columns, each padded to 128 lanes (1 MB each at T 2048, 4 MB
+  # at T 8192) and double-buffered. It now takes them as lane-dense rows
+  # (`ops/attention.py:_flash_bwd_dkv_kernel`), which leaves q and dO as
+  # its only whole-T operands (1 MB each at T 8192) and lets the shipped
+  # step compile at the default compiler options; `PERF.md` section 7 has
+  # the temporaries of each. (`scripts/tpu_seq_timing.py` still raises
+  # `xla_tpu_scoped_vmem_limit_kib` for T 8192; it no longer has to.)
+  @pytest.mark.parametrize("seq_len,batch", [
+      (4096, None), (4096, 16), (4096, 64), (8192, None)])
+  def test_longcontext_flash_train_step_compiles(self, seq_len, batch,
                                                  v5e_devices):
     """Flash forward and both backward kernels INSIDE the train step at
-    the `train_longcontext_flash.gin` shape (B2, H8, T4096, d64), and at
-    the T=8192 the roadmap's second sequence cell asks for."""
-    model, batch = _model_from_config(
+    the `train_longcontext_flash.gin` shape (B2, H8, T4096, d64), at the
+    batches the next sequence cell needs (64 x T 4096 is `pool_b64_T4096`)
+    and at the T=8192 the roadmap's third sequence cell asks for."""
+    model, config_batch = _model_from_config(
         "configs/train_longcontext_flash.gin",
         [f"SequenceRegressionModel.sequence_length = {seq_len}"])
     compiled = _compile_step_for_mesh(
-        model, _trainer_mesh(v5e_devices[:1]), batch, donate=True)
+        model, _trainer_mesh(v5e_devices[:1]), batch or config_batch,
+        donate=True)
     # 2 blocks x (forward, dq, dkv).
     assert compiled.as_text().count("tpu_custom_call") >= 6
     assert compiled.memory_analysis().temp_size_in_bytes < 16e9
