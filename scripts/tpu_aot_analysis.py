@@ -199,7 +199,7 @@ def flash_analysis() -> None:
   repl = NamedSharding(mesh, PartitionSpec())
 
   def run(name, fn, t):
-    s = jax.ShapeDtypeStruct((2, 4, t, 64), jnp.bfloat16, sharding=repl)
+    s = jax.ShapeDtypeStruct((2, t, 4 * 64), jnp.bfloat16, sharding=repl)
     start = time.time()
     compiled = jax.jit(fn).lower(s, s, s).compile()
     _, byts = _cost(compiled)
@@ -210,7 +210,7 @@ def flash_analysis() -> None:
     }))
 
   def fwd(q, k, v):
-    return attention.flash_attention(q, k, v, causal=True,
+    return attention.flash_attention(q, k, v, 4, causal=True,
                                      interpret=False)
 
   def bwd(q, k, v):
@@ -342,9 +342,8 @@ def seqattn_analysis() -> None:
   attention_backend='reference' (plain XLA attention, O(T^2) score
   materialization) vs 'flash' (the Pallas kernel, O(T) memory) at
   long-context shapes on v5e. Decides VERDICT r4 item 4's compile-fact
-  half — which backend the long-context configs should ship — while
-  wall-clock confirmation stays a window item
-  (scripts/tpu_flash_validate.py)."""
+  half — which backend the long-context configs should ship; the
+  wall clock is `benchmarks/`' to read (PERF.md)."""
   import optax
 
   from tensor2robot_tpu.models import sequence_model

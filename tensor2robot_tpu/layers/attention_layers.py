@@ -53,29 +53,32 @@ class MultiHeadAttention(nn.Module):
     k = nn.Dense(proj, dtype=self.dtype, name="k_proj")(kv)
     v = nn.Dense(proj, dtype=self.dtype, name="v_proj")(kv)
 
-    def heads(y):
-      return y.reshape(b, -1, self.num_heads,
-                       self.head_dim).transpose(0, 2, 1, 3)
-
-    q, k, v = heads(q), heads(k), heads(v)  # [B, H, T, D]
     if self.backend == "flash":
-      out = attention_ops.flash_attention(q, k, v, causal=self.causal,
-                                          interpret=self.flash_interpret)
-    elif self.backend == "ring":
-      if self.mesh is None:
-        raise ValueError("ring backend requires a mesh.")
-      out = attention_ops.ring_attention(
-          q, k, v, self.mesh, axis_name=self.sp_axis, causal=self.causal)
-    elif self.backend == "ulysses":
-      if self.mesh is None:
-        raise ValueError("ulysses backend requires a mesh.")
-      out = attention_ops.ulysses_attention(
-          q, k, v, self.mesh, axis_name=self.sp_axis, causal=self.causal,
-          inner=self.ulysses_inner,
-          flash_interpret=self.flash_interpret)
+      # The flash kernels read and write the projections' own layout.
+      out = attention_ops.flash_attention(
+          q, k, v, self.num_heads, causal=self.causal,
+          interpret=self.flash_interpret)
     else:
-      out = attention_ops.attention(q, k, v, causal=self.causal)
-    out = out.transpose(0, 2, 1, 3).reshape(b, t, proj)
+      def heads(y):
+        return y.reshape(b, -1, self.num_heads,
+                         self.head_dim).transpose(0, 2, 1, 3)
+
+      q, k, v = heads(q), heads(k), heads(v)  # [B, H, T, D]
+      if self.backend == "ring":
+        if self.mesh is None:
+          raise ValueError("ring backend requires a mesh.")
+        out = attention_ops.ring_attention(
+            q, k, v, self.mesh, axis_name=self.sp_axis, causal=self.causal)
+      elif self.backend == "ulysses":
+        if self.mesh is None:
+          raise ValueError("ulysses backend requires a mesh.")
+        out = attention_ops.ulysses_attention(
+            q, k, v, self.mesh, axis_name=self.sp_axis, causal=self.causal,
+            inner=self.ulysses_inner,
+            flash_interpret=self.flash_interpret)
+      else:
+        out = attention_ops.attention(q, k, v, causal=self.causal)
+      out = out.transpose(0, 2, 1, 3).reshape(b, t, proj)
     if self.dropout_rate:
       out = nn.Dropout(self.dropout_rate, name="dropout")(
           out, deterministic=not train)

@@ -16,7 +16,9 @@ long-context capability TPU-first:
   sequences `axis_size`x longer than one chip's memory; compute and
   ring transfers overlap under XLA's async collectives.
 
-All functions take [batch, heads, seq, head_dim] ("BHTD") arrays.
+All functions take [batch, heads, seq, head_dim] ("BHTD") arrays, except
+`flash_attention`, which takes and returns [batch, seq, heads x head_dim]:
+the q/k/v projections' own layout, which its kernels read in place.
 """
 
 from __future__ import annotations
@@ -174,6 +176,34 @@ def _normalize(l, o, d: int):
 # on the transposed tile, the forward's row sum rides the p.v product
 # where a head leaves it idle columns (`_sum_rides`), and `delta` no
 # longer travels as a lane-padded column.
+#
+# Layout (PERF.md section 6, PR 30). The kernels read q, k, v, dO and
+# write O, dQ, dK, dV as [B, T, H x D], the layout the q/k/v projections
+# write and the output projection reads: the eight head-split transposes a
+# layer ran between the two (1.20-1.26 ms each at 128 x 2048 x 8 x 64)
+# are gone. Heads are indexed through the `BlockSpec`s: a program takes
+# `lane_block(H, D)` lanes of the last dimension, the fewest whole heads
+# that make a multiple of 128 (two heads of 64), grid (B, H x D / lanes,
+# T / block). Inside a program every head of the block goes through the
+# one tile loop, and is told from its neighbours by operands with the
+# other heads' lanes zeroed (`_only`): a contraction over the whole block
+# then gives one head's scores exactly (the other terms are exact zeros;
+# on the 128-deep MXU it costs what the contraction over 64 at half fill
+# cost), and a product into [block, lanes] puts a head's result in its own
+# lanes. The forward's row sum rides in the lanes of the other heads (v
+# set to one there), as it rides in the 65th column where a program is one
+# head. Same products, same float32 accumulation: on the chip every output
+# and gradient is bit for bit the [B x H, T, D] kernels'. Readings, v5e,
+# 128 x 2048 x 8 x 64, 512 x 512 tiles, causal, bf16, ms a call, forward /
+# dQ / dK/dV (the [B x H, T, D] kernels: 12.09 / 13.75 / 19.17):
+#   static lane slices of the refs, one head's loop after the other
+#                                              12.62 / 14.62 / 18.79
+#   lane slices, the heads in one loop         11.68 / 13.70 / 17.95
+#   masked operands, one loop after the other  11.61 / 13.51 / 17.80
+#   masked operands, the heads in one loop     11.60 / 12.55 / 17.56  (kept)
+# Two heads in one loop body give the scheduler one head's products to
+# run under the other's passes over lanes; a slice at lane 64 costs a
+# lane shift a tile, a select on a [block, 128] operand nothing.
 
 
 def _valid_mask(q_start, k_start, q_block, k_block, causal: bool,
@@ -193,29 +223,117 @@ def _valid_mask(q_start, k_start, q_block, k_block, causal: bool,
   return mask
 
 
+def lane_block(num_heads: int, head_dim: int) -> int:
+  """Lanes of [B, T, H x D] that one program of a flash kernel takes.
+
+  A TPU block's last dimension is a multiple of 128 lanes or the whole
+  dimension, so a program takes the smallest run of whole heads that is a
+  multiple of 128 lanes and divides H x D (D 64: two heads, D 32: four,
+  D 128 and D 256: one), and the whole H x D where there is none (the CPU
+  tests' heads of 8). It follows the operands' shape and nothing else.
+  """
+  lanes = math.lcm(head_dim, 128)
+  return lanes if (num_heads * head_dim) % lanes == 0 else (
+      num_heads * head_dim)
+
+
+# Heads of a lane block that share one tile loop. Two is what the chip
+# chose at two heads of 64 (the readings are above); each head in a loop
+# holds its own [block, block] float32 tiles in VMEM, and four heads of 32
+# in one loop are refused at T 8192 (16.07 MB of the 16 MB scoped limit;
+# described v5e, PR 30). Further heads of the block take further loops.
+_HEADS_A_LOOP = 2
+
+
+def _head_groups(lanes: int, head_dim: int):
+  """The heads of a program's `lanes`-wide block, `_HEADS_A_LOOP` to a
+  group: for each head its index and the [1, lanes] mask of its own lanes
+  (`None` where the block is one head)."""
+  if lanes == head_dim:
+    return [[(0, None)]]
+  lane = jax.lax.broadcasted_iota(jnp.int32, (1, lanes), 1)
+  heads = [(h, (lane >= h * head_dim) & (lane < (h + 1) * head_dim))
+           for h in range(lanes // head_dim)]
+  return [heads[i:i + _HEADS_A_LOOP]
+          for i in range(0, len(heads), _HEADS_A_LOOP)]
+
+
+def _only(in_head, x, other=0):
+  """x with the lanes outside a head set to `other` (x itself where the
+  block is one head)."""
+  return x if in_head is None else jnp.where(in_head, x, other)
+
+
 def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *,
-                      block_k: int, causal: bool, q_block: int,
-                      valid_len: int):
-  """One (batch*head, q_block) program: stream K/V blocks through VMEM."""
-  q = q_ref[:]  # [block_q, D]
-  tq_idx = pl.program_id(1)
-  seq_len = k_ref.shape[0]
+                      head_dim: int, block_k: int, causal: bool,
+                      q_block: int, valid_len: int):
+  """One (batch, lane block, q_block) program: stream K/V blocks through
+  VMEM, every head of the lane block in the one loop."""
+  tq_idx = pl.program_id(2)
+  seq_len, lanes = k_ref.shape
   num_k_blocks = seq_len // block_k
   if causal:
     # Future blocks are fully masked: stop the stream at the diagonal.
     num_k_blocks = jnp.minimum(
         num_k_blocks,
         ((tq_idx + 1) * q_block + block_k - 1) // block_k)
+  q = q_ref[:]  # [block_q, lanes]
 
-  def body(kb, carry):
-    k_blk = k_ref[pl.ds(kb * block_k, block_k), :]
-    v_blk = v_ref[pl.ds(kb * block_k, block_k), :]
+  def tile(kb):
+    rows = pl.ds(kb * block_k, block_k)
     mask = _valid_mask(tq_idx * q_block, kb * block_k, q_block, block_k,
                        causal, valid_len, seq_len)
-    return _online_block_update(q, k_blk, v_blk, *carry, mask)
+    return k_ref[rows, :], v_ref[rows, :], mask
 
-  m, l, o = jax.lax.fori_loop(0, num_k_blocks, body, _online_init(q))
-  out, l = _normalize(l, o, q.shape[-1])
+  if lanes == head_dim:
+    # One head a program: the block update the ring shares, as it is.
+    def body(kb, carry):
+      k_blk, v_blk, mask = tile(kb)
+      return _online_block_update(q, k_blk, v_blk, *carry, mask)
+
+    m, l, o = jax.lax.fori_loop(0, num_k_blocks, body, _online_init(q))
+    out, l = _normalize(l, o, head_dim)
+    stats = [(m, l)]
+  else:
+    # Several heads side by side. q with the other heads' lanes zeroed
+    # against the whole k block contracts to this head's scores exactly
+    # (the other terms are exact zeros), and p.v over the whole v block
+    # puts this head's numerator in its own lanes. The other heads' lanes
+    # of that product are idle, so v is set to one there and they carry
+    # the row sum, as the 65th column does for one head (`_sum_rides`).
+    scale = 1.0 / math.sqrt(head_dim)
+
+    def body(kb, carries, group, q_heads):
+      k_blk, v_blk, mask = tile(kb)
+      new = []
+      for (_, in_head), q_h, (m_prev, o_prev) in zip(group, q_heads,
+                                                     carries):
+        s = jnp.einsum("qd,kd->qk", q_h, k_blk,
+                       preferred_element_type=jnp.float32) * scale
+        if mask is not None:
+          s = jnp.where(mask, s, _mask_value(s.dtype))
+        m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
+        p = jnp.exp(s - m_new)
+        o_new = o_prev * jnp.exp(m_prev - m_new) + jnp.matmul(
+            p.astype(v_blk.dtype), _only(in_head, v_blk, 1),
+            preferred_element_type=jnp.float32)
+        new.append((m_new, o_new))
+      return tuple(new)
+
+    out, stats = jnp.zeros((q_block, lanes), jnp.float32), []
+    for group in _head_groups(lanes, head_dim):
+      carries = jax.lax.fori_loop(
+          0, num_k_blocks,
+          functools.partial(
+              body, group=group,
+              q_heads=[_only(in_head, q) for _, in_head in group]),
+          ((jnp.full((q_block, 1), -jnp.inf, jnp.float32),
+            jnp.zeros((q_block, lanes), jnp.float32)),) * len(group))
+      for (h, in_head), (m, o) in zip(group, carries):
+        beside = ((h + 1) * head_dim) % lanes  # a lane of another head
+        l = jnp.maximum(o[:, beside:beside + 1], 1e-30)
+        out = jnp.where(in_head, o / l, out)
+        stats.append((m, l))
   o_ref[:] = out.astype(o_ref.dtype)
   # logsumexp per query row, stored [T, 1]: the trailing unit lane dim
   # keeps the block shape inside Mosaic's (8, 128)-divisible-or-whole
@@ -233,7 +351,8 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *,
   q_pos = tq_idx * q_block + jax.lax.broadcasted_iota(
       jnp.int32, (q_block, 1), 0)
   row_valid = q_pos < valid_len
-  lse_ref[:] = jnp.where(row_valid, m + jnp.log(l), 0.0)
+  for h, (m, l) in enumerate(stats):
+    lse_ref[h] = jnp.where(row_valid, m + jnp.log(l), 0.0)
 
 
 def _delta(do, o):
@@ -245,193 +364,216 @@ def _delta(do, o):
 
 
 def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref,
-                         dq_ref, *, block_k: int, causal: bool,
-                         q_block: int, valid_len: int):
+                         dq_ref, *, head_dim: int, block_k: int,
+                         causal: bool, q_block: int, valid_len: int):
   """dQ for one q block: dS = P * (dO.V^T - delta); dQ = scale * dS.K.
 
   `delta` is taken here from the block's own rows of dO and O, once a
   program (+0.29 ms a call): as an operand it would be a [T, 1] column,
   which XLA keeps padded to 128 lanes (1 GB written a call at the
-  benchmark's shape, 1.42 ms).
+  benchmark's shape, 1.42 ms). Where the block holds several heads, k and
+  v with the other heads' lanes zeroed give this head's scores and dP from
+  the whole q and dO, and dS.K lands in this head's lanes of the one
+  accumulator, zeros in the others.
   """
-  scale = 1.0 / math.sqrt(q_ref.shape[-1])
-  q = q_ref[:]
-  do = do_ref[:].astype(jnp.float32)
-  lse = lse_ref[:]      # [block_q, 1]
-  delta = _delta(do, o_ref[:])
-  tq_idx = pl.program_id(1)
-  seq_len = k_ref.shape[0]
+  scale = 1.0 / math.sqrt(head_dim)
+  tq_idx = pl.program_id(2)
+  seq_len, lanes = k_ref.shape
   num_k_blocks = seq_len // block_k
   if causal:
     num_k_blocks = jnp.minimum(
         num_k_blocks,
         ((tq_idx + 1) * q_block + block_k - 1) // block_k)
+  q = q_ref[:]
+  do = do_ref[:].astype(jnp.float32)
+  o = o_ref[:]
 
-  def body(kb, dq):
-    k_blk = k_ref[pl.ds(kb * block_k, block_k), :]
-    v_blk = v_ref[pl.ds(kb * block_k, block_k), :]
-    s = jnp.matmul(q, k_blk.T,
-                   preferred_element_type=jnp.float32) * scale
-    p = jnp.exp(s - lse)
+  def body(kb, dq, group, deltas):
+    rows = pl.ds(kb * block_k, block_k)
+    k_blk, v_blk = k_ref[rows, :], v_ref[rows, :]
     mask = _valid_mask(tq_idx * q_block, kb * block_k, q_block, block_k,
                        causal, valid_len, seq_len)
-    if mask is not None:
-      p = jnp.where(mask, p, 0.0)
-    dp = jnp.matmul(do, v_blk.T, preferred_element_type=jnp.float32)
-    ds = p * (dp - delta) * scale
-    return dq + jnp.matmul(ds, k_blk,
-                           preferred_element_type=jnp.float32)
+    for (h, in_head), delta in zip(group, deltas):
+      k_h, v_h = _only(in_head, k_blk), _only(in_head, v_blk)
+      s = jnp.matmul(q, k_h.T, preferred_element_type=jnp.float32) * scale
+      p = jnp.exp(s - lse_ref[h])      # [block_q, 1]
+      if mask is not None:
+        p = jnp.where(mask, p, 0.0)
+      dp = jnp.matmul(do, v_h.T, preferred_element_type=jnp.float32)
+      ds = p * (dp - delta) * scale
+      dq = dq + jnp.matmul(ds, k_h, preferred_element_type=jnp.float32)
+    return dq
 
-  dq0 = jnp.zeros((q_block, q.shape[-1]), jnp.float32)
-  dq_ref[:] = jax.lax.fori_loop(0, num_k_blocks, body, dq0).astype(
-      dq_ref.dtype)
+  dq = jnp.zeros((q_block, lanes), jnp.float32)
+  for group in _head_groups(lanes, head_dim):
+    deltas = [_delta(_only(in_head, do), o) for _, in_head in group]
+    dq = jax.lax.fori_loop(
+        0, num_k_blocks,
+        functools.partial(body, group=group, deltas=deltas), dq)
+  dq_ref[:] = dq.astype(dq_ref.dtype)
 
 
 def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                          dk_ref, dv_ref, *, block_q: int, causal: bool,
-                          k_block: int, valid_len: int):
+                          dk_ref, dv_ref, *, head_dim: int, block_q: int,
+                          causal: bool, k_block: int, valid_len: int):
   """dK/dV for one k block: dV = P^T.dO; dK = scale * dS^T.Q.
 
   Works on the transposed tile: S^T = K.Q^T and dP^T = V.dO^T come out of
   the MXU as [k_block, block_q], so P^T and dS^T feed the two
   accumulating products as they are (transposing P and dS cost two
   passes through the XLU a tile, a fifth of the kernel). `lse` and
-  `delta` arrive as lane-dense rows, [T // block_q, 1, block_q]: a
-  sublane broadcast a tile, and 16 KB of VMEM at T 2048 where the [T, 1]
-  columns, padded to 128 lanes, held 1 MB each.
+  `delta` arrive as lane-dense rows, [heads, T // block_q, 1, block_q]:
+  a sublane broadcast a tile, and 16 KB of VMEM a head at T 2048 where
+  the [T, 1] columns, padded to 128 lanes, held 1 MB each. Where the
+  block holds several heads, k and v with the other heads' lanes zeroed
+  (once a program) against the whole q and dO give this head's tiles; its
+  dV and dK then stand in its own lanes of its accumulators, and what the
+  products put in the other lanes is dropped at the end.
   """
-  scale = 1.0 / math.sqrt(q_ref.shape[-1])
-  k_blk = k_ref[:]
-  v_blk = v_ref[:]
-  tk_idx = pl.program_id(1)
-  seq_len = q_ref.shape[0]
+  scale = 1.0 / math.sqrt(head_dim)
+  tk_idx = pl.program_id(2)
+  seq_len, lanes = q_ref.shape
   num_q_blocks = seq_len // block_q
   start_q = 0
   if causal:
     # Blocks strictly above the diagonal see no unmasked entries.
     start_q = (tk_idx * k_block) // block_q
-
-  def body(qb, carry):
-    dk, dv = carry
-    q_blk = q_ref[pl.ds(qb * block_q, block_q), :]
-    do_blk = do_ref[pl.ds(qb * block_q, block_q), :].astype(jnp.float32)
-    st = jnp.matmul(k_blk, q_blk.T,
-                    preferred_element_type=jnp.float32) * scale
-    pt = jnp.exp(st - lse_ref[qb])                    # row [1, block_q]
+  def body(qb, carries, group, k_heads, v_heads):
+    rows = pl.ds(qb * block_q, block_q)
+    q_blk = q_ref[rows, :]
+    do_blk = do_ref[rows, :].astype(jnp.float32)
     mask = _valid_mask(qb * block_q, tk_idx * k_block, block_q, k_block,
                        causal, valid_len, seq_len, q_axis=1)
-    if mask is not None:
-      pt = jnp.where(mask, pt, 0.0)
-    dv = dv + jnp.matmul(pt, do_blk, preferred_element_type=jnp.float32)
-    dpt = jnp.matmul(v_blk, do_blk.T, preferred_element_type=jnp.float32)
-    dst = pt * (dpt - delta_ref[qb]) * scale
-    dk = dk + jnp.matmul(dst, q_blk, preferred_element_type=jnp.float32)
-    return dk, dv
+    new = []
+    for (h, _), k_h, v_h, (dk, dv) in zip(group, k_heads, v_heads, carries):
+      st = jnp.matmul(k_h, q_blk.T,
+                      preferred_element_type=jnp.float32) * scale
+      pt = jnp.exp(st - lse_ref[h, qb])               # row [1, block_q]
+      if mask is not None:
+        pt = jnp.where(mask, pt, 0.0)
+      dv = dv + jnp.matmul(pt, do_blk, preferred_element_type=jnp.float32)
+      dpt = jnp.matmul(v_h, do_blk.T, preferred_element_type=jnp.float32)
+      dst = pt * (dpt - delta_ref[h, qb]) * scale
+      dk = dk + jnp.matmul(dst, q_blk, preferred_element_type=jnp.float32)
+      new.append((dk, dv))
+    return tuple(new)
 
-  dk0 = jnp.zeros((k_block, k_blk.shape[-1]), jnp.float32)
-  dv0 = jnp.zeros((k_block, v_blk.shape[-1]), jnp.float32)
-  dk, dv = jax.lax.fori_loop(start_q, num_q_blocks, body, (dk0, dv0))
+  zeros = jnp.zeros((k_block, lanes), jnp.float32)
+  dk, dv = zeros, zeros
+  for group in _head_groups(lanes, head_dim):
+    carries = jax.lax.fori_loop(
+        start_q, num_q_blocks,
+        functools.partial(
+            body, group=group,
+            k_heads=[_only(in_head, k_ref[:]) for _, in_head in group],
+            v_heads=[_only(in_head, v_ref[:]) for _, in_head in group]),
+        ((zeros, zeros),) * len(group))
+    for (_, in_head), (dk_h, dv_h) in zip(group, carries):
+      dk, dv = _only(in_head, dk_h, dk), _only(in_head, dv_h, dv)
   dk_ref[:] = dk.astype(dk_ref.dtype)
   dv_ref[:] = dv.astype(dv_ref.dtype)
 
 
-def _flash_forward(q3, k3, v3, causal, block_q, block_k, valid_len,
+def _block_specs(t: int, block: int, lanes: int, head_dim: int):
+  """BlockSpecs over a (batch, lane block, T block) grid: a [block, lanes]
+  tile and the whole-T [T, lanes] strip of [B, T, H x D], and the
+  [heads, block, 1] columns of [B, H, T, 1] that go with the tile."""
+  return (pl.BlockSpec((None, block, lanes), lambda b, g, i: (b, i, g)),
+          pl.BlockSpec((None, t, lanes), lambda b, g, i: (b, 0, g)),
+          pl.BlockSpec((None, lanes // head_dim, block, 1),
+                       lambda b, g, i: (b, g, i, 0)))
+
+
+def _flash_forward(q, k, v, num_heads, causal, block_q, block_k, valid_len,
                    interpret):
-  bh, t, d = q3.shape
+  """q, k, v [B, T, H x D] -> (out [B, T, H x D], lse [B, H, T, 1])."""
+  b, t, hd = q.shape
+  d = hd // num_heads
+  lanes = lane_block(num_heads, d)
   kernel = functools.partial(
-      _flash_fwd_kernel, block_k=block_k, causal=causal, q_block=block_q,
-      valid_len=valid_len)
+      _flash_fwd_kernel, head_dim=d, block_k=block_k, causal=causal,
+      q_block=block_q, valid_len=valid_len)
+  tile, whole, column = _block_specs(t, block_q, lanes, d)
   out, lse = pl.pallas_call(
       kernel,
-      grid=(bh, t // block_q),
-      in_specs=[
-          pl.BlockSpec((None, block_q, d), lambda b, qb: (b, qb, 0)),
-          pl.BlockSpec((None, t, d), lambda b, qb: (b, 0, 0)),
-          pl.BlockSpec((None, t, d), lambda b, qb: (b, 0, 0)),
-      ],
-      out_specs=[
-          pl.BlockSpec((None, block_q, d), lambda b, qb: (b, qb, 0)),
-          pl.BlockSpec((None, block_q, 1), lambda b, qb: (b, qb, 0)),
-      ],
+      grid=(b, hd // lanes, t // block_q),
+      in_specs=[tile, whole, whole],
+      out_specs=[tile, column],
       out_shape=[
-          jax.ShapeDtypeStruct((bh, t, d), q3.dtype),
-          jax.ShapeDtypeStruct((bh, t, 1), jnp.float32),
+          jax.ShapeDtypeStruct((b, t, hd), q.dtype),
+          jax.ShapeDtypeStruct((b, num_heads, t, 1), jnp.float32),
       ],
       interpret=interpret,
       name="flash_fwd",
-  )(q3, k3, v3)
+  )(q, k, v)
   return out, lse
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2, 3, 4))
-def _flash(causal: bool, block_q: int, block_k: int, valid_len: int,
-           interpret: bool, q3, k3, v3):
-  out, _ = _flash_forward(q3, k3, v3, causal, block_q, block_k,
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2, 3, 4, 5))
+def _flash(num_heads: int, causal: bool, block_q: int, block_k: int,
+           valid_len: int, interpret: bool, q, k, v):
+  out, _ = _flash_forward(q, k, v, num_heads, causal, block_q, block_k,
                           valid_len, interpret)
   return out
 
 
-def _flash_fwd(causal, block_q, block_k, valid_len, interpret, q3, k3, v3):
-  out, lse = _flash_forward(q3, k3, v3, causal, block_q, block_k,
+def _flash_fwd(num_heads, causal, block_q, block_k, valid_len, interpret,
+               q, k, v):
+  out, lse = _flash_forward(q, k, v, num_heads, causal, block_q, block_k,
                             valid_len, interpret)
-  return out, (q3, k3, v3, out, lse)
+  return out, (q, k, v, out, lse)
 
 
-def _flash_bwd(causal, block_q, block_k, valid_len, interpret, residuals,
-               g):
-  q3, k3, v3, out, lse = residuals
-  bh, t, d = q3.shape
+def _flash_bwd(num_heads, causal, block_q, block_k, valid_len, interpret,
+               residuals, g):
+  q, k, v, out, lse = residuals
+  b, t, hd = q.shape
+  d = hd // num_heads
+  lanes = lane_block(num_heads, d)
   # One row a q block for the dK/dV kernel (the block is the whole of the
   # last two dims, so every block_q lowers, sub-128 ones too).
-  rows = (bh, t // block_q, 1, block_q)
+  rows = (b, num_heads, t // block_q, 1, block_q)
   # For dK/dV, which needs every q block's `delta` as a row and cannot
-  # take it from its own block as dQ does.
-  delta = _delta(g, out).reshape(rows)
+  # take it from its own block as dQ does. One sum over the whole H x D
+  # lanes a head, the other heads' lanes zeroed: XLA makes one pass of
+  # them all (0.78 ms at 128 x 2048 x 8 x 64, v5e), where a reduction over
+  # [B, T, H, D]'s last dimension needs the product laid out again with
+  # 64 of every 128 lanes idle (3.24 ms; PERF.md section 6, PR 30).
+  head_of_lane = jnp.arange(hd) // d
+  delta = jnp.stack(
+      [_delta(jnp.where(head_of_lane == h, g, 0), out)[..., 0]
+       for h in range(num_heads)], axis=1).reshape(rows)
   dq_kernel = functools.partial(
-      _flash_bwd_dq_kernel, block_k=block_k, causal=causal,
+      _flash_bwd_dq_kernel, head_dim=d, block_k=block_k, causal=causal,
       q_block=block_q, valid_len=valid_len)
+  tile, whole, column = _block_specs(t, block_q, lanes, d)
   dq = pl.pallas_call(
       dq_kernel,
-      grid=(bh, t // block_q),
-      in_specs=[
-          pl.BlockSpec((None, block_q, d), lambda b, qb: (b, qb, 0)),
-          pl.BlockSpec((None, t, d), lambda b, qb: (b, 0, 0)),
-          pl.BlockSpec((None, t, d), lambda b, qb: (b, 0, 0)),
-          pl.BlockSpec((None, block_q, d), lambda b, qb: (b, qb, 0)),
-          pl.BlockSpec((None, block_q, d), lambda b, qb: (b, qb, 0)),
-          pl.BlockSpec((None, block_q, 1), lambda b, qb: (b, qb, 0)),
-      ],
-      out_specs=pl.BlockSpec((None, block_q, d), lambda b, qb: (b, qb, 0)),
-      out_shape=jax.ShapeDtypeStruct((bh, t, d), q3.dtype),
+      grid=(b, hd // lanes, t // block_q),
+      in_specs=[tile, whole, whole, tile, tile, column],
+      out_specs=tile,
+      out_shape=jax.ShapeDtypeStruct((b, t, hd), q.dtype),
       interpret=interpret,
       name="flash_bwd_dq",
-  )(q3, k3, v3, g, out, lse)
+  )(q, k, v, g, out, lse)
   dkv_kernel = functools.partial(
-      _flash_bwd_dkv_kernel, block_q=block_q, causal=causal,
+      _flash_bwd_dkv_kernel, head_dim=d, block_q=block_q, causal=causal,
       k_block=block_k, valid_len=valid_len)
-  rows_spec = pl.BlockSpec((None,) + rows[1:], lambda b, kb: (b, 0, 0, 0))
+  tile = _block_specs(t, block_k, lanes, d)[0]
+  rows_spec = pl.BlockSpec((None, lanes // d) + rows[2:],
+                           lambda b, g, kb: (b, g, 0, 0, 0))
   dk, dv = pl.pallas_call(
       dkv_kernel,
-      grid=(bh, t // block_k),
-      in_specs=[
-          pl.BlockSpec((None, t, d), lambda b, kb: (b, 0, 0)),
-          pl.BlockSpec((None, block_k, d), lambda b, kb: (b, kb, 0)),
-          pl.BlockSpec((None, block_k, d), lambda b, kb: (b, kb, 0)),
-          pl.BlockSpec((None, t, d), lambda b, kb: (b, 0, 0)),
-          rows_spec,
-          rows_spec,
-      ],
-      out_specs=[
-          pl.BlockSpec((None, block_k, d), lambda b, kb: (b, kb, 0)),
-          pl.BlockSpec((None, block_k, d), lambda b, kb: (b, kb, 0)),
-      ],
+      grid=(b, hd // lanes, t // block_k),
+      in_specs=[whole, tile, tile, whole, rows_spec, rows_spec],
+      out_specs=[tile, tile],
       out_shape=[
-          jax.ShapeDtypeStruct((bh, t, d), k3.dtype),
-          jax.ShapeDtypeStruct((bh, t, d), v3.dtype),
+          jax.ShapeDtypeStruct((b, t, hd), k.dtype),
+          jax.ShapeDtypeStruct((b, t, hd), v.dtype),
       ],
       interpret=interpret,
       name="flash_bwd_dkv",
-  )(q3, k3, v3, g, lse.reshape(rows), delta)
+  )(q, k, v, g, lse.reshape(rows), delta)
   return dq, dk, dv
 
 
@@ -472,12 +614,20 @@ _DEFAULT_BLOCKS = (512, 512)
 
 
 def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
+                    num_heads: int,
                     causal: bool = False,
                     block_q: Optional[int] = None,
                     block_k: Optional[int] = None,
                     interpret: Optional[bool] = None) -> jnp.ndarray:
-  """Pallas flash attention, [B, H, T, D]. Fully differentiable
-  (custom FlashAttention-2 backward kernels).
+  """Pallas flash attention over `num_heads` heads, [B, T, H x D] in and
+  out. Fully differentiable (custom FlashAttention-2 backward kernels).
+
+  The layout is the projections' own, as `nn.Dense` writes and reads it,
+  and stays whole: the kernels index heads through their `BlockSpec`s
+  (`lane_block`), so no head-split transpose runs on either side of them.
+  (A [B, T, H, D] view would be no bitcast on the chip: a minor dimension
+  of 64 is tiled to 128 lanes, so each reshape is a copy, 0.82 ms at
+  128 x 2048 x 8 x 64; PERF.md section 6, PR 30.)
 
   Sequences that don't tile the block size are padded to the next block
   multiple and masked — never a silent O(T^2) fallback. `interpret=None`
@@ -487,11 +637,17 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
   assume self-attention layout). `block_q`/`block_k` default to the
   on-chip measured winners (`_DEFAULT_BLOCKS`).
   """
-  b, h, t, d = q.shape
+  b, t, hd = q.shape
+  if hd % num_heads:
+    raise ValueError(f"{num_heads} heads do not divide the last dimension "
+                     f"of {q.shape}")
   block_q = _DEFAULT_BLOCKS[0] if block_q is None else block_q
   block_k = _DEFAULT_BLOCKS[1] if block_k is None else block_k
-  if k.shape[2] != t:
-    return attention(q, k, v, causal=causal)
+  if k.shape[1] != t:
+    heads = lambda x: x.reshape(b, -1, num_heads, hd // num_heads).transpose(
+        0, 2, 1, 3)
+    return attention(heads(q), heads(k), heads(v),
+                     causal=causal).transpose(0, 2, 1, 3).reshape(b, t, hd)
   if interpret is None:
     # lax.platform_dependent, NOT jax.default_backend(): the process
     # backend bakes the HOST platform into the trace, so AOT-lowering a
@@ -506,12 +662,12 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     q, k, v = jax.lax.optimization_barrier((q, k, v))
     return jax.lax.optimization_barrier(jax.lax.platform_dependent(
         q, k, v,
-        tpu=functools.partial(flash_attention, causal=causal,
-                              block_q=block_q, block_k=block_k,
-                              interpret=False),
-        default=functools.partial(flash_attention, causal=causal,
-                                  block_q=block_q, block_k=block_k,
-                                  interpret=True)))
+        tpu=functools.partial(flash_attention, num_heads=num_heads,
+                              causal=causal, block_q=block_q,
+                              block_k=block_k, interpret=False),
+        default=functools.partial(flash_attention, num_heads=num_heads,
+                                  causal=causal, block_q=block_q,
+                                  block_k=block_k, interpret=True)))
   # Normalize blocks to powers of two in [_MIN_BLOCK, next_pow2(T)]: the
   # padding arithmetic below relies on lcm(bq, bk) == max(bq, bk), which
   # only holds for powers of two.
@@ -520,18 +676,13 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
   tile = max(eff_bq, eff_bk)
   t_pad = ((t + tile - 1) // tile) * tile
   assert t_pad % eff_bq == 0 and t_pad % eff_bk == 0
-  q3 = q.reshape(b * h, t, d)
-  k3 = k.reshape(b * h, t, d)
-  v3 = v.reshape(b * h, t, d)
   if t_pad != t:
     pad = ((0, 0), (0, t_pad - t), (0, 0))
-    q3 = jnp.pad(q3, pad)
-    k3 = jnp.pad(k3, pad)
-    v3 = jnp.pad(v3, pad)
+    q, k, v = jnp.pad(q, pad), jnp.pad(k, pad), jnp.pad(v, pad)
   if not interpret:
-    # XLA:TPU fuses surrounding layout ops (the model layer's
-    # BTHD->BHTD head-split transposes, the non-tiling-T pads above)
-    # into the custom-call's scoped-VMEM region; at long T the fused
+    # XLA:TPU fuses surrounding layout ops (the non-tiling-T pads above,
+    # a caller's own transposes) into the custom-call's
+    # scoped-VMEM region; at long T the fused
     # operands/results exceed VMEM and compilation fails with
     # RESOURCE_EXHAUSTED "allocating on stack" (found at T=8192/h512 by
     # the round-5 seqattn duel — interpret mode hid it, like the
@@ -540,13 +691,11 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     # since its transpose rule is itself a barrier, the backward
     # kernels get the same protection. Pinned by TestFlashMosaicLowering
     # test_long_context_train_graph_compiles.
-    q3, k3, v3 = jax.lax.optimization_barrier((q3, k3, v3))
-  out = _flash(causal, eff_bq, eff_bk, t, interpret, q3, k3, v3)
+    q, k, v = jax.lax.optimization_barrier((q, k, v))
+  out = _flash(num_heads, causal, eff_bq, eff_bk, t, interpret, q, k, v)
   if not interpret:
     out = jax.lax.optimization_barrier(out)  # see the entry barrier
-  if t_pad != t:
-    out = out[:, :t]
-  return out.reshape(b, h, t, d)
+  return out[:, :t] if t_pad != t else out
 
 
 # -- Ulysses attention (all_to_all sequence parallelism) ---------------------
@@ -596,28 +745,38 @@ def ulysses_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     # (dims swapped in the VJP), while the 0,0 form is self-transpose.
     b_l, _, t_l, _ = q_l.shape
 
+    # The full-sequence side of both all_to_alls is [B_l, H/S, T, D] for
+    # `attention` and [B_l, T, H/S x D] for `flash_attention`: the same
+    # permutes, ending in the layout the inner kernel takes.
+    flash = inner == "flash"
+
     def seq_to_heads(x):
       # [B_l,H,T_l,D] -> [S,B_l,H/S,T_l,D] -(a2a)-> src-major ->
-      # [B_l,H/S,T,D]; source order == sequence order, so the merge
-      # reassembles the global sequence.
+      # [B_l,H/S,T,D] (flash: [B_l,T,H/S x D]); source order == sequence
+      # order, so the merge reassembles the global sequence.
       x = x.reshape(b_l, s, h // s, t_l, d)
       x = jnp.moveaxis(x, 1, 0)
       x = jax.lax.all_to_all(x, axis_name, 0, 0)   # [S(src),B_l,H/S,T_l,D]
+      if flash:
+        x = x.transpose(1, 0, 3, 2, 4)             # [B_l,S,T_l,H/S,D]
+        return x.reshape(b_l, s * t_l, h // s * d)
       x = x.transpose(1, 2, 0, 3, 4)               # [B_l,H/S,S,T_l,D]
       return x.reshape(b_l, h // s, s * t_l, d)
 
     def heads_to_seq(x):
-      # inverse: [B_l,H/S,T,D] -> [S,B_l,H/S,T_l,D] -(a2a)->
-      # head-group-major -> [B_l,H,T_l,D]
-      x = x.reshape(b_l, h // s, s, t_l, d)
-      x = x.transpose(2, 0, 1, 3, 4)               # [S,B_l,H/S,T_l,D]
+      # inverse: [B_l,H/S,T,D] (flash: [B_l,T,H/S x D]) ->
+      # [S,B_l,H/S,T_l,D] -(a2a)-> head-group-major -> [B_l,H,T_l,D]
+      if flash:
+        x = x.reshape(b_l, s, t_l, h // s, d).transpose(1, 0, 3, 2, 4)
+      else:
+        x = x.reshape(b_l, h // s, s, t_l, d).transpose(2, 0, 1, 3, 4)
       x = jax.lax.all_to_all(x, axis_name, 0, 0)   # [S(grp),B_l,H/S,T_l,D]
       x = jnp.moveaxis(x, 0, 1)                    # [B_l,S,H/S,T_l,D]
       return x.reshape(b_l, h, t_l, d)
 
     q_g, k_g, v_g = seq_to_heads(q_l), seq_to_heads(k_l), seq_to_heads(v_l)
-    if inner == "flash":
-      out = flash_attention(q_g, k_g, v_g, causal=causal,
+    if flash:
+      out = flash_attention(q_g, k_g, v_g, h // s, causal=causal,
                             interpret=flash_interpret)
     else:
       out = attention(q_g, k_g, v_g, causal=causal)

@@ -116,7 +116,18 @@ CONFIGS = [
     ((1, 1, 16, 64), False, 128, 128),    # tiny T, block > T
     ((1, 2, 1024, 64), True, 128, 256),   # asymmetric block sizes
     ((1, 1, 4096, 64), True, 128, 128),   # long-context SP building block
+    ((2, 8, 1024, 64), True, 512, 512),   # two heads of 64 a program
+    ((2, 2, 512, 256), True, 256, 256),   # one head in a 256-lane block
+    ((2, 8, 256, 32), True, 128, 128),    # four heads of 32 a program
+    ((2, 4, 256, 16), False, 128, 128),   # whole H x D = 64, under 128
+    ((1, 3, 256, 64), True, 128, 128),    # whole H x D = 192: 128 divides not
 ]
+
+
+def _flash_shapes(shape, dtype=jnp.bfloat16):
+  """[B, T, H x D] operands and the head count for a (b, h, t, d) case."""
+  b, h, t, d = shape
+  return jax.ShapeDtypeStruct((b, t, h * d), dtype), h
 
 
 @pytest.mark.usefixtures("tpu_lowering")
@@ -124,29 +135,29 @@ class TestFlashMosaicLowering:
 
   @pytest.mark.parametrize("shape,causal,bq,bk", CONFIGS)
   def test_forward_lowers(self, shape, causal, bq, bk):
-    s = jax.ShapeDtypeStruct(shape, jnp.bfloat16)
+    s, h = _flash_shapes(shape)
     _export_for_tpu(
         lambda q, k, v: attention.flash_attention(
-            q, k, v, causal=causal, block_q=bq, block_k=bk,
+            q, k, v, h, causal=causal, block_q=bq, block_k=bk,
             interpret=False), s, s, s)
 
   @pytest.mark.parametrize("shape,causal,bq,bk", CONFIGS)
   def test_backward_lowers(self, shape, causal, bq, bk):
-    s = jax.ShapeDtypeStruct(shape, jnp.bfloat16)
+    s, h = _flash_shapes(shape)
 
     def grads(q, k, v):
       return jax.grad(
           lambda q_, k_, v_: attention.flash_attention(
-              q_, k_, v_, causal=causal, block_q=bq, block_k=bk,
+              q_, k_, v_, h, causal=causal, block_q=bq, block_k=bk,
               interpret=False).astype(jnp.float32).sum(),
           argnums=(0, 1, 2))(q, k, v)
 
     _export_for_tpu(grads, s, s, s)
 
   def test_lowered_module_contains_mosaic_kernels(self):
-    s = jax.ShapeDtypeStruct((2, 2, 256, 64), jnp.bfloat16)
+    s, h = _flash_shapes((2, 2, 256, 64))
     exported = _export_for_tpu(
-        lambda q, k, v: attention.flash_attention(q, k, v, causal=True,
+        lambda q, k, v: attention.flash_attention(q, k, v, h, causal=True,
                                                   interpret=False),
         s, s, s)
     text = exported.mlir_module()
@@ -159,9 +170,9 @@ class TestFlashMosaicLowering:
     jax.default_backend() auto-select baked the CPU host backend into
     TPU-target AOT programs, so 'flash' compile facts silently priced
     the interpreter emulation."""
-    s = jax.ShapeDtypeStruct((2, 2, 256, 64), jnp.bfloat16)
+    s, h = _flash_shapes((2, 2, 256, 64))
     exported = _export_for_tpu(
-        lambda q, k, v: attention.flash_attention(q, k, v, causal=True),
+        lambda q, k, v: attention.flash_attention(q, k, v, h, causal=True),
         s, s, s)
     assert "tpu_custom_call" in exported.mlir_module(), (
         "default-interpret flash lowered the interpreter emulation "
@@ -171,21 +182,20 @@ class TestFlashMosaicLowering:
     grads = _export_for_tpu(
         lambda q, k, v: jax.grad(
             lambda q_, k_, v_: attention.flash_attention(
-                q_, k_, v_, causal=True).astype(jnp.float32).sum(),
+                q_, k_, v_, h, causal=True).astype(jnp.float32).sum(),
             argnums=(0, 1, 2))(q, k, v), s, s, s)
     assert "tpu_custom_call" in grads.mlir_module()
 
   @pytest.mark.parametrize("t", [8192, 8000])
   def test_long_context_train_graph_compiles(self, t, v5e_devices):
-    """The kernel embedded in a model-like graph (head-split transposes
-    + projections + grad) must COMPILE at long T, not just lower:
-    without the optimization barriers XLA:TPU fuses the surrounding
-    transposes into the custom-call's scoped-VMEM region and T=8192
-    dies with RESOURCE_EXHAUSTED 'allocating on stack' (the bare-kernel
-    tests above can't see it). T=8000 covers the non-block-multiple
-    path, where the pad ops sit between the model transposes and the
-    kernel — the barriers must bind to the padded operands, not the
-    pre-pad ones."""
+    """The kernel embedded in a model-like graph (projections + grad)
+    must COMPILE at long T, not just lower: without the optimization
+    barriers XLA:TPU fuses the surrounding layout ops into the
+    custom-call's scoped-VMEM region and T=8192 dies with
+    RESOURCE_EXHAUSTED 'allocating on stack' (the bare-kernel tests
+    above can't see it). T=8000 covers the non-block-multiple path,
+    where the pad ops sit between the projections and the kernel — the
+    barriers must bind to the padded operands, not the pre-pad ones."""
     mesh = Mesh(v5e_devices[:1], ("data",))
     repl = NamedSharding(mesh, PartitionSpec())
     bsz, h, d, f = 2, 8, 64, 512
@@ -193,20 +203,17 @@ class TestFlashMosaicLowering:
     ws = jax.ShapeDtypeStruct((f, h * d), jnp.bfloat16, sharding=repl)
 
     def loss(x, wq, wk, wv):
-      def heads(y):
-        return y.reshape(bsz, t, h, d).transpose(0, 2, 1, 3)
-      out = attention.flash_attention(
-          heads(x @ wq), heads(x @ wk), heads(x @ wv), causal=True,
-          interpret=False)
+      out = attention.flash_attention(x @ wq, x @ wk, x @ wv, h,
+                                      causal=True, interpret=False)
       return out.astype(jnp.float32).sum()
 
     jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3))).lower(
         xs, ws, ws, ws).compile()
 
   def test_f32_inputs_lower(self):
-    s = jax.ShapeDtypeStruct((1, 2, 256, 64), jnp.float32)
+    s, h = _flash_shapes((1, 2, 256, 64), jnp.float32)
     _export_for_tpu(
-        lambda q, k, v: attention.flash_attention(q, k, v,
+        lambda q, k, v: attention.flash_attention(q, k, v, h,
                                                   interpret=False),
         s, s, s)
 
@@ -303,6 +310,11 @@ def _compile_step_for_mesh(model, mesh, batch, rules=None, donate=False):
   model's partition rules (not replicated) and batches on the model's
   own batch_partition_spec (e.g. ('data', 'sp') for ring attention) —
   the same layout train_eval/create_train_state deploy."""
+  return _lower_step_for_mesh(model, mesh, batch, rules, donate).compile()
+
+
+def _lower_step_for_mesh(model, mesh, batch, rules=None, donate=False):
+  """`_compile_step_for_mesh` up to the lowered program."""
   from tensor2robot_tpu import specs as specs_lib
   from tensor2robot_tpu.parallel import train_step as ts
 
@@ -329,7 +341,7 @@ def _compile_step_for_mesh(model, mesh, batch, rules=None, donate=False):
                             batch_spec=batch_spec, donate=donate)
   return step.lower(shapes(state_shape, shardings),
                     _uniform_shapes(features, batch_sh),
-                    _uniform_shapes(labels, batch_sh)).compile()
+                    _uniform_shapes(labels, batch_sh))
 
 
 def _compile_loop_for_mesh(model, mesh, batch, loop_k, rules=None):
@@ -367,6 +379,14 @@ def _compile_loop_for_mesh(model, mesh, batch, loop_k, rules=None):
                     _uniform_shapes(labels, loop_sh)).compile()
 
 
+def _activation_transposes(lowered_text):
+  """The transposes of a lowered step that move an activation: every
+  `stablehlo.transpose` but the plain ones of a 2-D weight matrix (the
+  dense layers' backward)."""
+  return [line.strip() for line in lowered_text.splitlines()
+          if "stablehlo.transpose" in line and "dims = [1, 0]" not in line]
+
+
 def _trainer_mesh(devices):
   """The (data, fsdp, model) mesh `train_eval_model` builds by default."""
   return Mesh(np.asarray(devices).reshape(-1, 1, 1),
@@ -390,22 +410,45 @@ class TestShippedStepsCompileForV5e:
   # the temporaries of each. (`scripts/tpu_seq_timing.py` still raises
   # `xla_tpu_scoped_vmem_limit_kib` for T 8192; it no longer has to.)
   @pytest.mark.parametrize("seq_len,batch", [
-      (4096, None), (4096, 16), (4096, 64), (8192, None)])
+      (4096, None), (4096, 16), (4096, 64), (8192, None), (2048, 128),
+      (8192, 32)])
   def test_longcontext_flash_train_step_compiles(self, seq_len, batch,
                                                  v5e_devices):
     """Flash forward and both backward kernels INSIDE the train step at
     the `train_longcontext_flash.gin` shape (B2, H8, T4096, d64), at the
-    batches the next sequence cell needs (64 x T 4096 is `pool_b64_T4096`)
-    and at the T=8192 the roadmap's third sequence cell asks for."""
+    benchmark cell's 128 x T 2048, at the batches the next sequence cells
+    need (64 x T 4096 is `pool_b64_T4096`, 32 x T 8192) and at the T=8192
+    the roadmap's third sequence cell asks for. Since PR 30 the kernels
+    read [B, T, H x D] as the projections write it: nothing is laid out
+    again between the two, neither as the step is lowered nor as the
+    chip's compiler leaves it."""
     model, config_batch = _model_from_config(
         "configs/train_longcontext_flash.gin",
         [f"SequenceRegressionModel.sequence_length = {seq_len}"])
-    compiled = _compile_step_for_mesh(
-        model, _trainer_mesh(v5e_devices[:1]), batch or config_batch,
-        donate=True)
+    batch = batch or config_batch
+    lowered = _lower_step_for_mesh(
+        model, _trainer_mesh(v5e_devices[:1]), batch, donate=True)
+    assert _activation_transposes(lowered.as_text()) == []
+    compiled = lowered.compile()
+    text = compiled.as_text()
     # 2 blocks x (forward, dq, dkv).
-    assert compiled.as_text().count("tpu_custom_call") >= 6
+    assert text.count("tpu_custom_call") >= 6
+    assert f"bf16[{batch},8,{seq_len},64]" not in text  # no [B, H, T, D]
     assert compiled.memory_analysis().temp_size_in_bytes < 16e9
+
+  def test_head_split_transposes_are_what_the_count_finds(self,
+                                                          v5e_devices):
+    """The count above can see them: the same step under the `reference`
+    backend splits heads by transposing q, k, v and the output, and
+    their four cotangents, in each of its two blocks."""
+    model, _ = _model_from_config(
+        "configs/train_longcontext_flash.gin",
+        ["SequenceRegressionModel.sequence_length = 256",
+         "SequenceRegressionModel.attention_backend = 'reference'"])
+    lowered = _lower_step_for_mesh(
+        model, _trainer_mesh(v5e_devices[:1]), 4, donate=True)
+    found = _activation_transposes(lowered.as_text())
+    assert sum("dims = [0, 2, 1, 3]" in line for line in found) == 16, found
 
   def test_tuned_grasping44_train_step_fits_one_chip(self, v5e_devices):
     """Grasping44 @472, batch 256, bf16 (train_qtopt_tpu_tuned.gin): the
