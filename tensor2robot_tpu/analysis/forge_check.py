@@ -17,8 +17,8 @@ pays the compile the farm exists to kill.
   to such a literal, a direct `bucket_ladder(...)` call (the canonical
   derivation), and `**splat` call sites (not statically analyzable).
   Everything else is a finding. Runtime-derived ladders are sometimes
-  the point (the fleet bench's `traffic_bucket_ladder` A/B) — those
-  sites carry a justified suppression and, in production, route ladder
+  the point (a `traffic_bucket_ladder` derived from served traffic) —
+  those sites carry a justified suppression and, in production, route ladder
   changes through `ServingFleet.rollout(ladder=...)`, which pre-forges
   the new rungs inside the drained window instead of in front of
   traffic.
@@ -59,7 +59,7 @@ def _is_int_literal_sequence(node: ast.AST) -> bool:
 
 def _module_literal_names(tree: ast.Module) -> Dict[str, bool]:
   """Module-level `NAME = [1, 2, 4]`-style constants (the one
-  indirection worth resolving: bench.py's SESSION_BUCKETS pattern)."""
+  indirection worth resolving: a ladder named once and passed on)."""
   out: Dict[str, bool] = {}
   for node in tree.body:
     if isinstance(node, ast.Assign) and len(node.targets) == 1 \

@@ -29,8 +29,8 @@ Output contracts:
 * `--list-rules` — the catalog, generated from the registry
   (docs/ARCHITECTURE.md renders the same registry; a test pins them);
 * `--stats` — `lint/files`, `lint/parse_ms`, `lint/rules_ms` on
-  stderr; `--runs PATH` appends the same block to a runs.jsonl so lint
-  latency is diff-gated like every other bench family;
+  stderr; `--runs PATH` appends the same block to a runs.jsonl so
+  `graftscope diff` gates lint latency;
 * `--baseline` / `--write-baseline` — accept today's findings, gate
   only new ones (fingerprints are line-number-independent);
 * `--cache-file` / `--changed-only` — content-hash incremental mode

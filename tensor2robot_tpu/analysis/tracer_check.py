@@ -23,9 +23,9 @@ hazards in Python sources.
   window is closed by `jax.block_until_ready` (measured on the v5e in
   PR 22: a window it closes takes 0.9997 of one a host fetch closes —
   it IS a barrier) or by a host fetch (`backend.sync`/`state_barrier`,
-  `np.asarray`, `jax.device_get`, `.item()`, `float()`). `obs/` and
-  `utils/backend.py` are exempt — they are the two places allowed to
-  own clocks around device code (the barrier discipline lives there).
+  `np.asarray`, `jax.device_get`, `.item()`, `float()`). `obs/` is
+  exempt — the one place allowed to own clocks around device code (its
+  windows end in barriers by design).
 
 A function is "traced" when decorated with `jax.jit`/`pjit` (directly or
 via `functools.partial`), or passed by name/lambda to a `jax.jit(...)` /
@@ -81,10 +81,8 @@ _BARRIER_CALLS = _NP_HOST_CONVERTERS | {"jax.device_get", "float", "int",
                                         "jax.block_until_ready"}
 # Method/attribute names that barrier regardless of the object they hang
 # off (backend.sync, backend_lib.state_barrier, arr.item(),
-# arr.block_until_ready(), and the backend timing helpers, which
-# barrier internally).
-_BARRIER_ATTRS = {"sync", "state_barrier", "block_until_ready", "item",
-                  "time_op", "time_train_steps", "time_train_steps_halves"}
+# arr.block_until_ready()).
+_BARRIER_ATTRS = {"sync", "state_barrier", "block_until_ready", "item"}
 
 
 def _import_aliases(tree: ast.AST) -> Dict[str, str]:
@@ -342,8 +340,8 @@ def _check_device_timing(tree: ast.Module, aliases: Dict[str, str],
             f"{dispatches[0]}() without a barrier — jax returns before "
             "the device is done, so this measures dispatch, not "
             "execution; end the window with jax.block_until_ready or a "
-            "host fetch (backend.sync / np.asarray), or use "
-            "tensor2robot_tpu.utils.backend.time_op / time_train_steps",
+            "host fetch (tensor2robot_tpu.utils.backend.sync / "
+            "np.asarray)",
             end_line=end_line))
 
   _check_scope(tree)
@@ -397,11 +395,9 @@ def allows_device_timing(path: str) -> bool:
   """True for the paths that own clocks around device code — shared by
   `check_python_file` and the engine registration, so the exemption
   cannot drift between the two call paths: obs/ owns the
-  instrumentation clocks (its windows end in barriers by design),
-  utils/backend.py the shared timing recipes."""
+  instrumentation clocks (its windows end in barriers by design)."""
   norm = path.replace("\\", "/")
-  return (norm.endswith("utils/backend.py") or "/obs/" in norm
-          or norm.startswith("obs/"))
+  return "/obs/" in norm or norm.startswith("obs/")
 
 
 def check_python_file(path: str) -> List[Finding]:
@@ -442,12 +438,11 @@ engine_lib.register(engine_lib.Rule(
             doc=("time.time/perf_counter window around device\n"
                  "dispatch without a barrier (block_until_ready or\n"
                  "a host fetch): measures dispatch, not execution;\n"
-                 "obs/ and utils/backend.py are exempt"),
+                 "obs/ is exempt"),
             meaning=("`time.time`/`perf_counter` window around a device "
                      "dispatch without a barrier "
                      "(`jax.block_until_ready` or a host fetch) — "
-                     "measures dispatch, not execution; `obs/` and "
-                     "`utils/backend.py` (the clock owners) are "
-                     "exempt")),
+                     "measures dispatch, not execution; `obs/` (the "
+                     "clock owner) is exempt")),
     ),
     check=_engine_check))

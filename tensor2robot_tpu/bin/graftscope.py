@@ -779,7 +779,7 @@ def _main_forge(argv: List[str]) -> int:
                            "('auto', the default, is excache.cache_root():"
                            " under $JAX_COMPILATION_CACHE_DIR when set, "
                            "else the checkout's .graftcache — where "
-                           "trainer, servers and bench look)")
+                           "trainer and servers look)")
   parser.add_argument("--jobs", type=int, default=2,
                       help="parallel compile-farm worker subprocesses")
   parser.add_argument("--plan", action="store_true",

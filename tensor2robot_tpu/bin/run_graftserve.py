@@ -11,10 +11,8 @@ Restores a predictor from an export bundle (the same timestamped dirs
 `ServingFleet` of N replicas on disjoint device groups behind the
 load-aware router), warms every shape bucket, then drives a
 closed-loop load test and prints ONE JSON stats line — QPS, latency
-percentiles, per-bucket compile economics, shed/SLO counters. The
-operational twin of `bench.py --serve` / `bench.py --fleet` (same
-`serving.loadgen` machinery), pointed at real checkpoints instead of
-the smoke critic.
+percentiles, per-bucket compile economics, shed/SLO counters
+(`serving.loadgen` machinery, pointed at real checkpoints).
 
 Usage:
   python -m tensor2robot_tpu.bin.run_graftserve \

@@ -26,7 +26,7 @@ still surfaces instead of silently starving the run. The quota is 0
 by default: eval and parity paths keep the strict raise-immediately
 contract. `obs.faultlab` points (`data.record_io`,
 `data.corrupt_record`, `data.preprocess`) inject exactly these
-failures for the chaos bench.
+failures (tests/test_graftguard.py).
 """
 
 from __future__ import annotations
@@ -317,7 +317,7 @@ class RecordBatchPipeline:
   abandon iteration early (finished eval rounds) should close it; the
   train loop's DevicePrefetcher does so on its own close.
   `overlap=False` restores the serial generator chain, which the
-  data-bench A/B and parity tests use.
+  parity tests use.
   """
 
   def __init__(self,
@@ -580,7 +580,7 @@ class RecordBatchPipeline:
     """The overlap-plane decision: explicit `overlap` wins; auto (None)
     pipelines whenever the caller wants background behavior at all
     (`prefetch_size` > 0). `overlap=False` keeps the serial generator
-    chain — the data-bench A/B and the parity tests force it."""
+    chain — the parity tests force it."""
     if self._overlap is not None:
       return self._overlap
     return prefetch_size > 0

@@ -18,8 +18,8 @@ the publisher to re-roll the current version onto lagging replicas
 serving replica to the newest verified step, equalizing a replica that
 was evicted through a publish and later readmitted with old params),
 and SKIPS collecting until the fleet catches up. Counted
-`loop/stale_repins`/`loop/stale_skips`; the bound itself is the loop
-bench's "no action from a policy > K versions behind" pin.
+`loop/stale_repins`/`loop/stale_skips`; the bound itself is the "no
+action from a policy > K versions behind" pin of tests/test_loop.py.
 
 **Fault seams.** `loop.actor_crash` (key = actor index) raises out of
 the worker — the supervisor's restart path; `loop.actor_hang`
@@ -177,7 +177,7 @@ class EpisodeActor:
             self._episodes_per_iteration)
         # Collection pacing: on CPU-constrained hosts an unthrottled
         # actor pool starves the learner of the interpreter (observed
-        # on the 1-core bench host: warm actors monopolized the GIL and
+        # on a 1-core host: warm actors monopolized the GIL and
         # round 1 of training never finished). The pause caps the
         # pool's duty cycle; 0 disables it on hosts with cores to
         # spare.

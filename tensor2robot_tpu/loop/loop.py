@@ -30,7 +30,7 @@ exports, /root/reference/utils/continuous_collect_eval.py:28-108):
   `max_staleness_versions` published versions behind (drain + re-pin
   otherwise, `loop/actor.py`).
 
-`summary()` returns the loop-level accounting the bench reads: episode
+`summary()` returns the loop-level accounting: episode
 goodput, publish history, publish-to-first-action latency, the
 served-version AUDIT (every version actors acted on must be the initial
 one or a verified publish), max observed staleness, and worker
@@ -385,7 +385,7 @@ class GraftLoop:
       # drop_remainder pipeline over a glob holding fewer records than
       # one batch yields ZERO batches per epoch and spins empty epochs
       # forever — the first fetch never returns and the learner wedges
-      # while actors collect merrily (bench.py --loop found this: warm
+      # while actors collect merrily (seen on a CPU run of the loop: warm
       # actors rotate shard 0 out in <1s, so a shards-only gate races
       # down to one 8-record file). Later rounds re-glob and see
       # everything new.

@@ -12,9 +12,9 @@ whatever bytes survived. Here a checkpoint reaches actors ONLY through:
      `loop/publish_rejected`, incident `loop_publish_rejected`); the
      fleet keeps serving the last verified version and the learner's
      own verified-restore walk quarantines the bad step on its next
-     resume. No unverified checkpoint ever reaches an actor — the loop
-     bench pins it by auditing every served version against the
-     publisher's verified-publish history.
+     resume. No unverified checkpoint ever reaches an actor —
+     tests/test_loop.py pins it by auditing every served version
+     against the publisher's verified-publish history.
   2. **`ServingFleet.rollout()`** — canary-first zero-downtime swap
      under live actor traffic (PR 11): a canary verification failure
      aborts with the rest of the fleet still on the OLD checkpoint.
@@ -35,7 +35,7 @@ rewind target — those steps are quarantined/about-to-be-resaved, and
 publishing across the rewind would race the learner's replay. Already-
 published versions stay published: actors keep serving the last
 verified checkpoint while the learner rewinds (collection never stops
-for a rewind — the loop bench measures it).
+for a rewind).
 
 Telemetry: `loop/publishes`, `loop/publish_rejected`,
 `loop/publish_aborted` counters; `loop/publish_to_serve_ms` histogram

@@ -24,7 +24,7 @@ but makes the store BOUNDED:
       counted `loop/replay/shed_episodes` — the actor sees the refusal
       and its episode is not silently half-written.
   Either way the accounting is explicit: a stalled learner costs
-  dropped/shed EPISODES (visible in telemetry and the loop bench), not
+  dropped/shed EPISODES (visible in telemetry and `summary()`), not
   host memory or an unbounded disk.
 
 Telemetry: `loop/replay/bytes` + `loop/replay/shards` gauges;
@@ -158,7 +158,7 @@ class ReplayRecordSink:
     The loop's data gate holds on this, not shard count alone: a single
     short shard with fewer records than one training batch makes a
     drop_remainder pipeline yield ZERO batches per epoch and spin empty
-    epochs forever (observed wedging the whole loop on the bench host —
+    epochs forever (observed wedging the whole loop on a CPU host —
     warm actors rotate the first shard out almost instantly, so the
     gate's glob raced down to one 8-record file)."""
     with self._lock:

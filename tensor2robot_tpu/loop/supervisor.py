@@ -46,7 +46,7 @@ watches every registered worker:
 Telemetry: `loop/worker_restarts`, `loop/worker_hangs`,
 `loop/worker_escalations` counters; `loop/workers_alive` gauge;
 `loop/worker_downtime_ms` histogram (crash/hang detection to successful
-restart — the loop-level MTTR number `bench.py --loop` reads).
+restart — the loop-level MTTR number).
 
 Backend-free by construction (threading + obs only).
 """

@@ -21,7 +21,7 @@ timing that diagnosed every perf round by hand (PERFORMANCE.md):
 * `excache`   — graftcache: persistent on-disk executable/AOT cache
   (content-addressed `serialize_executable` round-trips of the xray
   AOT executables + the XLA compilation-cache backstop), so trainer
-  restarts, serving cold starts, and bench probes deserialize warm
+  restarts and serving cold starts deserialize warm
   executables instead of recompiling; read back / maintained with
   `graftscope cache`;
 * `sentinel`  — online anomaly detection over the stepstats stream:
@@ -31,7 +31,7 @@ timing that diagnosed every perf round by hand (PERFORMANCE.md):
 * `faultlab`  — graftguard's seeded deterministic fault-injection
   plane: named injection points threaded through the data/checkpoint/
   train/serving seams, every injected fault counted and stamped into
-  the run record so a chaos run (`bench.py --chaos`) is attributable;
+  the run record so a run under a fault plan is attributable;
 * `flightrec` — crash/hang flight recorder: bounded ring buffers of
   recent steps/incidents dumped as a `graftscope-postmortem-v1` bundle
   on unhandled exception, SIGTERM (host-side state only),

@@ -299,7 +299,7 @@ def has_causal_chain(events: Sequence[Dict[str, Any]],
                      names: Sequence[str]) -> bool:
   """Whether some single chain of causal edges walks events named
   `names[0] -> names[1] -> ... -> names[-1]` (each hop a parent/links
-  edge). The loop-bench acceptance check: one episode's collect span
+  edge). The loop's causality check: one episode's collect span
   flow-linked through replay shard, learner round, publish, and first
   served action."""
   if not names:

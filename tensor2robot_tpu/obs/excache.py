@@ -3,8 +3,7 @@ serve many — across PROCESSES).
 
 Compile time is the measured tax everywhere in this system: the round-5
 compile valley (PERFORMANCE.md), `BucketedEngine.warmup()` compiling
-every bucket on every serving cold start, every bench probe re-tracing
-from scratch in its own subprocess, and every trainer restart re-paying
+every bucket on every serving cold start, and every trainer restart re-paying
 the train-step compile it already paid yesterday. The reference never solved this either — TF
 sessions re-specialize per feed shape behind an opaque boundary
 (/root/reference/predictors/exported_savedmodel_predictor.py:53-359);
@@ -96,8 +95,8 @@ CACHE_VERSION = 4
 # Where both cache tiers live (ISSUE 22 §4). `JAX_COMPILATION_CACHE_DIR`
 # places them from outside: jax's own persistent cache is then that
 # directory and no code here points it anywhere else. Unset, both go to
-# ONE fixed path inside the checkout (gitignored) — for trainer, servers
-# and bench alike: the path is part of jax's cache key, so a directory
+# ONE fixed path inside the checkout (gitignored) — for trainer and
+# servers alike: the path is part of jax's cache key, so a directory
 # that moves (a temporary model_dir, a pid, a timestamp) never hits.
 _PLACED_ENV = "JAX_COMPILATION_CACHE_DIR"
 _CHECKOUT_CACHE_DIR = os.path.join(
@@ -623,8 +622,8 @@ class ExecutableCache:
     evicts one entry; `older_than_secs` evicts entries created longer
     ago than that (sidecar-less orphans always match an age sweep);
     `name_prefix` evicts entries whose recorded name starts with it
-    (how the cold-start bench resets ONLY its own namespace instead of
-    nuking every probe's entries in a shared cache dir).
+    (how one caller resets ONLY its own namespace instead of nuking
+    every other caller's entries in a shared cache dir).
     """
     selective = (key is not None or older_than_secs is not None
                  or name_prefix is not None)
@@ -725,9 +724,8 @@ def enable_xla_cache() -> str:
 def cache_stats(registry: Optional[metrics_lib.Registry] = None
                 ) -> Dict[str, float]:
   """The `cache/*` registry slice as a flat dict — the block run records
-  and bench headlines embed (ISSUE 7: every hit/miss/load lands in
-  runs.jsonl). Counters are pre-created so the headline schema is
-  stable even on a zero-traffic run."""
+  embed (ISSUE 7: every hit/miss/load lands in runs.jsonl). Counters
+  are pre-created so the schema is stable even on a zero-traffic run."""
   reg = registry or metrics_lib.get_registry()
   for name in ("cache/hits", "cache/misses", "cache/corrupt_entries",
                "cache/stores", "cache/store_failures",

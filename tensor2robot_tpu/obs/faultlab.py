@@ -8,9 +8,9 @@ measured quantity (the Gemma-on-TPU serving writeup). This repo could
 flight-recorder postmortems) but recovery behavior was asserted, never
 measured — because nothing could inject a fault on demand. faultlab is
 that missing half: a deterministic fault plane threaded through the
-existing seams, so `bench.py --chaos` can run a SEEDED fault storm and
-price goodput-under-faults and MTTR per fault class like any other
-diff-gated bench family.
+existing seams, so a test (or a run under a config's plan) injects a
+SEEDED fault storm and holds the recovery of each fault class:
+tests/test_graftguard.py, tests/test_fleet.py, tests/test_loop.py.
 
 Injection points (the seam that checks each one is named in situ):
 
@@ -181,8 +181,7 @@ class FaultPlan:
                   ) -> "FaultPlan":
     """Builds a plan from a JSON-safe dict:
     `{"seed": 7, "faults": [{"point": "serve.dispatch", "at": [3],
-    "key": 1}, ...]}` — the shape `bench.py --chaos` and config files
-    carry."""
+    "key": 1}, ...]}` — the shape config files carry."""
     faults = [FaultSpec(**dict(f)) for f in config.get("faults", ())]
     return cls(faults, seed=int(config.get("seed", 0)), registry=registry)
 

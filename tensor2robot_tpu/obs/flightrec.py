@@ -1,8 +1,8 @@
 """Crash/hang flight recorder: bounded ring buffers + postmortem bundles.
 
 The postmortem gap in one sentence: when a run's device died mid-run the
-only evidence used to be a different metric name in the next bench
-record — no record of the last healthy steps or the incident sequence
+only evidence used to be a different metric name in the driver's next
+BENCH_r0*.json — no record of the last healthy steps or the incident sequence
 (VERDICT r5 weakness #1). The reference stack is no better: a crashed TPUEstimator job leaves whatever
 TensorBoard flushed (/root/reference/models/abstract_model.py:873-936).
 

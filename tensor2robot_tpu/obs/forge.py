@@ -240,7 +240,7 @@ def plan_from_config(config_files: Sequence[str],
       # An unbound mesh_shape is NOT single-device: train_eval builds
       # the default all-devices mesh — record it so the worker compiles
       # (and keys) the executable the trainer actually dispatches.
-      # (None is reserved for hand-built one-chip plans, bench.py.)
+      # (None is reserved for hand-built one-chip plans.)
       mesh_shape = query("train_eval_model.mesh_shape") or "default"
       mode = str(query("train_eval_model.mode") or "train_and_evaluate")
       loop_k = int(query("train_eval_model.iterations_per_loop") or 1)
@@ -635,13 +635,13 @@ def _engine_result(target: Dict[str, Any], engine,
 def build_train_step(spec: Dict[str, Any],
                      target: Dict[str, Any]) -> Tuple[Any, Tuple]:
   """Builds the trainer's first-dispatch executable, exactly as
-  train_eval / bench pay it, and returns `(step, args)` ready to
+  train_eval pays it, and returns `(step, args)` ready to
   `.trace(*args)` or dispatch: the plain step at [B], or — for
   `loop_k` targets — the `make_train_loop` [K, B] scan program (a
   DIFFERENT jaxpr; forging the plain step under the loop name would
   store an entry the trainer never looks up). `mesh_shape=None` is the
   one-chip deployment shape (SingleDeviceSharding donation —
-  serializes safely, the bench plan); "default" is train_eval's
+  serializes safely; hand-built plans only); "default" is train_eval's
   unbound-mesh_shape case (all devices on the data axis); an explicit
   shape mirrors the config. Shared by the farm worker
   (`_forge_train_target`) and the jaxpr audit worker

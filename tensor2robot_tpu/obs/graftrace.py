@@ -21,7 +21,7 @@ episode) followable across all of them:
   (`queue_wait`/`batch_form`/`dispatch`/`split` sum to the end-to-end
   `serve/request_ms`; `pad`/`device` are informational sub-stages of
   dispatch) recorded into `serve/stage/<name>_ms` histograms and
-  summarized by `stage_breakdown()` for the bench headlines.
+  summarized by `stage_breakdown()`.
 * **Causality links** — span args may carry `links` (a list of source
   span_ids); `obs.aggregate` synthesizes Perfetto flow events from
   `parent_id`/`links` at merge time, which is what turns the loop's
@@ -62,7 +62,7 @@ __all__ = ["TraceContext", "mint", "current", "activate",
 
 STAGE_PREFIX = "serve/stage/"
 # The stages whose per-request sum reconciles with the end-to-end
-# `serve/request_ms` window (bench acceptance: within 5%). `pad` and
+# `serve/request_ms` window (within 5% on a CPU run). `pad` and
 # `device` happen INSIDE the dispatch window (engine-side sub-stages)
 # and are reported but excluded from the sum — counting them twice
 # would break the reconciliation by construction.
@@ -180,7 +180,7 @@ def record_stage_many(name: str, values_ms: Iterable[float]) -> None:
 
 
 def stage_breakdown() -> Optional[Dict[str, Any]]:
-  """The bench headline block: per-stage p50/p95/p99 plus the
+  """Per-stage p50/p95/p99 plus the
   reconciliation of the summed stage means against the end-to-end
   `serve/request_ms` mean. Returns None when no stage was recorded in
   the current registry window (e.g. a traffic shape that never touched

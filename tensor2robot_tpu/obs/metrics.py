@@ -3,10 +3,10 @@
 Replaces the reference's host_call scalar plumbing
 (/root/reference/models/abstract_model.py:873-936) for everything that is
 NOT a per-step training scalar: pipeline wait times, serving latencies,
-episode counts, bench probe outcomes. Components record into the global
+episode counts. Components record into the global
 registry from any thread; `snapshot()` flattens the whole registry into
 plain floats for the JSONL event stream (`utils/summaries.py`) or a
-bench JSON record.
+run record (`obs/runlog.py`).
 
 Naming scheme (docs/ARCHITECTURE.md "Observability"): metric names are
 `component/metric_unit` (e.g. `data/prefetch_wait_ms`,

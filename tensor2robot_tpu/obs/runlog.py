@@ -69,14 +69,19 @@ DEFAULT_THRESHOLDS: Dict[str, Tuple[str, float]] = {
     "bytes_per_step": ("up", 0.10),
     "jaxpr_eqns": ("up", 0.25),
     "hbm_watermark_bytes": ("up", 0.10),
-    # Data-plane A/B ratio (bench.py --data): stager vs Python-chain
+    # Everything from here to `forge_compile_share`, and the last four
+    # entries, gates a field of the "bench" records that the CPU bench
+    # modes wrote until PR 31 deleted them: nothing in the tree writes
+    # those fields now (ROADMAP D1b decides whether the thresholds
+    # stay). The comments say what each number was.
+    # Data-plane A/B ratio: stager vs Python-chain
     # throughput measured as back-to-back pairs, so host-load swings
     # cancel — the load-INVARIANT gate for the staging plane (absolute
     # examples_per_sec on that record flaps with the host; see
     # PERFORMANCE.md "Reading a data bench"). 15%: the per-run median
     # still wobbles 1.85-1.90x on this VM.
     "stager_vs_python_chain": ("down", 0.15),
-    # Train-smoke data-path ratio (bench.py --smoke / CPU fallback):
+    # Train-smoke data-path ratio:
     # record-fed vs synthetic device-resident throughput, paired
     # back-to-back — the load-invariant up-good gate for the REAL train
     # data path (ROADMAP item 5). Tightened 0.20 -> 0.15 when the
@@ -85,16 +90,16 @@ DEFAULT_THRESHOLDS: Dict[str, Tuple[str, float]] = {
     # there (~0.76) still clears the pre-overlap level, so the gate
     # protects the overlap win itself, not just staging parity.
     "data_vs_synthetic": ("down", 0.15),
-    # graftcache cold-start gates (bench.py --cache / engine warmup,
-    # PERFORMANCE.md "Reading a cache bench"): warmup_ms is wall-clock
+    # graftcache cold-start gates (engine warmup; PERFORMANCE.md
+    # "Reading a cache bench"): warmup_ms is wall-clock
     # (host noise — loose band), cold_vs_warm_warmup is the paired
     # cold/warm speedup ratio (>= 1; a drop toward 1 means the cache
     # stopped saving compiles — the load-invariant down-bad gate of the
     # ISSUE 7 acceptance).
     "warmup_ms": ("up", 0.50),
     "cold_vs_warm_warmup": ("down", 0.30),
-    # Pipeline-schedule gates (bench.py --pp / scripts/pp_bench.sh,
-    # PERFORMANCE.md "Reading a pipeline bench"): onefonb_vs_gpipe is
+    # Pipeline-schedule gates (PERFORMANCE.md "Reading a pipeline
+    # bench"): onefonb_vs_gpipe is
     # the paired step-time ratio GPipe/1F1B on the virtual 8-device
     # mesh (>= 1 when the interleaved schedule wins; back-to-back pairs
     # make it load-invariant like data_vs_synthetic — 15% band for the
@@ -103,8 +108,8 @@ DEFAULT_THRESHOLDS: Dict[str, Tuple[str, float]] = {
     # growth is a real schedule change, not noise (tightest band).
     "onefonb_vs_gpipe": ("down", 0.15),
     "pp_bubble_fraction": ("up", 0.02),
-    # Stateful-session gates (bench.py --session / scripts/
-    # session_bench.sh, PERFORMANCE.md "Reading a session bench"):
+    # Stateful-session gates (PERFORMANCE.md "Reading a session
+    # bench"):
     # session_vs_stateless is the paired per-tick cost ratio
     # stateless-full-prefix / cached-decode at T=32 (back-to-back pairs
     # => load-invariant, like data_vs_synthetic; >= 2.0 is the ISSUE 11
@@ -120,8 +125,7 @@ DEFAULT_THRESHOLDS: Dict[str, Tuple[str, float]] = {
     # detector over the kernel dispatch path; back-to-back pairs make
     # it load-invariant like the other ratio gates).
     "decode_kernel_vs_xla": ("down", 0.15),
-    # Fleet-serving gates (bench.py --fleet / scripts/fleet_bench.sh,
-    # PERFORMANCE.md "Reading a fleet bench"): fleet_vs_single_replica
+    # Fleet-serving gates (PERFORMANCE.md "Reading a fleet bench"): fleet_vs_single_replica
     # is the paired 1-vs-2-replica goodput ratio under open-loop load
     # (back-to-back pairs => load-invariant; >= 1.5 is the ISSUE 12
     # acceptance floor). fleet_rollout_shed is the shed/failed count
@@ -131,8 +135,7 @@ DEFAULT_THRESHOLDS: Dict[str, Tuple[str, float]] = {
     # and flags).
     "fleet_vs_single_replica": ("down", 0.15),
     "fleet_rollout_shed": ("up", 0.0),
-    # Chaos/robustness gates (bench.py --chaos / scripts/chaos_bench.sh,
-    # PERFORMANCE.md "Reading a chaos bench"): chaos_goodput_ratio is
+    # Chaos/robustness gates (PERFORMANCE.md "Reading a chaos bench"): chaos_goodput_ratio is
     # the paired faulted/clean serving-goodput ratio under the seeded
     # fault storm (back-to-back pairs => load-invariant like
     # data_vs_synthetic; a drop means recovery got more expensive or
@@ -141,8 +144,7 @@ DEFAULT_THRESHOLDS: Dict[str, Tuple[str, float]] = {
     # 1-core host — wall-clock, so it gets the loose band warmup_ms has.
     "chaos_goodput_ratio": ("down", 0.15),
     "chaos_recovery_ms": ("up", 0.50),
-    # graftloop gates (bench.py --loop / scripts/loop_bench.sh,
-    # PERFORMANCE.md "Reading a loop bench"): loop_goodput_ratio is the
+    # graftloop gates (PERFORMANCE.md "Reading a loop bench"): loop_goodput_ratio is the
     # paired chaos/clean COLLECTION goodput ratio (episodes/s) with the
     # full actor/learner/deploy loop under the seeded storm
     # (back-to-back arms => load-invariant; ISSUE 14 acceptance floor
@@ -153,8 +155,7 @@ DEFAULT_THRESHOLDS: Dict[str, Tuple[str, float]] = {
     # 1-core host, so the loose warmup_ms band.
     "loop_goodput_ratio": ("down", 0.15),
     "publish_to_serve_ms": ("up", 0.50),
-    # graftforge gates (bench.py --forge / scripts/forge_bench.sh,
-    # PERFORMANCE.md "Reading a forge bench"): forged_vs_cold is the
+    # graftforge gates (PERFORMANCE.md "Reading a forge bench"): forged_vs_cold is the
     # paired cold/forged cold-start speedup ratio measured in two fresh
     # subprocesses back-to-back (load-invariant like cold_vs_warm_warmup
     # — >= 2.0 is the ISSUE 15 acceptance floor; a drop toward 1 means
@@ -175,8 +176,7 @@ DEFAULT_THRESHOLDS: Dict[str, Tuple[str, float]] = {
     # host noise.
     "lint_parse_ms": ("up", 0.50),
     "lint_rules_ms": ("up", 0.50),
-    # graftrace gates (bench.py --fleet / PERFORMANCE.md "Reading a
-    # timeline"): serve_queue_wait_p99_ms is the p99 of the queue_wait
+    # graftrace gates (PERFORMANCE.md "Reading a timeline"): serve_queue_wait_p99_ms is the p99 of the queue_wait
     # stage in the traced fleet arm — growth means admission is
     # outpacing dispatch (wall-clock on the 1-core host, loose band).
     # trace_overhead_ratio is the PAIRED traced-vs-untraced goodput
@@ -187,8 +187,7 @@ DEFAULT_THRESHOLDS: Dict[str, Tuple[str, float]] = {
     # band on a nonzero one).
     "serve_queue_wait_p99_ms": ("up", 0.50),
     "trace_overhead_ratio": ("up", 0.50),
-    # graftwatch gates (bench.py --fleet / PERFORMANCE.md "Reading a
-    # watch/SLO report"): fleet_utilization is the duo arm's busy/wall
+    # graftwatch gates (PERFORMANCE.md "Reading a watch/SLO report"): fleet_utilization is the duo arm's busy/wall
     # device-second ratio from the obs.usage ledger — DOWN-bad (idle
     # devices are paid for), wall-clock on the 1-core host so it gets
     # the loose band. slo_budget_burn is the SLO engine's worst
@@ -455,19 +454,20 @@ def key_metrics(record: Dict[str, Any]) -> Dict[str, float]:
     out["stager_vs_python_chain"] = float(bench["stager_vs_python_chain"])
   if bench.get("data_vs_synthetic") is not None:
     out["data_vs_synthetic"] = float(bench["data_vs_synthetic"])
-  # graftcache cold-start metrics (bench.py --cache headlines; the
-  # serve headline's engine warmup lands here too when present).
+  # graftcache cold-start metrics (an engine's warmup wall and the
+  # paired cold/warm ratio). From here on, every field but the lint
+  # pair has had no writer since PR 31 (ROADMAP D1b).
   if bench.get("warmup_ms") is not None:
     out["warmup_ms"] = float(bench["warmup_ms"])
   if bench.get("cold_vs_warm_warmup") is not None:
     out["cold_vs_warm_warmup"] = float(bench["cold_vs_warm_warmup"])
-  # Pipeline-schedule bench (bench.py --pp): the load-invariant paired
+  # Pipeline schedules: the load-invariant paired
   # step-time ratio and the static 1F1B bubble fraction.
   if bench.get("onefonb_vs_gpipe") is not None:
     out["onefonb_vs_gpipe"] = float(bench["onefonb_vs_gpipe"])
   if bench.get("pp_bubble_fraction") is not None:
     out["pp_bubble_fraction"] = float(bench["pp_bubble_fraction"])
-  # Session-serving bench (bench.py --session): the load-invariant
+  # Session serving: the load-invariant
   # paired stateless/decode per-tick cost ratio + the absolute decode
   # tick (both at T=32, the headline config).
   if bench.get("session_vs_stateless") is not None:
@@ -476,11 +476,11 @@ def key_metrics(record: Dict[str, Any]) -> Dict[str, float]:
     out["decode_tick_ms"] = float(bench["decode_tick_ms"])
   if bench.get("decode_kernel_vs_xla") is not None:
     out["decode_kernel_vs_xla"] = float(bench["decode_kernel_vs_xla"])
-  # Fleet-serving bench (bench.py --fleet): the load-invariant paired
+  # Fleet serving: the load-invariant paired
   # replica-scaling ratio and the rollout-window shed/failure count.
   if bench.get("fleet_vs_single_replica") is not None:
     out["fleet_vs_single_replica"] = float(bench["fleet_vs_single_replica"])
-  # Chaos bench (bench.py --chaos): goodput under the seeded fault
+  # Chaos: goodput under the seeded fault
   # storm vs clean, and the worst per-fault-class recovery time.
   if bench.get("chaos_goodput_ratio") is not None:
     out["chaos_goodput_ratio"] = float(bench["chaos_goodput_ratio"])
@@ -489,7 +489,7 @@ def key_metrics(record: Dict[str, Any]) -> Dict[str, float]:
   rollout = bench.get("rollout") or {}
   if rollout.get("window_shed") is not None:
     out["fleet_rollout_shed"] = float(rollout["window_shed"])
-  # graftforge bench (bench.py --forge): the paired cold/forged start
+  # graftforge: the paired cold/forged start
   # ratio, the absolute forged start, and the forged start's compile
   # share (0 when every rung deserialized).
   if bench.get("forged_vs_cold") is not None:
@@ -505,7 +505,7 @@ def key_metrics(record: Dict[str, Any]) -> Dict[str, float]:
     out["lint_parse_ms"] = float(bench["lint_parse_ms"])
   if bench.get("lint_rules_ms") is not None:
     out["lint_rules_ms"] = float(bench["lint_rules_ms"])
-  # graftrace telemetry (bench.py --fleet): traced-arm queue-wait p99
+  # graftrace telemetry: traced-arm queue-wait p99
   # and the paired tracing-overhead ratio, diff-gated like every other
   # bench family.
   if bench.get("serve_queue_wait_p99_ms") is not None:
@@ -513,7 +513,7 @@ def key_metrics(record: Dict[str, Any]) -> Dict[str, float]:
         bench["serve_queue_wait_p99_ms"])
   if bench.get("trace_overhead_ratio") is not None:
     out["trace_overhead_ratio"] = float(bench["trace_overhead_ratio"])
-  # graftwatch telemetry (bench.py --fleet): the ledger's fleet-wide
+  # graftwatch telemetry: the ledger's fleet-wide
   # device utilization and the SLO engine's worst fast-window burn.
   if bench.get("fleet_utilization") is not None:
     out["fleet_utilization"] = float(bench["fleet_utilization"])
@@ -621,9 +621,9 @@ def comparability_warnings(a: Dict[str, Any], b: Dict[str, Any]
                            ) -> List[str]:
   """Reasons the two records' deltas may not be meaningful.
 
-  The recurring case: `bench.py --smoke` (the CPU smoke config, its own
-  metric name, NOT comparable to the TPU number — bench.py docstring)
-  and the TPU bench both land in the same `runs.jsonl`, and
+  The recurring case: a CPU smoke record (its own metric name, NOT
+  comparable to a TPU number) and a TPU record both land in the same
+  `runs.jsonl`, and
   `key_metrics` folds both onto `examples_per_sec`. Diffing across
   that boundary must shout, not silently flag a bogus regression.
   """

@@ -22,8 +22,9 @@ costs ZERO extra device round trips. Detectors:
   persistent shift is re-admitted into the baseline after
   `spike_adapt_after` windows so a degraded-for-good regime does not
   flood incidents forever. Records flagged `barrier_dominated` (the
-  timing is a clamped upper bound, `backend.time_train_steps_halves`)
-  are excluded from BOTH detection and the running statistics.
+  barrier fetch swallowed the window, so `step_ms` is an upper bound:
+  `stepstats.BARRIER_DOMINATED_RESIDUAL`) are excluded from BOTH
+  detection and the running statistics.
 * **data starvation** — `data_wait_ms/step_ms` above a fraction for N
   consecutive windows (latched: one incident per starvation episode).
 * **non-finite divergence** — `nonfinite_params` piggybacked on the

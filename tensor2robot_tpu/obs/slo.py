@@ -325,7 +325,7 @@ class SloEngine:
     return record
 
   def state(self, now: Optional[float] = None) -> Dict[str, Any]:
-    """JSON-safe per-spec budget state (the bench/loop `slo` block)."""
+    """JSON-safe per-spec budget state (the loop's `slo` block)."""
     out: Dict[str, Any] = {}
     for spec in self._specs:
       st = self._state[spec.name]
@@ -402,7 +402,7 @@ def default_serving_slos(latency_budget: float = 0.01,
                          slow_window_s: float = 300.0,
                          burn_factor: float = DEFAULT_BURN_FACTOR
                          ) -> List[SloSpec]:
-  """The stock serving objectives (fleet bench, watch default):
+  """The stock serving objectives (watch default):
   latency-SLO breach ratio and fleet shed ratio over routed requests.
   Budgets/windows are explicit HERE so every construction site stays
   `slo-unbudgeted`-clean — override per deployment via config."""

@@ -62,8 +62,8 @@ __all__ = ["StepStatsRecorder"]
 
 # A window whose post-barrier residual is below this fraction of the
 # window is "barrier dominated": its step_ms is an upper bound, not a
-# measurement (the same 0.2 clamp rule as backend.time_train_steps_halves)
-# — flagged in the record so obs.sentinel's spike detector skips it.
+# measurement — flagged in the record so obs.sentinel's spike detector
+# skips it.
 BARRIER_DOMINATED_RESIDUAL = 0.2
 
 # A dispatch call taking longer than BOTH this floor and 10x the running
@@ -255,9 +255,9 @@ class StepStatsRecorder:
         "examples_per_sec": n * self._batch_size / window_s,
         "compile": float(self._compile_in_window > 0),
         "steps_in_window": float(n),
-        # The 0.2-residual clamp rule (backend.time_train_steps_halves):
-        # a window the barrier fetch swallowed is an upper bound — the
-        # sentinel spike detector must skip it.
+        # BARRIER_DOMINATED_RESIDUAL: a window the barrier fetch
+        # swallowed is an upper bound — the sentinel spike detector
+        # must skip it.
         "barrier_dominated": float(
             window_s * 1e9 - self._barrier_ns
             < BARRIER_DOMINATED_RESIDUAL * window_s * 1e9),

@@ -143,8 +143,8 @@ def _event_size(event: Dict[str, Any]) -> int:
   """Cheap per-event byte estimate for the ring's byte bound: fixed
   framing + name/cat + per-arg framing + string payload lengths.
   Deliberately NOT json.dumps or str(args) (either would dominate the
-  cost of every append — str(args) alone was ~40% of the traced-arm
-  fleet-bench overhead); non-string values count a flat 8, so the
+  cost of every append — str(args) alone was ~40% of the tracing
+  overhead of a traced fleet run on the CPU); non-string values count a flat 8, so the
   estimate only needs to be proportional, the bound is approximate."""
   size = 96 + len(event.get("name", "")) + len(event.get("cat", ""))
   args = event.get("args")

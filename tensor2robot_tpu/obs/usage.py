@@ -30,7 +30,7 @@ both from dispatch windows the serving path ALREADY times:
 
 Every `record_busy` also mirrors into the active metrics registry
 (`serve/fleet/busy_ms/<group>` + `serve/fleet/busy_requests/<group>`
-counters), so bench `metrics.isolated()` windows and graftrace metrics
+counters), so `metrics.isolated()` windows and graftrace metrics
 shards carry per-group busy time for `graftscope watch` without
 touching the ledger object. `summary()` exports the
 `serve/fleet/device_seconds_{busy,idle}` / `serve/fleet/utilization` /
@@ -83,8 +83,8 @@ class UsageLedger:
   `clock` is injectable (monotonic seconds) so the reconciliation
   arithmetic is testable without sleeping; production callers leave the
   default. `name` prefixes the mirrored registry counters/gauges —
-  the fleet passes its own name so two fleets in one process (the
-  bench's single + duo arms) stay distinguishable.
+  the fleet passes its own name so two fleets in one process stay
+  distinguishable.
   """
 
   def __init__(self, name: str = "serve/fleet",
@@ -138,7 +138,7 @@ class UsageLedger:
           or now - entry.samples[-1][0] >= self._sample_interval_s):
         entry.samples.append((now, entry.busy_s))
     # Registry mirror (counters live in whatever registry is active —
-    # bench isolation windows and graftrace shards see per-group busy
+    # isolation windows and graftrace shards see per-group busy
     # without holding the ledger).
     obs_metrics.counter(f"{self._name}/busy_ms/{group}").inc(
         float(busy_s) * 1e3)
@@ -195,7 +195,7 @@ class UsageLedger:
     return min(busy / wall, 1.0), coverage
 
   def summary(self, now: Optional[float] = None) -> Dict[str, Any]:
-    """The JSON utilization block (runs.jsonl / bench headline), and
+    """The JSON utilization block (runs.jsonl), and
     the gauge export. busy + idle == wall x devices by construction."""
     at = self._clock() if now is None else now
     groups_out: Dict[str, Any] = {}

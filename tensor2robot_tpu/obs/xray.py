@@ -34,7 +34,7 @@ on ANY analysis or compiled-call failure.
 graftcache (PR 7): `analyze_jit`/`XrayedFunction` take a `cache=` seam
 (`obs.excache`) that persists the AOT executables they produce and
 short-circuits lower+compile with a deserialize on later processes —
-trainer restarts, serving cold starts, and bench probes warm-start in
+trainer restarts and serving cold starts warm-start in
 milliseconds. All cache failure modes degrade to the fresh compile.
 """
 
@@ -349,8 +349,7 @@ class XrayedFunction:
     self._fn = fn
     self._registry = registry or metrics_lib.get_registry()
     # graftcache seam: a persisted executable turns the first call's
-    # compile into a deserialize (trainer restarts / bench probes warm-
-    # start); all cache failure modes already degrade inside analyze_jit.
+    # compile into a deserialize (trainer restarts warm-start); all cache failure modes already degrade inside analyze_jit.
     self._cache = cache
     self._compiled = None
     self._record: Optional[Dict[str, Any]] = None

@@ -214,7 +214,7 @@ class DevicePrefetcher:
   Iterating yields (features, labels) pairs already placed with
   `put_host_batch` — or, with a custom `place_fn`, whatever that
   returns (the train loop's stacked-group path places K-step groups
-  under the loop spec; the bench data probe device_puts to one device).
+  under the loop spec).
   Exceptions in the worker re-raise in the consumer; `close()` (also
   called on exhaustion) stops the worker promptly, and with
   `close_source` also closes a closable `dataset` (e.g. an

@@ -31,8 +31,8 @@ Two SCHEDULES share one tick skeleton (`_tick_plan`):
 `schedule_accounting` prices any (S, M, v) statically — total ticks,
 per-rank busy/idle ticks, bubble fraction — and every pipelined apply
 registers the result as `pp/*` gauges so the schedule win is observable
-in runs.jsonl (bench.py --pp measures the wall-clock side as
-`onefonb_vs_gpipe`; PERFORMANCE.md "Reading a pipeline bench").
+in runs.jsonl (the wall-clock side has no cell on the chip yet:
+ROADMAP W13).
 
 Two PARAM LAYOUTS feed the same schedules:
 

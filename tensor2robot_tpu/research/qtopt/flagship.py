@@ -1,12 +1,13 @@
 """The flagship measurement configuration of the QT-Opt grasping critic.
 
-One shared constructor so every measurement surface (bench.py and the
-TPU window tuning/latency scripts) times the SAME network:
-reference-scale Grasping44 — the 16-conv BN tower (stem + 6+6+3,
+One shared constructor so every surface that compiles or times the
+critic outside a gin file (the described-v5e compiles of
+tests/test_mosaic_lowering.py, `obs/forge.py`'s smoke plan) builds the
+SAME network: reference-scale Grasping44 — the 16-conv BN tower (stem + 6+6+3,
 reference /root/reference/research/qtopt/networks.py:299-615) at
 472x472x3 with named grasp-param blocks, bfloat16 compute and EMA —
 exactly what `research/qtopt/configs/train_qtopt.gin` trains. The small
-32-px smoke critic the CPU bench modes and tests use is the same
+32-px smoke critic the CPU tests use is the same
 constructor with `smoke=True`, asked for by name and never chosen from
 the platform.
 """
@@ -29,7 +30,7 @@ def make_flagship_model(device_platform: str, remat: bool = False,
   """Reference-scale Grasping44 critic, or with `smoke=True` the small
   smoke critic. `space_to_depth` folds the stem per
   Grasping44.space_to_depth (exact math, 4x the stem's MXU lane
-  utilization) — a bench probe, off by default. `image_size` overrides
+  utilization) — off by default; no cell runs it (ROADMAP D3). `image_size` overrides
   the reference 472 (reduced-scale CI compile twins stay on this one
   constructor instead of hand-copying it)."""
   full = not smoke

@@ -271,7 +271,7 @@ class MicroBatcher:
       # arrival (it may be idle) and batch-full (it should dispatch NOW
       # instead of at the flush deadline). Notifying on every arrival
       # costs a worker wakeup per request (GIL ping-pong measured at
-      # ~4 ms per batch-8 cycle on the CPU smoke bench — more than the
+      # ~4 ms per batch-8 cycle on a CPU run — more than the
       # batch's own compute).
       if was == 0 or (was < self._max_batch_size <= self._pending_rows):
         self._have_work.notify()
@@ -387,7 +387,7 @@ class MicroBatcher:
     finally:
       self._phase[0] = "gather"
     # Record batch telemetry BEFORE completing: a caller woken by
-    # complete() may snapshot the registry immediately (bench's
+    # complete() may snapshot the registry immediately (a
     # `metrics.isolated()` window closes as soon as run_load returns) —
     # counters incremented after the wake would race out of the
     # snapshot. A telemetry failure here cannot orphan a request: the

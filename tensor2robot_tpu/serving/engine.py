@@ -176,7 +176,7 @@ def traffic_bucket_ladder(sizes: Sequence[int],
 def ladder_padding_stats(sizes: Sequence[int],
                          ladder: Sequence[int]) -> Dict[str, float]:
   """Padding economics of `ladder` over observed `sizes`: the
-  fixed-vs-derived A/B numbers the fleet bench headlines.
+  fixed-vs-derived numbers.
   `padded_row_frac` is the fraction of dispatched rows that are padding;
   `dispatch_rows_per_row` the dispatched/requested row blow-up."""
   ladder = sorted(set(int(b) for b in ladder))

@@ -67,7 +67,7 @@ multislice):
   (`serve/fleet/probation_giveups`) until re-evicted/operator action —
   displaced-session reopen is unchanged either way. The `obs.faultlab`
   points `serve.dispatch` / `serve.latency` inject per-replica
-  dispatch failures and latency spikes for the chaos bench.
+  dispatch failures and latency spikes (tests/test_fleet.py).
 * ZERO-DOWNTIME ROLLOUT (`rollout()`): canary-first one-at-a-time
   checkpoint swap under live traffic. Per replica: steer the router
   around it, wait for its outstanding work to drain, `restore()` under
@@ -78,8 +78,7 @@ multislice):
   outputs); a canary verification failure aborts the rollout with the
   rest of the fleet still serving the OLD checkpoint. The pinned
   contract — no request fails, no fresh compile occurs during a
-  rollout — is asserted by tests/test_fleet.py and priced by
-  `bench.py --fleet`'s rollout window.
+  rollout — is asserted by tests/test_fleet.py.
 
 Traffic-derived bucket ladders (`engine.traffic_bucket_ladder` over the
 `serve/request_rows` reservoir) plug in through the factory: build the
@@ -483,8 +482,8 @@ class ServingFleet:
   def utilization_summary(self) -> Dict[str, Any]:
     """The fleet's device-time ledger block (`obs.usage.UsageLedger
     .summary`): per-replica busy/idle device-seconds, utilization, and
-    cost-per-request — the `utilization` block `bench.py --fleet`
-    appends to runs.jsonl and `graftscope watch` renders. Also exports
+    cost-per-request — the `utilization` block `graftscope watch`
+    renders. Also exports
     the `serve/fleet/device_seconds_{busy,idle}` / `.../utilization` /
     `.../cost_per_request_usd` gauges as a side effect."""
     return self._usage.summary()
@@ -767,7 +766,7 @@ class ServingFleet:
       ok = False
       health_relevant = True
       try:
-        # faultlab seams (chaos bench): a latency spike holds the
+        # faultlab seams: a latency spike holds the
         # dispatch open (spec.arg ms), a dispatch fault fails it — both
         # INSIDE the health accounting, so injected faults exercise
         # exactly the eviction/failover machinery real ones do.
@@ -999,7 +998,7 @@ class ServingFleet:
   def warmup_provenance(self) -> List[Dict[str, Any]]:
     """Per-replica per-rung warmup provenance (`{replica, rung, source,
     ms, key}` — engine.warmup_provenance with the replica index stamped
-    in), for the run records the forge bench appends."""
+    in), for run records."""
     out: List[Dict[str, Any]] = []
     for replica in self._replicas:
       for entry in getattr(replica.engine, "warmup_provenance", []) or []:

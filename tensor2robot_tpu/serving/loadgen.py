@@ -11,8 +11,8 @@ The measurement half of the serving runtime:
   back-to-back against a predict callable (each thread's next request
   waits for its previous answer, the robot-fleet traffic shape); QPS
   plus latency percentiles from the `serve/request_ms` histogram.
-  Shared by `bench.py --serve` and `bin/run_graftserve.py` so the two
-  can never measure different things.
+  Shared by `bin/run_graftserve.py` and `chip_smoke.py`'s `serve` phase,
+  so the two can never measure different things.
 * `run_session_load` — OPEN loop, session-shaped (ISSUE 11 / ROADMAP
   item 1's trace-driven shape): session STARTS arrive by a Poisson
   process at a target rate whether or not earlier episodes finished

@@ -143,8 +143,8 @@ def resolve_decode_kernel(requested: Optional[bool],
   every other precondition already holds. Auto declines off-TPU
   because there the kernel tier runs the Pallas interpreter — a
   parity/smoke vehicle, not a win; `use_decode_kernel=True` still
-  forces it (that is how CPU tier-1 and the bench A/B arm run the
-  real kernel body)."""
+  forces it (that is how the CPU tier-1 tests run the real kernel
+  body)."""
   if requested is False:
     return False, "disabled (use_decode_kernel=False)"
   if not has_arena_fn:

@@ -278,8 +278,8 @@ def train_eval_model(
   bit-flipped step is quarantined and the next-newest serves), rebuilds
   the data stream from the input generator (deterministically re-seeded
   — a rewound run and a clean run resumed from the same checkpoint see
-  the same records, which is what makes the chaos bench's numerical-
-  parity pin possible), and continues. Each rewind is counted
+  the same records, which is what lets tests/test_graftguard.py hold a
+  rewound run to the parameters of a clean resume), and continues. Each rewind is counted
   (`train/rewinds`, wall time in `train/rewind_ms`); the budget is
   BOUNDED (`max_rewinds`) and exhausting it escalates to the existing
   flight-recorder abort — a model that keeps diverging is a bug, not
@@ -919,7 +919,8 @@ def train_eval_model(
         hooks_lib.call_hooks(hooks, "after_rewind", ctx, step)
         # Fresh, deterministically re-seeded stream: a rewound run and
         # a clean resume from the same checkpoint consume the same
-        # records (the chaos bench's numerical-parity pin).
+        # records (tests/test_graftguard.py holds the two to the same
+        # final parameters).
         train_dataset = input_generator_train.create_dataset(
             modes_lib.TRAIN)
         raw_train_dataset = train_dataset

@@ -1,4 +1,4 @@
-"""Tests for utils/backend.py: CPU pinning, the TPU requirement, timers.
+"""Tests for utils/backend.py: CPU pinning, the barriers, the v5e peaks.
 
 These run inside the conftest-pinned CPU process, so pin_cpu/assert here are
 exercising idempotent paths; the env-merge logic is tested directly on
@@ -72,89 +72,9 @@ def test_pin_cpu_before_backend_init_pins_a_fresh_process():
   assert done.returncode == 0 and "PINNED_OK" in done.stdout, done.stderr
 
 
-def test_require_tpu_refuses_a_cpu_process():
-  """The measurement scripts' first call: no chip, no number."""
-  with pytest.raises(RuntimeError, match="needs a TPU"):
-    backend.require_tpu()
-
-
-def test_require_tpu_returns_the_device(monkeypatch):
-  import jax
-
-  class _FakeTpu:
-    platform = "tpu"
-    device_kind = "TPU v5 lite"
-
-  monkeypatch.setattr(jax, "devices", lambda *a: [_FakeTpu()])
-  assert backend.require_tpu().device_kind == "TPU v5 lite"
-
-
 def test_assert_cpu_backend_passes_here():
   # conftest pinned this process to CPU, so the live backend is CPU.
   backend.assert_cpu_backend()
-
-
-def test_time_train_steps_runs_warmup_plus_iters_with_barriers():
-  """The shared timing helper executes warmup+iters steps and fetches a
-  param leaf as the barrier (the discipline every bench/tuning script
-  must share)."""
-  import numpy as np
-
-  calls = []
-
-  class _State:
-    params = {"w": np.zeros(3), "b": np.zeros(1)}
-
-  def step(state, features, labels):
-    calls.append((features, labels))
-    return state, {}
-
-  sec, out = backend.time_train_steps(step, _State(), "f", "l",
-                                      iters=4, warmup=2)
-  assert len(calls) == 6
-  assert calls[0] == ("f", "l")
-  assert sec >= 0
-  assert isinstance(out, _State)
-
-
-def test_time_train_steps_halves_reports_steady_state_separately():
-  """The split-halves timer must run exactly warmup+iters steps, split
-  the timed window into two barrier-separated halves, and report the
-  second (steady-state) half independently — the round-5 discipline
-  that keeps one-time remote allocation effects out of the headline
-  number. Semantic check: with a step whose first timed call is slow,
-  the first-half rate must come out slower than the second half."""
-  import time as _time
-
-  import numpy as np
-
-  calls = []
-
-  class _State:
-    params = {"w": np.zeros(3)}
-
-  def step(state, features, labels):
-    calls.append(1)
-    if len(calls) == 3:  # first TIMED step (after warmup=2)
-      _time.sleep(0.05)
-    return state, {}
-
-  h1, h2, out = backend.time_train_steps_halves(
-      step, _State(), "f", "l", iters=6, warmup=2)
-  assert len(calls) == 8
-  assert h1 > h2 > 0
-  assert isinstance(out, _State)
-
-
-def test_time_train_steps_halves_single_iter_degrades_gracefully():
-  import numpy as np
-
-  class _State:
-    params = {"w": np.zeros(1)}
-
-  h1, h2, _ = backend.time_train_steps_halves(
-      lambda s, f, l: (s, {}), _State(), "f", "l", iters=1, warmup=0)
-  assert h1 >= 0 and h2 == h1
 
 
 def test_state_barrier_fetches_smallest_param_leaf():
@@ -167,52 +87,38 @@ def test_state_barrier_fetches_smallest_param_leaf():
   np.testing.assert_array_equal(fetched, [7.0])
 
 
-def test_time_train_steps_halves_clamps_barrier_dominated_windows():
-  """ADVICE round 5: when the estimated barrier cost swallows a half's
-  window, the fallback must be max(residual, 0.2*window)/n — NOT the
-  full window (which re-includes the whole barrier and reads high) —
-  and out_flags must flag the record so autotune/sentinel treat the
-  number as an upper bound."""
-  import time as _time
-
+def test_sync_fetches_to_host_numpy():
+  """`sync` is the barrier every timed window in the repo closes on: it
+  returns the device value on the host, as numpy, so the computation
+  that produced it has finished (and a device error has surfaced)."""
+  import jax.numpy as jnp
   import numpy as np
 
-  class _SlowLeaf:
-    """Param leaf whose host fetch (the barrier) dominates the window."""
-    size = 1
-    shape = (1,)
-
-    def __array__(self, *a, **kw):
-      _time.sleep(0.03)
-      return np.zeros(1)
-
-  class _State:
-    params = {"w": _SlowLeaf()}
-
-  flags = {}
-  h1, h2, _ = backend.time_train_steps_halves(
-      lambda s, f, l: (s, {}), _State(), "f", "l", iters=4, warmup=0,
-      out_flags=flags)
-  assert flags.get("barrier_dominated") is True
-  # The clamp: a near-instant step under a ~30 ms barrier must come out
-  # far below the naive window/n fallback (which would be >= ~15 ms),
-  # yet strictly positive (downstream divides by it).
-  assert 0.0 < h1 < 0.015
-  assert 0.0 < h2 < 0.015
+  device_value = jnp.arange(6.0).reshape(2, 3) * 2.0
+  fetched = backend.sync(device_value)
+  assert type(fetched) is np.ndarray
+  np.testing.assert_array_equal(fetched, np.arange(6.0).reshape(2, 3) * 2.0)
 
 
-def test_time_train_steps_halves_leaves_flags_unset_when_clean():
-  import numpy as np
+@pytest.mark.parametrize("program,benchmark", [
+    ("V5E_PEAK_BF16_FLOPS", "bf16_flops_per_s"),
+    ("V5E_PEAK_HBM_BW", "hbm_bytes_per_s")])
+def test_program_peaks_match_the_benchmarks(program, benchmark):
+  """`obs/xray.py` prices its roofline with this module's two peaks and
+  the benchmark prices `step_mfu` with its own table: two copies that
+  may not drift apart."""
+  from benchmarks.harness import peaks
 
-  class _State:
-    params = {"w": np.zeros(3)}
+  assert getattr(backend, program) == peaks.PEAKS["TPU v5 lite"][benchmark]
 
-  flags = {}
-  def step(state, features, labels):
-    import time as _time
-    _time.sleep(0.005)
-    return state, {}
 
-  backend.time_train_steps_halves(step, _State(), "f", "l", iters=4,
-                                  warmup=0, out_flags=flags)
-  assert "barrier_dominated" not in flags
+def test_backend_exports_no_timer():
+  """One way to time: a barrier and a clock, where the measurement is
+  made. The module holds the barriers and no clock of its own."""
+  import inspect
+
+  public = {name for name, value in vars(backend).items()
+            if not name.startswith("_") and inspect.isfunction(value)}
+  assert public == {"pin_cpu", "assert_cpu_backend", "sync", "state_barrier",
+                    "device_memory_stats"}
+  assert "perf_counter" not in inspect.getsource(backend)

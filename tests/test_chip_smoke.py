@@ -10,6 +10,7 @@ functions); the script itself has no option that makes it a CPU run.
 
 import json
 import os
+import re
 import subprocess
 import sys
 
@@ -203,3 +204,35 @@ class TestContractRefusals:
     last = capsys.readouterr().out.strip().splitlines()[-1]
     assert json.loads(last) == {"ok": True, "device": tpu}
     assert chip_smoke.main(["--cpu"]) == 2  # no other option exists
+
+
+# A path of the checkout as a script names it; a sentence's full stop
+# is not part of it.
+_SCRIPT_PATH = re.compile(
+    r"\b(?:tensor2robot_tpu|tests|scripts|benchmarks|docs)(?:/[\w.-]*\w)+")
+_SCRIPT_IMPORT = re.compile(
+    r"^\s*from (tensor2robot_tpu[\w.]*) import (\w+)", re.M)
+
+
+@pytest.mark.parametrize("script", ["scripts/lint.sh",
+                                    "scripts/obs_report.sh"])
+def test_shell_wrapper_parses_and_names_what_exists(script):
+  """All that `scripts/` holds: the shell parses it (`bash -n`), every
+  path of the checkout it names is there, and so is every module its
+  embedded Python imports."""
+  path = os.path.join(REPO_ROOT, script)
+  checked = subprocess.run(["bash", "-n", path], capture_output=True,
+                           text=True)
+  assert checked.returncode == 0, checked.stderr
+  with open(path) as f:
+    text = f.read()
+  named = set(_SCRIPT_PATH.findall(text))
+  assert script in named  # its own usage line, at the least
+  for rel in sorted(named):
+    assert os.path.exists(os.path.join(REPO_ROOT, rel)), (script, rel)
+  imports = _SCRIPT_IMPORT.findall(text)
+  assert imports
+  for package, name in imports:
+    base = os.path.join(REPO_ROOT, *package.split("."))
+    assert (os.path.isfile(os.path.join(base, name + ".py"))
+            or os.path.isfile(base + ".py")), (script, package, name)

@@ -1,5 +1,5 @@
 """Tests for graftcache (`obs/excache.py`): the persistent
-executable/AOT cache, its xray/engine/bench integration, the
+executable/AOT cache, its xray/engine integration, the
 `graftscope cache` CLI, and the `cache-key-missing-component` lint rule.
 
 Contracts (ISSUE 7):
@@ -427,9 +427,9 @@ class TestMaintenance:
     assert not xla_dir.exists()
 
   def test_evict_by_name_prefix_spares_other_namespaces(self, tmp_path):
-    """The cold-start bench resets ONLY its own namespace — a blanket
-    evict in a shared cache dir would re-tax every probe's entries
-    (one compile each)."""
+    """A caller resets ONLY its own namespace — a blanket evict in a
+    shared cache dir would re-tax every other caller's entries (one
+    compile each)."""
     import jax.numpy as jnp
 
     cache = excache.ExecutableCache(str(tmp_path / "exc"))
@@ -782,7 +782,7 @@ def _serving_predictor():
 class TestCachePlacement:
   """ISSUE 22 §4: the compile cache is placed from outside or sits at
   one fixed path in the checkout — never under a model_dir, never
-  nulled, never moved by trainer, server or bench."""
+  nulled, never moved by trainer or server."""
 
   def test_env_var_places_both_tiers(self, monkeypatch, tmp_path):
     import jax
@@ -816,13 +816,13 @@ class TestCachePlacement:
     assert jax.config.jax_compilation_cache_dir == xla_dir
     assert jax.config.jax_enable_compilation_cache
 
-  def test_trainer_server_and_bench_never_move_the_cache(
+  def test_trainer_and_server_never_move_the_cache(
       self, tmp_path, monkeypatch):
     """Every `jax_compilation_cache_dir` update made by the trainer
-    (train and eval modes, a resume among them), a serving engine warmup
-    and a bench probe is recorded: none names another directory than
-    the placed one, and train mode does not null it. "auto" is the
-    cache root, not `<model_dir>/excache`."""
+    (train and eval modes, a resume among them) and a serving engine
+    warmup is recorded: none names another directory than the placed
+    one, and train mode does not null it. "auto" is the cache root, not
+    `<model_dir>/excache`."""
     import jax
 
     from tensor2robot_tpu import serving, train_eval
@@ -855,11 +855,6 @@ class TestCachePlacement:
     with metrics_lib.isolated():
       serving.BucketedEngine(predictor=predictor, max_batch_size=2,
                              cache=excache.cache_root()).warmup()
-      bench = _load_bench()
-      rec = bench.probe_main({"platform": "cpu", "batch_size": 4,
-                              "reruns": 1,
-                              "cache_dir": str(tmp_path / "exc")})
-    assert rec["ok"]
     assert jax.config.jax_compilation_cache_dir == placed
     assert set(seen) <= {placed}, seen
 
@@ -893,54 +888,6 @@ class TestCachePlacement:
                    if r["name"] == "train_step"]
     assert step_rec["cache"]["hit"] is True
     assert np.isfinite(warm["extra"]["final_metrics"]["loss"])
-
-
-# ---------------------------------------------------------------------------
-# bench.py: the data-fed smoke probe (ROADMAP item 5 remainder).
-# ---------------------------------------------------------------------------
-
-
-def _load_bench():
-  import importlib.util
-
-  path = os.path.join(REPO_ROOT, "bench.py")
-  spec = importlib.util.spec_from_file_location("bench_under_excache",
-                                               path)
-  module = importlib.util.module_from_spec(spec)
-  spec.loader.exec_module(module)
-  return module
-
-
-def test_smoke_probe_measures_real_data_path(tmp_path, monkeypatch):
-  """The CPU-smoke probe with `data_path` feeds the train step from the
-  REAL record pipeline (TFRecords -> parse -> preprocess -> place) as
-  back-to-back A/B pairs against the synthetic feed, and reports the
-  record-fed number as `examples_per_sec` with the load-invariant
-  pair-median ratio alongside."""
-  bench = _load_bench()
-  monkeypatch.setattr(bench, "SMOKE_DATA_RECORDS", 128)
-  monkeypatch.setattr(bench, "SMOKE_DATA_FILES", 2)
-  with metrics_lib.isolated():
-    rec = bench.probe_main({"platform": "cpu", "batch_size": 4,
-                            "reruns": 2, "data_path": True,
-                            "cache_dir": str(tmp_path / "exc")})
-  assert rec["ok"]
-  data = rec["data_path"]
-  assert data["pairs"] == 2
-  assert data["examples_per_sec"] > 0
-  assert 0 < data["vs_synthetic"]
-  assert rec["examples_per_sec"] == data["examples_per_sec"]
-  assert rec["synthetic_examples_per_sec"] > 0
-  # The probe's compiles were persisted: a second probe at the same
-  # config starts warm (the bench-probe acceptance).
-  with metrics_lib.isolated():
-    rec2 = bench.probe_main({"platform": "cpu", "batch_size": 4,
-                             "reruns": 1,
-                             "cache_dir": str(tmp_path / "exc")})
-    snap_hits = metrics_lib.snapshot().get("counter/cache/hits", 0.0)
-  assert rec2["ok"]
-  assert snap_hits >= 1.0
-  assert (rec2["xray"] or {}).get("cache", {}).get("hit") is True
 
 
 # ---------------------------------------------------------------------------

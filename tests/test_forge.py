@@ -135,7 +135,7 @@ class TestPlanEnumeration:
   def test_unbound_mesh_shape_records_default_not_single_device(self):
     # train_eval builds the all-devices default mesh when mesh_shape is
     # unbound — the worker must key THAT executable, not a one-chip one
-    # (None is reserved for hand-built one-chip plans, bench.py).
+    # (None is reserved for hand-built one-chip plans).
     plan = forge.plan_from_config(
         [_cfg("train_pipelined_1f1b.gin")],
         ["train_eval_model.mesh_shape = None"])
@@ -332,8 +332,7 @@ def serving_engine(max_batch_size=4, cache=None, name="serve/engine",
 
 
 class _SwapOkPredictor:
-  """restore() always finds a 'new checkpoint' (bench _HotSwapPredictor
-  shape) so rollout() proceeds."""
+  """restore() always finds a 'new checkpoint', so rollout() proceeds."""
 
   def __init__(self, predictor):
     self._predictor = predictor
@@ -612,8 +611,8 @@ class TestForgeCLI:
     assert "no model source" in capsys.readouterr().err
 
   def test_cache_dir_auto_is_the_cache_root(self, capsys):
-    """`--cache-dir auto` (the default) is where trainer, servers and
-    bench look: `excache.cache_root()`, no model_dir involved."""
+    """`--cache-dir auto` (the default) is where trainer and servers
+    look: `excache.cache_root()`, no model_dir involved."""
     assert graftscope.main(
         ["forge", _cfg("serve_session.gin"), "--model",
          "SequenceRegressionModel", "--verify"]) == 1  # nothing forged yet
@@ -670,8 +669,7 @@ class TestWarmupUnforgeableRule:
 
   def test_repo_pinned_clean(self):
     findings = [f for f in lint_lib.run(
-        [os.path.join(REPO_ROOT, "tensor2robot_tpu"),
-         os.path.join(REPO_ROOT, "bench.py")])
+        [os.path.join(REPO_ROOT, "tensor2robot_tpu")])
         if f.rule == "warmup-unforgeable"]
     assert findings == []
 

@@ -593,6 +593,39 @@ class TestDivergenceRewind:
     from tensor2robot_tpu.obs import flightrec
     assert flightrec.find_bundles(str(tmp_path / "m"))
 
+  def test_rewound_run_reaches_the_params_of_a_clean_resume(self,
+                                                             tmp_path):
+    """A rewind costs nothing but time: the run that hit a NaN at step
+    6 and rewound to the verified step-4 checkpoint ends with the same
+    parameters as a run that was simply resumed from that checkpoint
+    (the rewind re-seeds the data stream as a resume does)."""
+    from tensor2robot_tpu import train_eval
+
+    plan = faultlab.FaultPlan([
+        faultlab.FaultSpec(point=faultlab.TRAIN_NONFINITE, at=(6,),
+                           count=1)], seed=0)
+    self._run(tmp_path / "rewound", plan)
+    source = tmp_path / "rewound" / train_eval.CHECKPOINT_DIRNAME / "4"
+    clean = tmp_path / "clean" / train_eval.CHECKPOINT_DIRNAME
+    clean.mkdir(parents=True)
+    shutil.copytree(source, clean / "4")
+    self._run(tmp_path / "clean", faultlab.FaultPlan([], seed=0))
+
+    def final_params(model_dir):
+      with checkpoints_lib.CheckpointManager(os.path.join(
+          str(model_dir), train_eval.CHECKPOINT_DIRNAME)) as manager:
+        restored = manager.restore()
+        assert manager.last_restored_step == 12
+        return restored["params"]
+
+    rewound, resumed = (final_params(tmp_path / name)
+                        for name in ("rewound", "clean"))
+    assert (jax.tree_util.tree_structure(rewound)
+            == jax.tree_util.tree_structure(resumed))
+    for a, b in zip(jax.tree_util.tree_leaves(rewound),
+                    jax.tree_util.tree_leaves(resumed)):
+      np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
   def test_rewind_resaves_quarantined_step(self, tmp_path):
     """A checkpoint step quarantined by the rewind's restore walk must
     be SAVED AGAIN when the replay re-crosses it — the save-dedup set

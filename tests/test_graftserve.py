@@ -474,49 +474,6 @@ class TestPolicyIntegration:
 
 
 # ---------------------------------------------------------------------------
-# Serve bench: headline schema + runlog regression gating.
-# ---------------------------------------------------------------------------
-
-
-class TestServeBench:
-
-  def test_serve_smoke_headline_and_runlog_gate(self, tmp_path,
-                                                capsys, monkeypatch):
-    import bench
-
-    runs_path = str(tmp_path / "runs.jsonl")
-    monkeypatch.setenv("GRAFTSCOPE_RUNS", runs_path)
-    bench.serve_main(requests_per_thread=20)
-    headline = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
-    assert headline["metric"] == "qtopt_serve_qps_cpu_smoke"
-    assert headline["unit"] == "requests/sec"
-    assert headline["value"] > 0
-    assert headline["unbatched_qps"] > 0
-    assert headline["batched_vs_unbatched"] is not None
-    assert headline["engine_compiles"] == len(headline["buckets"])
-    assert {"p50", "p95", "p99"} <= set(headline["latency_ms"])
-    assert headline["sweep"][-1]["concurrency"] == bench.SERVE_CONCURRENCY
-
-    from tensor2robot_tpu.obs import runlog
-    records = runlog.load_records(runs_path)
-    assert len(records) == 1
-    assert records[0]["kind"] == "bench"
-    assert records[0]["bench"]["metric"] == "qtopt_serve_qps_cpu_smoke"
-    assert records[0]["compile"], "per-bucket compile telemetry missing"
-
-    # A 50% serve-throughput drop must gate: append a degraded record
-    # and require `graftscope diff` to exit 3 — serving regressions are
-    # fenced exactly like training ones.
-    degraded = dict(records[0])
-    degraded["bench"] = dict(records[0]["bench"],
-                             value=records[0]["bench"]["value"] * 0.5)
-    runlog.append_record(runs_path, degraded)
-    from tensor2robot_tpu.bin import graftscope
-    rc = graftscope.main(["diff", runs_path + "#0", runs_path + "#1"])
-    assert rc == 3
-
-
-# ---------------------------------------------------------------------------
 # Tier-1: serving/ is backend-free (poisoned-platform trap).
 # ---------------------------------------------------------------------------
 

@@ -25,10 +25,8 @@ other tests than its peers (see the on-chip-measurement guide, §2).
 
 from __future__ import annotations
 
-import importlib
 import json
 import os
-import sys
 
 import jax
 import jax.numpy as jnp
@@ -313,6 +311,12 @@ def _compile_step_for_mesh(model, mesh, batch, rules=None, donate=False):
   return _lower_step_for_mesh(model, mesh, batch, rules, donate).compile()
 
 
+def _cost_analysis(compiled) -> dict:
+  """The compiler's cost record (older jax wraps it in a list)."""
+  cost = compiled.cost_analysis()
+  return cost[0] if isinstance(cost, (list, tuple)) else cost
+
+
 def _lower_step_for_mesh(model, mesh, batch, rules=None, donate=False):
   """`_compile_step_for_mesh` up to the lowered program."""
   from tensor2robot_tpu import specs as specs_lib
@@ -407,8 +411,7 @@ class TestShippedStepsCompileForV5e:
   # (`ops/attention.py:_flash_bwd_dkv_kernel`), which leaves q and dO as
   # its only whole-T operands (1 MB each at T 8192) and lets the shipped
   # step compile at the default compiler options; `PERF.md` section 7 has
-  # the temporaries of each. (`scripts/tpu_seq_timing.py` still raises
-  # `xla_tpu_scoped_vmem_limit_kib` for T 8192; it no longer has to.)
+  # the temporaries of each.
   @pytest.mark.parametrize("seq_len,batch", [
       (4096, None), (4096, 16), (4096, 64), (8192, None), (2048, 128),
       (8192, 32)])
@@ -630,41 +633,44 @@ class TestMultisliceDCNHybridCompilesForV5e:
 
 
 class TestAOTCostPins:
-  """Compiler-cost regression guard: the flagship b64/b128 train-step
-  flops and bytes-accessed, as computed by the real local XLA:TPU v5e
-  compiler, must stay within 10% of the values committed in
-  AOT_ANALYSIS_r04.json. Without this, a refactor that doubles
-  bytes/step (e.g. re-introducing the round-2 f32 activation leak,
-  which was exactly a 1.5x bytes regression) passes every green test
-  and silently burns chip time. Half a minute of compile each.
+  """Compiler-cost regression guard: the flagship b64/b128/b256
+  train-step flops and bytes accessed, as the v5e compiler counts them
+  for the production-sharded one-chip step, must stay within 10% of the
+  values committed in AOT_ANALYSIS_r04.json. Without this, a refactor
+  that doubles bytes/step (e.g. re-introducing the round-2 f32
+  activation leak, which was exactly a 1.5x bytes regression) passes
+  every green test and silently burns chip time. Half a minute of
+  compile each.
 
-  On an intentional cost change (new stem, different fusion), rerun
-  `python scripts/tpu_aot_analysis.py sweep` and re-commit the artifact
-  with the rationale in PERFORMANCE.md — the failure message prints the
-  new record to make that a copy-paste."""
+  On an intentional cost change (new stem, different fusion), re-commit
+  the pin in AOT_ANALYSIS_r04.json with the reason in CHANGES.md — the
+  failure message prints the compiler's new numbers to make that a
+  copy-paste."""
 
   # 256 is the SHIPPED batch (train_qtopt_tpu_tuned.gin): the chip
   # measured 6.441 TF / 39.63 GB per step at b256 on 2026-07-31 (old
   # setup) — within 0.5% of this pin, so a pin breach is a real program
   # change.
   @pytest.mark.parametrize("batch", [64, 128, 256])
-  def test_flagship_cost_within_10pct_of_committed(self, batch, topo):
-    del topo  # the script describes its own; this skips where it cannot
-    scripts_dir = os.path.join(_REPO_ROOT, "scripts")
-    if scripts_dir not in sys.path:
-      sys.path.insert(0, scripts_dir)
-    aot = importlib.import_module("tpu_aot_analysis")
+  def test_flagship_cost_within_10pct_of_committed(self, batch,
+                                                   v5e_devices):
+    from tensor2robot_tpu.research.qtopt import flagship
+
     with open(os.path.join(_REPO_ROOT, "AOT_ANALYSIS_r04.json")) as f:
       matrix = json.load(f)["flagship_lever_matrix"]
     pinned = {e["config"]: e for e in matrix}[
         f"grasping44_472_bf16_b{batch}"]
-    got = aot.step_analysis(batch, remat=False)
-    for key in ("flops_per_step_tf", "bytes_per_step_gb"):
+    cost = _cost_analysis(_compile_step_for_mesh(
+        flagship.make_flagship_model("tpu"),
+        Mesh(v5e_devices[:1], ("data",)), batch))
+    got = {"flops_per_step_tf": cost["flops"] / 1e12,
+           "bytes_per_step_gb": cost["bytes accessed"] / 1e9}
+    for key, now in got.items():
       want = pinned[key]
-      assert abs(got[key] - want) <= 0.10 * want, (
+      assert abs(now - want) <= 0.10 * want, (
           f"{key} at batch {batch} drifted >10% from the committed pin: "
-          f"pinned={want}, now={got[key]}. If intentional, re-baseline "
-          f"AOT_ANALYSIS_r04.json with this record: {got}")
+          f"pinned={want}, now={now}. If intentional, re-commit "
+          f"the pin in AOT_ANALYSIS_r04.json with these numbers: {got}")
 
 
 class TestTrainLoopCompilesForV5e:
@@ -683,8 +689,7 @@ class TestTrainLoopCompilesForV5e:
     # cost analysis must price the real program.
     compiled = _compile_loop_for_mesh(model, mesh, batch=8, loop_k=4,
                                       rules=ts.fsdp_rules())
-    cost = compiled.cost_analysis()
-    cost = cost[0] if isinstance(cost, (list, tuple)) else cost
+    cost = _cost_analysis(compiled)
     assert cost.get("flops", 0) > 0
 
   def test_flagship_eval_loop_compiles_sharded(self, v5e_devices):
@@ -721,10 +726,10 @@ class TestTrainLoopCompilesForV5e:
 
 
 class TestSpaceToDepthStemCompilesForV5e:
-  """bench.py probes the space-to-depth stem on the chip at the winning
-  batch WITH the winning remat setting (bench probes s2d after remat);
-  certify both combinations compile for v5e (reduced image scale for CI
-  time) so the probe can never burn chip time on a compile failure."""
+  """`Grasping44.space_to_depth` is a shipped option no cell runs yet
+  (ROADMAP D3): certify that it compiles for v5e with remat off and on
+  (reduced image scale for CI time), so its first chip run cannot burn
+  chip time on a compile failure."""
 
   @pytest.mark.parametrize("remat", [False, True])
   def test_s2d_grasping44_train_step_compiles(self, remat, v5e_devices):

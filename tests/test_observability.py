@@ -655,16 +655,46 @@ class TestDeviceTimingRule:
         "  # graftlint: disable=device-timing")
     assert tracer_check.check_python_source(src, "x.py") == []
 
-  def test_obs_and_backend_paths_exempt(self, tmp_path):
-    for rel in ("tensor2robot_tpu/obs/timing.py", "utils/backend.py"):
-      target = tmp_path / rel
-      target.parent.mkdir(parents=True, exist_ok=True)
-      target.write_text(_BAD_TIMING)
-      assert tracer_check.check_python_file(str(target)) == []
-    plain = tmp_path / "plain.py"
-    plain.write_text(_BAD_TIMING)
-    assert self._rules(tracer_check.check_python_file(str(plain))) \
-        == {"device-timing"}
+  def test_obs_paths_exempt(self, tmp_path):
+    """obs/ owns the instrumentation clocks; utils/backend.py holds no
+    clock any more and is held to the rule like any other file."""
+    target = tmp_path / "tensor2robot_tpu/obs/timing.py"
+    target.parent.mkdir(parents=True, exist_ok=True)
+    target.write_text(_BAD_TIMING)
+    assert tracer_check.check_python_file(str(target)) == []
+    for rel in ("plain.py", "utils/backend.py"):
+      plain = tmp_path / rel
+      plain.parent.mkdir(parents=True, exist_ok=True)
+      plain.write_text(_BAD_TIMING)
+      assert self._rules(tracer_check.check_python_file(str(plain))) \
+          == {"device-timing"}
+
+  @pytest.mark.parametrize(
+      "timer", ["time_op", "time_train_steps", "time_train_steps_halves"])
+  def test_a_deleted_backend_timer_closes_no_window(self, timer):
+    """The three timers left `utils/backend` (PR 31); a call by one of
+    their names is no barrier, so a window that ends in one is a
+    finding like any other unbarriered window."""
+    src = _BAD_TIMING.replace(
+        "  return time.perf_counter() - t0",
+        f"  from tensor2robot_tpu.utils import backend\n"
+        f"  backend.{timer}(f, y)\n"
+        f"  return time.perf_counter() - t0")
+    out = tracer_check.check_python_source(src, "x.py")
+    assert self._rules(out) == {"device-timing"}
+
+  def test_message_names_only_what_backend_exports(self):
+    """Whoever trips the rule is sent to functions that exist."""
+    import re
+
+    from tensor2robot_tpu.utils import backend
+
+    (finding,) = tracer_check.check_python_source(_BAD_TIMING, "x.py")
+    named = re.findall(r"\bbackend\.(\w+)", finding.message)
+    assert named == ["sync"]
+    assert all(callable(getattr(backend, name)) for name in named)
+    assert "jax.block_until_ready" in finding.message
+    assert "time_" not in finding.message
 
   def test_nested_function_body_not_part_of_window(self):
     src = ("import time\nimport jax.numpy as jnp\n\ndef f(x):\n"

@@ -611,6 +611,50 @@ class TestTrainEvalOverlapKnobs:
     assert params["host_overlap_queue_mb"].default is None
     assert params["device_prefetch_depth"].default == 2
 
+  def test_trainer_fed_by_records_through_the_overlapped_plane(self,
+                                                              tmp_path):
+    """`train_eval_model` on TFRecords: with the overlap plane on (the
+    default) every batch reaches the step through the OverlappedLoader's
+    stages and the DevicePrefetcher's place stage, and the run ends on
+    the very loss the serial chain (`overlap=False`) ends on."""
+    from tensor2robot_tpu import train_eval
+    from tensor2robot_tpu.utils import mocks
+
+    model_spec = mocks.MockT2RModel(device_type="cpu")
+    merged = SpecStruct(dict(
+        model_spec.get_feature_specification("train").items(),
+        y=model_spec.get_label_specification("train")["y"]))
+    x, y = mocks.make_separable_data(64)
+    path = str(tmp_path / "d.tfr")
+    with tfrecord.RecordWriter(path) as writer:
+      for i in range(64):
+        writer.write(codec.encode_example({"x": x[i], "y": y[i]}, merged))
+    steps = 6
+
+    def run(name, overlap_on):
+      with metrics_lib.isolated():
+        metrics = train_eval.train_eval_model(
+            model=mocks.MockT2RModel(device_type="cpu"),
+            model_dir=str(tmp_path / name), mode="train",
+            max_train_steps=steps, checkpoint_every_n_steps=steps,
+            log_every_n_steps=steps, mesh_shape=(1, 1, 1),
+            executable_cache_dir=None,
+            input_generator_train=(
+                input_generators.DefaultRecordInputGenerator(
+                    file_patterns=path, batch_size=8, seed=0,
+                    overlap=overlap_on)))
+        return metrics, metrics_lib.snapshot(prefix="data/")
+
+    overlapped, seen = run("overlapped", None)
+    assert seen["counter/data/overlap_batches"] >= steps
+    assert seen["hist/data/overlap_parse_ms/count"] >= steps
+    # The trainer draws its first batch itself, to shape the state.
+    assert seen["hist/data/overlap_place_ms/count"] >= steps - 1
+    serial, seen = run("serial", False)
+    assert "counter/data/overlap_batches" not in seen
+    assert np.isfinite(overlapped["loss"])
+    assert overlapped["loss"] == serial["loss"]
+
   def test_generators_without_record_pipeline_accept_options(self):
     gen = input_generators.DefaultRandomInputGenerator(batch_size=2)
     gen.set_overlap_options(num_parallel_parses=4)  # accepted, ignored

@@ -123,9 +123,9 @@ class TestDetectors:
     assert [i["step"] for i in s.incidents()] == [20, 60]
 
   def test_barrier_dominated_records_skip_spike_detection(self):
-    """The round-5 clamp contract: a barrier-dominated window's step_ms
-    is an UPPER BOUND (backend.time_train_steps_halves), not a
-    measurement — the spike detector must ignore it entirely."""
+    """A barrier-dominated window's step_ms is an UPPER BOUND
+    (stepstats.BARRIER_DOMINATED_RESIDUAL), not a measurement — the
+    spike detector must ignore it entirely."""
     s = sentinel_lib.Sentinel()
     for i in range(20):
       s.observe_step_record(i, _steady())
@@ -710,105 +710,6 @@ def test_finite_stream_mid_group_batches_are_single_stepped(tmp_path):
   # A finite stream ending is the loop-exit contract, not a crash: the
   # flight recorder must NOT have dumped an exception bundle for it.
   assert flightrec_lib.find_bundles(str(tmp_path)) == []
-
-
-# ---------------------------------------------------------------------------
-# bench.py: the headline mode measures the chip or fails.
-# ---------------------------------------------------------------------------
-
-
-def _load_bench():
-  path = os.path.join(REPO_ROOT, "bench.py")
-  spec = importlib.util.spec_from_file_location("bench_under_test", path)
-  module = importlib.util.module_from_spec(spec)
-  spec.loader.exec_module(module)
-  return module
-
-
-def test_bench_default_mode_fails_without_a_chip():
-  """`python bench.py` on this CPU-only machine: the first probe child
-  finds jax on the CPU, and the bench exits non-zero WITHOUT printing a
-  number under any metric name."""
-  done = subprocess.run(
-      [sys.executable, "bench.py"], capture_output=True, text=True,
-      timeout=600, cwd=REPO_ROOT,
-      env={**os.environ, "JAX_PLATFORMS": "cpu"})
-  assert done.returncode != 0
-  assert done.stdout.strip() == "", done.stdout
-  assert "asked for platform 'tpu' but jax runs on 'cpu'" in done.stderr
-
-
-def test_bench_stops_at_every_probe_timing_out(monkeypatch, capsys):
-  """No probe produced a number: no headline, non-zero exit — never the
-  CPU smoke in its place."""
-  bench = _load_bench()
-  monkeypatch.setattr(bench, "_subprocess_probe",
-                      lambda *a, **k: {"timeout": True})
-  monkeypatch.setattr(bench, "smoke_main", lambda: 1 / 0)
-  monkeypatch.setattr(sys, "argv", ["bench.py"])
-  with pytest.raises(SystemExit) as exit_info:
-    bench.main()
-  assert exit_info.value.code == 1
-  assert capsys.readouterr().out.strip() == ""
-
-
-def test_bench_headline_names_its_device_and_refuses_unknown_kinds(
-    tmp_path, monkeypatch, capsys):
-  """The TPU headline carries the device it ran on and prices its MFU
-  against THAT device's published peak; a `device_kind` missing from
-  the table raises instead of borrowing the v5e's."""
-  bench = _load_bench()
-
-  def fake_probe(kind):
-    def probe(batch, remat=False, s2d=False, **kw):
-      return {"ok": True, "examples_per_sec": 2000.0 + batch,
-              "step_sec": batch / 2000.0, "first_half_sec": 0.1,
-              "barrier_dominated": False, "flops": 1e12,
-              "bytes_accessed": 1e10, "device_kind": kind,
-              "platform": "tpu", "batch_size": batch, "loop_steps": 1,
-              "xray": None, "memory": None}
-    return probe
-
-  monkeypatch.setenv("GRAFTSCOPE_RUNS", str(tmp_path / "runs.jsonl"))
-  monkeypatch.setattr(sys, "argv", ["bench.py"])
-  monkeypatch.setattr(bench, "_subprocess_probe", fake_probe("TPU v5 lite"))
-  bench.main()
-  headline = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
-  assert headline["metric"] == "qtopt_grasps_per_sec_per_chip"
-  assert (headline["platform"], headline["device_kind"]) \
-      == ("tpu", "TPU v5 lite")
-  assert headline["mfu"] == pytest.approx(
-      1e12 / (headline["batch_size"] / headline["value"]) / 197e12,
-      rel=1e-3)
-  assert "fallback" not in headline
-  monkeypatch.setattr(bench, "_subprocess_probe",
-                      fake_probe("TPU v9 imaginary"))
-  with pytest.raises(ValueError, match="unknown device_kind"):
-    bench.main()
-  assert capsys.readouterr().out.strip() == ""
-  assert "default" not in bench.PEAK_BF16_FLOPS
-
-
-def test_probe_main_flags_barrier_dominated_records(monkeypatch):
-  """probe_main must surface time_train_steps_halves' clamp flag in its
-  record (the ADVICE round-5 satellite: autotune consumers must know a
-  barrier-dominated number is an upper bound)."""
-  bench = _load_bench()
-
-  calls = {"n": 0}
-
-  def fake_halves(step, state, features, labels, iters, warmup=3,
-                  out_flags=None):
-    calls["n"] += 1
-    if out_flags is not None:
-      out_flags["barrier_dominated"] = True
-    return 0.01, 0.01, state
-
-  monkeypatch.setattr(bench.backend_lib, "time_train_steps_halves",
-                      fake_halves)
-  rec = bench.probe_main({"platform": "cpu", "batch_size": 4})
-  assert calls["n"] == 1
-  assert rec["ok"] and rec["barrier_dominated"] is True
 
 
 # ---------------------------------------------------------------------------
