@@ -30,6 +30,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 from jax.sharding import Mesh, PartitionSpec
 
 from tensor2robot_tpu.parallel import mesh as mesh_lib
@@ -154,11 +155,11 @@ def _normalize(l, o, d: int):
 # -- Pallas flash attention --------------------------------------------------
 #
 # Forward: FlashAttention online softmax; also emits the per-row
-# logsumexp needed by the backward. Backward: FlashAttention-2 style
-# recompute kernels (one producing dQ over the q-block grid, one
-# producing dK/dV over the k-block grid) — the [T, T] score matrix never
-# materializes in HBM in either direction. Sequences that don't tile are
-# PADDED to the block size and masked (never a silent O(T^2) fallback).
+# logsumexp needed by the backward. Backward: one recompute kernel over
+# the k-block grid that takes dV, dK and dQ from one recomputation of each
+# score tile — the [T, T] score matrix never materializes in HBM in
+# either direction. Sequences that don't tile are PADDED to the block
+# size and masked (never a silent O(T^2) fallback).
 #
 # What a tile costs on the v5e (PERF.md section 6, PR 27; bh 1024,
 # T 2048, d 64, 512 x 512 tiles, causal; each piece taken out of the
@@ -172,10 +173,46 @@ def _normalize(l, o, d: int):
 # into masked and unmasked tiles cost 10 %), and so does the operands'
 # width: Mosaic feeds the MXU bf16 from float32 operands at default
 # precision, bit for bit what a cast gives. So the kernels keep the
-# elementwise passes they had and lose what crosses lanes: dK/dV works
-# on the transposed tile, the forward's row sum rides the p.v product
-# where a head leaves it idle columns (`_sum_rides`), and `delta` no
-# longer travels as a lane-padded column.
+# elementwise passes they had and lose what crosses lanes: the backward
+# works on the transposed tile, the forward's row sum rides the p.v
+# product where a head leaves it idle columns (`_sum_rides`), and `delta`
+# no longer travels as a lane-padded column.
+#
+# One backward kernel (PERF.md section 6, PR 32). FlashAttention-2's two
+# kernels (dQ over the q-block grid, dK/dV over the k-block grid) each
+# recomputed q.k^T, the exp over it, the mask and dO.v^T: four
+# [block, block] products and three accumulating ones a tile pair. The one
+# kernel is the dK/dV kernel with dQ's product added: a tile pair costs
+# two [block, block] products (S^T, dP^T), three accumulating ones (dV,
+# dK, dQ) and one transpose of dS^T, which has to meet K_h with the
+# contraction over its first dimension. Readings, v5e, the kernel alone
+# (device time in a trace), 128 x 2048 x 8 x 64, 512 x 512 tiles, causal,
+# bf16, ms a call (the two kernels: dQ 12.38 + dK/dV 18.53 = 30.91):
+#   no dQ product at all (dK/dV with the strip beside it)       18.86
+#   (A) dS^T.T, then a plain product                             22.65  (kept)
+#   (B) `dot_general` contracting dimension 0 of both            22.65
+#   (C) K_h^T, turned once a program, as the left operand:
+#       dQ^T[lanes, block_q] += K_h^T.dS^T, turned at the end    22.88
+#   (A) with dS^T cast to bf16 before it is turned               22.51
+# Mosaic lowers (B) as (A)'s transpose; (C)'s product has 128 rows, half
+# of them another head's zeros. So dQ costs 3.8 ms a call where its own
+# kernel cost 12.4. Casting dS^T first is 0.13 ms faster and the same
+# bits on the chip (the MXU is fed bf16 either way), but it would round
+# an operand that no other product of the kernel rounds where operands
+# stay float32 (interpret mode, float32 inputs): not taken. dK and dV
+# equal the two kernels' bit for bit; dQ differs in 21,416 of 134,217,728
+# elements, by one bf16 rounding at most (0.00195 on values up to 5.5):
+# same products, summed over k blocks in the same order, but `delta`
+# comes from XLA's sum where dQ's kernel summed its own rows.
+#
+# VMEM. dQ's strip stays resident beside the whole-T q and dO: in bf16 a
+# program holds 16 bytes an element of [T, lanes] (q and dO
+# double-buffered 8, dQ's output block 4, its float32 accumulator 4): 4 MB
+# at T 2048 and 128 lanes, 16 MB at T 8192 (the dK/dV kernel held 8 of
+# them). `_bwd_vmem_bytes` states the kernel's limit from those counts. The least limit that compiles (described v5e, two heads of 64):
+# 9.8 MB at T 2048, 13.6 at T 4096, 21.9 at T 8192, the last past
+# Mosaic's default 16 MB; one head of 128 and four of 32 need 19.9 and
+# 22.8 MB at T 8192, one of 256 23.3 at T 4096. The chip has 128 MB.
 #
 # Layout (PERF.md section 6, PR 30). The kernels read q, k, v, dO and
 # write O, dQ, dK, dV as [B, T, H x D], the layout the q/k/v projections
@@ -357,78 +394,34 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *,
 
 def _delta(do, o):
   """delta_i = sum_d dO_id * O_id (FlashAttention-2's backward precompute),
-  float32 [..., T, 1]. The one definition both backward kernels use: dQ
-  calls it on its own block, `_flash_bwd` on the whole for dK/dV."""
+  float32 [..., T, 1]."""
   return jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1,
                  keepdims=True)
 
 
-def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref,
-                         dq_ref, *, head_dim: int, block_k: int,
-                         causal: bool, q_block: int, valid_len: int):
-  """dQ for one q block: dS = P * (dO.V^T - delta); dQ = scale * dS.K.
-
-  `delta` is taken here from the block's own rows of dO and O, once a
-  program (+0.29 ms a call): as an operand it would be a [T, 1] column,
-  which XLA keeps padded to 128 lanes (1 GB written a call at the
-  benchmark's shape, 1.42 ms). Where the block holds several heads, k and
-  v with the other heads' lanes zeroed give this head's scores and dP from
-  the whole q and dO, and dS.K lands in this head's lanes of the one
-  accumulator, zeros in the others.
-  """
-  scale = 1.0 / math.sqrt(head_dim)
-  tq_idx = pl.program_id(2)
-  seq_len, lanes = k_ref.shape
-  num_k_blocks = seq_len // block_k
-  if causal:
-    num_k_blocks = jnp.minimum(
-        num_k_blocks,
-        ((tq_idx + 1) * q_block + block_k - 1) // block_k)
-  q = q_ref[:]
-  do = do_ref[:].astype(jnp.float32)
-  o = o_ref[:]
-
-  def body(kb, dq, group, deltas):
-    rows = pl.ds(kb * block_k, block_k)
-    k_blk, v_blk = k_ref[rows, :], v_ref[rows, :]
-    mask = _valid_mask(tq_idx * q_block, kb * block_k, q_block, block_k,
-                       causal, valid_len, seq_len)
-    for (h, in_head), delta in zip(group, deltas):
-      k_h, v_h = _only(in_head, k_blk), _only(in_head, v_blk)
-      s = jnp.matmul(q, k_h.T, preferred_element_type=jnp.float32) * scale
-      p = jnp.exp(s - lse_ref[h])      # [block_q, 1]
-      if mask is not None:
-        p = jnp.where(mask, p, 0.0)
-      dp = jnp.matmul(do, v_h.T, preferred_element_type=jnp.float32)
-      ds = p * (dp - delta) * scale
-      dq = dq + jnp.matmul(ds, k_h, preferred_element_type=jnp.float32)
-    return dq
-
-  dq = jnp.zeros((q_block, lanes), jnp.float32)
-  for group in _head_groups(lanes, head_dim):
-    deltas = [_delta(_only(in_head, do), o) for _, in_head in group]
-    dq = jax.lax.fori_loop(
-        0, num_k_blocks,
-        functools.partial(body, group=group, deltas=deltas), dq)
-  dq_ref[:] = dq.astype(dq_ref.dtype)
-
-
-def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                          dk_ref, dv_ref, *, head_dim: int, block_q: int,
-                          causal: bool, k_block: int, valid_len: int):
-  """dK/dV for one k block: dV = P^T.dO; dK = scale * dS^T.Q.
+def _flash_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+                      dq_ref, dk_ref, dv_ref, dq_acc, *, head_dim: int,
+                      block_q: int, causal: bool, k_block: int,
+                      valid_len: int):
+  """The whole backward for one k block, from one recomputation of each
+  score tile: dV = P^T.dO, dK = scale * dS^T.Q, and this k block's term of
+  dQ = scale * dS.K.
 
   Works on the transposed tile: S^T = K.Q^T and dP^T = V.dO^T come out of
-  the MXU as [k_block, block_q], so P^T and dS^T feed the two
-  accumulating products as they are (transposing P and dS cost two
-  passes through the XLU a tile, a fifth of the kernel). `lse` and
-  `delta` arrive as lane-dense rows, [heads, T // block_q, 1, block_q]:
-  a sublane broadcast a tile, and 16 KB of VMEM a head at T 2048 where
-  the [T, 1] columns, padded to 128 lanes, held 1 MB each. Where the
-  block holds several heads, k and v with the other heads' lanes zeroed
-  (once a program) against the whole q and dO give this head's tiles; its
-  dV and dK then stand in its own lanes of its accumulators, and what the
-  products put in the other lanes is dropped at the end.
+  the MXU as [k_block, block_q], so P^T and dS^T feed the two products
+  that accumulate dV and dK as they are; dS^T is turned once for dQ.
+  `lse` and `delta` arrive as lane-dense rows, [heads, T // block_q, 1,
+  block_q]: a sublane broadcast a tile. Where the block holds several
+  heads, k and v with the other heads' lanes zeroed (once a program)
+  against the whole q and dO give this head's tiles; its dV, dK and dQ
+  then stand in its own lanes, and what the products put in the other
+  lanes of dV and dK is dropped at the end (dS.K_h puts exact zeros there).
+
+  dQ is summed over k blocks, which are grid steps: `dq_acc` is a whole-T
+  float32 strip that stays in VMEM across the k-block axis of one (batch,
+  lane block), zeroed at the first k block, cast into `dq_ref` (whose
+  block index ignores the k block) at the last. The k-block axis must run
+  in order on one core: it carries no `parallel` dimension semantics.
   """
   scale = 1.0 / math.sqrt(head_dim)
   tk_idx = pl.program_id(2)
@@ -438,13 +431,18 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
   if causal:
     # Blocks strictly above the diagonal see no unmasked entries.
     start_q = (tk_idx * k_block) // block_q
+
+  @pl.when(tk_idx == 0)
+  def _():
+    dq_acc[...] = jnp.zeros_like(dq_acc)
+
   def body(qb, carries, group, k_heads, v_heads):
     rows = pl.ds(qb * block_q, block_q)
     q_blk = q_ref[rows, :]
     do_blk = do_ref[rows, :].astype(jnp.float32)
     mask = _valid_mask(qb * block_q, tk_idx * k_block, block_q, k_block,
                        causal, valid_len, seq_len, q_axis=1)
-    new = []
+    new, dq = [], []
     for (h, _), k_h, v_h, (dk, dv) in zip(group, k_heads, v_heads, carries):
       st = jnp.matmul(k_h, q_blk.T,
                       preferred_element_type=jnp.float32) * scale
@@ -455,7 +453,9 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
       dpt = jnp.matmul(v_h, do_blk.T, preferred_element_type=jnp.float32)
       dst = pt * (dpt - delta_ref[h, qb]) * scale
       dk = dk + jnp.matmul(dst, q_blk, preferred_element_type=jnp.float32)
+      dq.append(jnp.matmul(dst.T, k_h, preferred_element_type=jnp.float32))
       new.append((dk, dv))
+    dq_acc[rows, :] += functools.reduce(jnp.add, dq)
     return tuple(new)
 
   zeros = jnp.zeros((k_block, lanes), jnp.float32)
@@ -472,6 +472,10 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
       dk, dv = _only(in_head, dk_h, dk), _only(in_head, dv_h, dv)
   dk_ref[:] = dk.astype(dk_ref.dtype)
   dv_ref[:] = dv.astype(dv_ref.dtype)
+
+  @pl.when(tk_idx == pl.num_programs(2) - 1)
+  def _():
+    dq_ref[:] = dq_acc[...].astype(dq_ref.dtype)
 
 
 def _block_specs(t: int, block: int, lanes: int, head_dim: int):
@@ -524,55 +528,68 @@ def _flash_fwd(num_heads, causal, block_q, block_k, valid_len, interpret,
   return out, (q, k, v, out, lse)
 
 
+# What Mosaic's default scoped-VMEM limit gives a kernel for everything.
+_SCOPED_VMEM_BYTES = 16 * 2 ** 20
+
+
+def _bwd_vmem_bytes(t: int, lanes: int, head_dim: int, block_q: int,
+                    block_k: int, itemsize: int) -> int:
+  """The backward kernel's VMEM limit, from its operands' shapes: the
+  blocks it keeps resident, and on top of them the default limit, which
+  then has only the tile loop's own float32 tiles to hold."""
+  strip = t * lanes
+  resident = (
+      2 * 2 * strip * itemsize               # q and dO, whole T, two buffers
+      + 2 * strip * itemsize + 4 * strip     # dQ: output block, accumulator
+      + 4 * 2 * block_k * lanes * itemsize   # k, v, dK, dV tiles
+      # lse and delta rows [heads, T / block_q, 1, block_q] float32: the
+      # unit dimension is padded to 8 sublanes, the row to 128 lanes.
+      + 2 * 2 * (lanes // head_dim) * (t // block_q) * 8
+      * max(block_q, 128) * 4)
+  return resident + _SCOPED_VMEM_BYTES
+
+
 def _flash_bwd(num_heads, causal, block_q, block_k, valid_len, interpret,
                residuals, g):
   q, k, v, out, lse = residuals
   b, t, hd = q.shape
   d = hd // num_heads
   lanes = lane_block(num_heads, d)
-  # One row a q block for the dK/dV kernel (the block is the whole of the
-  # last two dims, so every block_q lowers, sub-128 ones too).
+  # One row a q block (the block is the whole of the last two dims, so
+  # every block_q lowers, sub-128 ones too).
   rows = (b, num_heads, t // block_q, 1, block_q)
-  # For dK/dV, which needs every q block's `delta` as a row and cannot
-  # take it from its own block as dQ does. One sum over the whole H x D
-  # lanes a head, the other heads' lanes zeroed: XLA makes one pass of
-  # them all (0.78 ms at 128 x 2048 x 8 x 64, v5e), where a reduction over
-  # [B, T, H, D]'s last dimension needs the product laid out again with
-  # 64 of every 128 lanes idle (3.24 ms; PERF.md section 6, PR 30).
+  # The kernel needs every q block's `delta` as a row. One sum over the
+  # whole H x D lanes a head, the other heads' lanes zeroed: XLA makes one
+  # pass of them all (0.78 ms at 128 x 2048 x 8 x 64, v5e), where a
+  # reduction over [B, T, H, D]'s last dimension needs the product laid out
+  # again with 64 of every 128 lanes idle (3.24 ms; PERF.md section 6,
+  # PR 30).
   head_of_lane = jnp.arange(hd) // d
   delta = jnp.stack(
       [_delta(jnp.where(head_of_lane == h, g, 0), out)[..., 0]
        for h in range(num_heads)], axis=1).reshape(rows)
-  dq_kernel = functools.partial(
-      _flash_bwd_dq_kernel, head_dim=d, block_k=block_k, causal=causal,
-      q_block=block_q, valid_len=valid_len)
-  tile, whole, column = _block_specs(t, block_q, lanes, d)
-  dq = pl.pallas_call(
-      dq_kernel,
-      grid=(b, hd // lanes, t // block_q),
-      in_specs=[tile, whole, whole, tile, tile, column],
-      out_specs=tile,
-      out_shape=jax.ShapeDtypeStruct((b, t, hd), q.dtype),
-      interpret=interpret,
-      name="flash_bwd_dq",
-  )(q, k, v, g, out, lse)
-  dkv_kernel = functools.partial(
-      _flash_bwd_dkv_kernel, head_dim=d, block_q=block_q, causal=causal,
+  kernel = functools.partial(
+      _flash_bwd_kernel, head_dim=d, block_q=block_q, causal=causal,
       k_block=block_k, valid_len=valid_len)
-  tile = _block_specs(t, block_k, lanes, d)[0]
+  tile, whole, _ = _block_specs(t, block_k, lanes, d)
   rows_spec = pl.BlockSpec((None, lanes // d) + rows[2:],
                            lambda b, g, kb: (b, g, 0, 0, 0))
-  dk, dv = pl.pallas_call(
-      dkv_kernel,
+  dq, dk, dv = pl.pallas_call(
+      kernel,
       grid=(b, hd // lanes, t // block_k),
       in_specs=[whole, tile, tile, whole, rows_spec, rows_spec],
-      out_specs=[tile, tile],
+      out_specs=[whole, tile, tile],
       out_shape=[
+          jax.ShapeDtypeStruct((b, t, hd), q.dtype),
           jax.ShapeDtypeStruct((b, t, hd), k.dtype),
           jax.ShapeDtypeStruct((b, t, hd), v.dtype),
       ],
+      scratch_shapes=[pltpu.VMEM((t, lanes), jnp.float32)],
+      compiler_params=pltpu.CompilerParams(
+          vmem_limit_bytes=_bwd_vmem_bytes(t, lanes, d, block_q, block_k,
+                                           q.dtype.itemsize)),
       interpret=interpret,
-      name="flash_bwd_dkv",
+      name="flash_bwd",
   )(q, k, v, g, lse.reshape(rows), delta)
   return dq, dk, dv
 
@@ -595,9 +612,10 @@ _MIN_BLOCK = 8
 
 
 # Measured-winner block sizes (block_q, block_k) at every length the step
-# compiles at (v5e, 2026-10-01, PERF.md section 6, PR 27): the whole train
-# step of `configs/train_longcontext_flash.gin` (hidden 512, 8 heads x 64,
-# bf16), milliseconds a step by (block_q, block_k).
+# compiles at (v5e, 2026-10-01, PERF.md section 6, PR 27; the backward was
+# then two kernels, dQ and dK/dV, under the default VMEM limit): the whole
+# train step of `configs/train_longcontext_flash.gin` (hidden 512, 8 heads
+# x 64, bf16), milliseconds a step by (block_q, block_k).
 #
 # T 2048 x 128 sequences: 512x512 178.3, 1024x1024 183.1, 512x1024 186.4,
 # 1024x512 186.7, 256x512 193.1, 512x256 197.1. T 4096 x 64 sequences:
@@ -620,7 +638,7 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
                     block_k: Optional[int] = None,
                     interpret: Optional[bool] = None) -> jnp.ndarray:
   """Pallas flash attention over `num_heads` heads, [B, T, H x D] in and
-  out. Fully differentiable (custom FlashAttention-2 backward kernels).
+  out. Fully differentiable (one custom backward kernel, `flash_bwd`).
 
   The layout is the projections' own, as `nn.Dense` writes and reads it,
   and stays whole: the kernels index heads through their `BlockSpec`s

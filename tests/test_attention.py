@@ -362,6 +362,52 @@ class TestFlashLayout:
         atol=1e-6)
 
 
+class TestFlashOneBackward:
+  """PR 32: one backward kernel. dQ is summed over k blocks, which are
+  grid steps: it accumulates in a whole-T float32 strip that lives in
+  VMEM across the k-block axis of one (batch, lane block)."""
+
+  # Four k blocks or more (T 256: 4 or 8; T 300 pads to 320: 5 or 10),
+  # block_q != block_k in both orders, on the three sides of `lane_block`;
+  # two batch rows, so that a strip left over from one would show in the
+  # next.
+  @pytest.mark.parametrize("h,d", [(2, 64), (1, 128), (4, 8)])
+  @pytest.mark.parametrize("causal", [False, True])
+  @pytest.mark.parametrize("t", [256, 300])
+  @pytest.mark.parametrize("bq,bk", [(32, 64), (64, 32)])
+  def test_dq_summed_over_k_blocks_matches_reference(self, h, d, causal, t,
+                                                     bq, bk):
+    q, k, v, h = _qkv_flash(b=2, h=h, t=t, d=d, seed=17)
+    flash = _cos_loss(lambda q, k, v: attn.flash_attention(
+        q, k, v, h, causal=causal, block_q=bq, block_k=bk))
+    ref = _cos_loss(lambda q, k, v: _reference(q, k, v, h, causal=causal))
+    g_flash = jax.grad(flash, argnums=(0, 1, 2))(q, k, v)
+    g_ref = jax.grad(ref, argnums=(0, 1, 2))(q, k, v)
+    for a, b in zip(g_flash, g_ref):
+      np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                 atol=5e-5, rtol=5e-4)
+
+  def test_q_rows_a_k_block_skips_keep_their_dq(self):
+    """Causal, block_q 32 under block_k 128: the second k block starts its
+    loop at q block 4 (`start_q`) and must leave the strip's first 128 rows
+    as the first k block left them. Those rows see the first 128 keys
+    only, so their dQ is, to the bit, the dQ of the first 128 tokens run
+    alone (one k block), and the reference's within the usual tolerance."""
+    q, k, v, h = _qkv_flash(b=2, h=2, t=256, d=64, seed=19)
+    grad = lambda fn, *a: jax.grad(_cos_loss(fn))(*a)  # dQ
+    flash = lambda q, k, v: attn.flash_attention(
+        q, k, v, h, causal=True, block_q=32, block_k=128)
+    dq = grad(flash, q, k, v)
+    alone = grad(flash, q[:, :128], k[:, :128], v[:, :128])
+    np.testing.assert_array_equal(np.asarray(dq[:, :128]), np.asarray(alone))
+    assert np.abs(np.asarray(dq[:, :128])).max() > 0.1
+    np.testing.assert_allclose(
+        np.asarray(dq),
+        np.asarray(grad(lambda q, k, v: _reference(q, k, v, h, causal=True),
+                        q, k, v)),
+        atol=5e-5, rtol=5e-4)
+
+
 class TestRingAttention:
 
   @pytest.fixture(scope="class")

@@ -128,6 +128,14 @@ def _flash_shapes(shape, dtype=jnp.bfloat16):
   return jax.ShapeDtypeStruct((b, t, h * d), dtype), h
 
 
+def _flash_grads(h, **kwargs):
+  """(q, k, v) -> their gradients through the real (Mosaic) kernels."""
+  return jax.grad(
+      lambda q, k, v: attention.flash_attention(
+          q, k, v, h, interpret=False, **kwargs).astype(jnp.float32).sum(),
+      argnums=(0, 1, 2))
+
+
 @pytest.mark.usefixtures("tpu_lowering")
 class TestFlashMosaicLowering:
 
@@ -142,15 +150,8 @@ class TestFlashMosaicLowering:
   @pytest.mark.parametrize("shape,causal,bq,bk", CONFIGS)
   def test_backward_lowers(self, shape, causal, bq, bk):
     s, h = _flash_shapes(shape)
-
-    def grads(q, k, v):
-      return jax.grad(
-          lambda q_, k_, v_: attention.flash_attention(
-              q_, k_, v_, h, causal=causal, block_q=bq, block_k=bk,
-              interpret=False).astype(jnp.float32).sum(),
-          argnums=(0, 1, 2))(q, k, v)
-
-    _export_for_tpu(grads, s, s, s)
+    _export_for_tpu(_flash_grads(h, causal=causal, block_q=bq, block_k=bk),
+                    s, s, s)
 
   def test_lowered_module_contains_mosaic_kernels(self):
     s, h = _flash_shapes((2, 2, 256, 64))
@@ -183,6 +184,17 @@ class TestFlashMosaicLowering:
                 q_, k_, v_, h, causal=True).astype(jnp.float32).sum(),
             argnums=(0, 1, 2))(q, k, v), s, s, s)
     assert "tpu_custom_call" in grads.mlir_module()
+
+  def test_backward_is_one_mosaic_kernel(self):
+    """PR 32: dQ, dK and dV come out of one kernel, `flash_bwd`. A
+    gradient's module holds it once beside the forward, and none of the
+    two kernels it replaced."""
+    s, h = _flash_shapes((2, 8, 1024, 64))
+    text = _export_for_tpu(_flash_grads(h, causal=True), s, s,
+                           s).mlir_module()
+    assert text.count('kernel_name = "flash_fwd"') == 1
+    assert text.count('kernel_name = "flash_bwd"') == 1
+    assert text.count("kernel_name = ") == 2
 
   @pytest.mark.parametrize("t", [8192, 8000])
   def test_long_context_train_graph_compiles(self, t, v5e_devices):
@@ -407,17 +419,19 @@ class TestShippedStepsCompileForV5e:
   # allocation with size 16.05M and limit 16.00M"), and T 8192 at every
   # batch (20.75 MB): the dK/dV kernel held `lse` and `delta` as whole-T
   # [T, 1] columns, each padded to 128 lanes (1 MB each at T 2048, 4 MB
-  # at T 8192) and double-buffered. It now takes them as lane-dense rows
-  # (`ops/attention.py:_flash_bwd_dkv_kernel`), which leaves q and dO as
-  # its only whole-T operands (1 MB each at T 8192) and lets the shipped
-  # step compile at the default compiler options; `PERF.md` section 7 has
-  # the temporaries of each.
+  # at T 8192) and double-buffered. Since then they arrive as lane-dense
+  # rows. Since PR 32 the backward is one kernel (`_flash_bwd_kernel`) that
+  # also holds dQ's whole-T strip (float32 accumulator and output block,
+  # 4 + 4 MB at T 8192 beside 8 MB of q and dO), so it states its own
+  # VMEM limit from those byte counts (`_bwd_vmem_bytes`): the least
+  # limit that compiles is 9.8 MB at T 2048, 13.6 at T 4096 and 21.9 at
+  # T 8192 (described v5e, PR 32), past the default 16 MB at the last.
   @pytest.mark.parametrize("seq_len,batch", [
       (4096, None), (4096, 16), (4096, 64), (8192, None), (2048, 128),
       (8192, 32)])
   def test_longcontext_flash_train_step_compiles(self, seq_len, batch,
                                                  v5e_devices):
-    """Flash forward and both backward kernels INSIDE the train step at
+    """Flash forward and the backward kernel INSIDE the train step at
     the `train_longcontext_flash.gin` shape (B2, H8, T4096, d64), at the
     benchmark cell's 128 x T 2048, at the batches the next sequence cells
     need (64 x T 4096 is `pool_b64_T4096`, 32 x T 8192) and at the T=8192
@@ -434,8 +448,8 @@ class TestShippedStepsCompileForV5e:
     assert _activation_transposes(lowered.as_text()) == []
     compiled = lowered.compile()
     text = compiled.as_text()
-    # 2 blocks x (forward, dq, dkv).
-    assert text.count("tpu_custom_call") >= 6
+    # 2 blocks x (forward, backward).
+    assert text.count("tpu_custom_call") >= 4
     assert f"bf16[{batch},8,{seq_len},64]" not in text  # no [B, H, T, D]
     assert compiled.memory_analysis().temp_size_in_bytes < 16e9
 
