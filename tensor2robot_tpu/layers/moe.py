@@ -30,6 +30,13 @@ Design notes for TPU:
     `sparse`).
 * router in float32 for numerics, experts in the compute dtype;
 * auxiliary load-balancing loss (Switch-style) returned alongside.
+
+`ShardedExpertsMoE` is one chip's share of an expert-parallel layer at the
+sizes of today's sparse language models (hundreds of experts, ten a token):
+it is told which experts it holds, routes over all of them, and computes
+the part of the result its own experts give, through grouped products over
+the (token, expert) pairs sorted by expert. No per-expert capacity, no
+[N, E, C] tensor.
 """
 
 from __future__ import annotations
@@ -44,7 +51,7 @@ from jax.sharding import Mesh, PartitionSpec
 
 from tensor2robot_tpu.parallel import mesh as mesh_lib
 
-__all__ = ["MixtureOfExperts", "EXPERT_AXIS_PARAM_RULE",
+__all__ = ["MixtureOfExperts", "ShardedExpertsMoE", "EXPERT_AXIS_PARAM_RULE",
            "expert_axis_param_rule"]
 
 def expert_axis_param_rule(axis: str = "model"):
@@ -250,3 +257,127 @@ class MixtureOfExperts(nn.Module):
                   spec_exp, spec_exp, spec_exp, spec_exp),
         out_specs=(spec_tok, PartitionSpec()))
     return sharded(tokens, top_probs, top_idx, w1, b1, w2, b2)
+
+
+class ShardedExpertsMoE(nn.Module):
+  """The experts `experts_held = (first, count)` of a layer of `num_experts`
+  gated-SiLU experts, with the layer's shared expert.
+
+    p = softmax_float32(x W_r) over all `num_experts`; the `top_k` largest;
+    weights p_i / sum of the top_k (over all of them, held here or not);
+    expert e: (silu(x W_g^e) * (x W_u^e)) W_d^e, no biases;
+    shared: the same form, times sigmoid(x w_s);
+    result = shared + the weighted terms of the experts held here.
+
+  What the absent experts would add is left out: on a deployment the other
+  chips add it. Mechanism: the N x top_k (token, expert) pairs get the held
+  expert's local number as key, or `count` where the expert lives elsewhere;
+  one sort over all the keys brings the held pairs to the front, grouped by
+  expert; the first `rows` of them (a static buffer: `buffer_factor` x the
+  balanced load N x top_k x count / num_experts, rounded up to 128 rows)
+  are gathered from the tokens, pass two grouped products
+  (`jax.lax.ragged_dot`: gate and up in one, then down) and are
+  scatter-added back by token. Held pairs beyond the buffer are dropped and
+  counted. The rows of the buffer that hold no pair are zero and are given
+  to the last group, so the group sizes always add up to the buffer: the
+  products visit every row tile whatever the router picked, and a step's
+  device work does not depend on the weights or the batch.
+
+  Returns (result, counters): `moe_rows_held` (held pairs), `moe_buffer_fill`
+  (held pairs / buffer rows), `moe_rows_dropped`, `moe_load_max_over_mean`
+  (over the experts held).
+  """
+
+  num_experts: int = 8
+  experts_held: Tuple[int, int] = (0, 8)
+  top_k: int = 2
+  expert_width: int = 64
+  shared_width: int = 64   # 0: no shared expert
+  buffer_factor: float = 2.0
+  dtype: Optional[Any] = None
+
+  ROW_TILE = 128  # the buffer is a whole number of these rows
+
+  def buffer_rows(self, n_tokens: int) -> int:
+    pairs = n_tokens * self.top_k
+    balanced = pairs * self.experts_held[1] / self.num_experts
+    tiles = max(1, math.ceil(self.buffer_factor * balanced / self.ROW_TILE))
+    return min(tiles, -(-pairs // self.ROW_TILE)) * self.ROW_TILE
+
+  @nn.compact
+  def __call__(self, x: jnp.ndarray):
+    first, count = self.experts_held
+    if not (0 <= first and count > 0 and first + count <= self.num_experts):
+      raise ValueError(f"experts_held {self.experts_held} outside the "
+                       f"layer's {self.num_experts} experts")
+    features = x.shape[-1]
+    tokens = x.reshape(-1, features)
+    n = tokens.shape[0]
+    rows = self.buffer_rows(n)
+    init = nn.initializers.normal(0.02)
+    dense = lambda width, name: nn.Dense(  # noqa: E731
+        width, use_bias=False, dtype=self.dtype, kernel_init=init, name=name)
+    w_gate_up = self.param("experts_gate_up", init,
+                           (count, features, 2 * self.expert_width))
+    w_down = self.param("experts_down", init,
+                        (count, self.expert_width, features))
+    if self.dtype is not None:
+      w_gate_up, w_down = w_gate_up.astype(self.dtype), w_down.astype(
+          self.dtype)
+
+    with jax.named_scope("moe_route"):
+      logits = dense(self.num_experts, "router")(tokens)
+      probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
+      top_probs, top_idx = jax.lax.top_k(probs, self.top_k)
+      top_probs = top_probs / jnp.sum(top_probs, axis=-1, keepdims=True)
+      local = top_idx.reshape(-1) - first
+      held = (local >= 0) & (local < count)
+      keys = jnp.where(held, local, count).astype(jnp.int32)
+      keys, pair = jax.lax.sort(
+          (keys, jnp.arange(n * self.top_k, dtype=jnp.int32)), num_keys=1)
+      keys, pair = keys[:rows], pair[:rows]
+      filled = keys < count
+      token = jnp.where(filled, pair // self.top_k, 0)
+      weight = jnp.where(filled, top_probs.reshape(-1)[pair], 0.0)
+      sizes = jnp.sum(keys[:, None] == jnp.arange(count)[None, :], axis=0,
+                      dtype=jnp.int32)
+      load = jnp.sum(
+          (top_idx.reshape(-1)[:, None] - first) == jnp.arange(count)[None, :],
+          axis=0, dtype=jnp.int32)
+      rows_held = jnp.sum(load)
+      # Rows that hold no pair go to the last group: zero rows in, zero
+      # rows out, and the same tiles visited in every step.
+      sizes = sizes.at[count - 1].add(rows - jnp.sum(sizes))
+      buffer = jnp.where(filled[:, None], tokens[token], 0).astype(
+          w_gate_up.dtype)
+
+    with jax.named_scope("moe_experts"):
+      gate_up = jax.lax.ragged_dot(buffer, w_gate_up, sizes,
+                                   preferred_element_type=jnp.float32)
+      gate, up = jnp.split(gate_up, 2, axis=-1)
+      hidden = (jax.nn.silu(gate) * up).astype(w_down.dtype)
+      out = jax.lax.ragged_dot(hidden, w_down, sizes,
+                               preferred_element_type=jnp.float32)
+
+    with jax.named_scope("moe_route"):
+      routed = jnp.zeros((n, features), jnp.float32).at[token].add(
+          out * weight[:, None])
+
+    result = routed
+    if self.shared_width:
+      with jax.named_scope("moe_shared"):
+        hidden = jax.nn.silu(dense(self.shared_width, "shared_gate_proj")(
+            tokens)) * dense(self.shared_width, "shared_up_proj")(tokens)
+        shared = dense(features, "shared_down_proj")(hidden)
+        shared_gate = jax.nn.sigmoid(
+            dense(1, "shared_expert_gate")(tokens).astype(jnp.float32))
+        result = result + shared.astype(jnp.float32) * shared_gate
+    counters = {
+        "moe_rows_held": rows_held.astype(jnp.float32),
+        "moe_buffer_fill": rows_held.astype(jnp.float32) / rows,
+        "moe_rows_dropped": jnp.maximum(rows_held - rows, 0).astype(
+            jnp.float32),
+        "moe_load_max_over_mean": jnp.max(load).astype(jnp.float32)
+        / jnp.maximum(jnp.mean(load.astype(jnp.float32)), 1e-9),
+    }
+    return result.astype(x.dtype).reshape(x.shape), counters

@@ -138,8 +138,12 @@ class StepStatsRecorder:
                barrier: Optional[Callable[[Any], None]] = None,
                registry: Optional[metrics_lib.Registry] = None,
                tracer: Optional[trace_lib.Tracer] = None,
-               device_gauges: bool = True):
+               device_gauges: bool = True,
+               counter_prefixes: Tuple[str, ...] = ()):
     self._enabled = every_n_steps > 0
+    # Step metrics a model names as counters (`step_counter_prefixes`)
+    # ride the record: read after the barrier, so they cost no wait.
+    self._counter_prefixes = tuple(counter_prefixes)
     self._batch_size = int(batch_size)
     self._every_n = max(int(every_n_steps), 1)
     self._barrier = barrier or _default_barrier
@@ -211,8 +215,12 @@ class StepStatsRecorder:
     if len(history) > _DISPATCH_HISTORY:
       history.pop(0)
 
-  def end_step(self, step: int, state: Any, num_steps: int = 1) -> None:
-    """Closes the step; at the cadence, barriers and emits a record."""
+  def end_step(self, step: int, state: Any, num_steps: int = 1,
+               metrics: Optional[Dict[str, Any]] = None) -> None:
+    """Closes the step; at the cadence, barriers and emits a record.
+    `metrics` are the dispatch's step metrics (stacked for a K-step loop):
+    those under the recorder's counter prefixes join the record, as the
+    last step gave them."""
     if not self._enabled:
       return
     self._steps_in_window += num_steps
@@ -225,7 +233,17 @@ class StepStatsRecorder:
     self._barrier_ns += now_ns - barrier_start_ns
     with self._tracer.span("train/record", cat="train"):
       self._observe_barrier(fetched)
-      self._emit(step, now_ns)
+      self._emit(step, now_ns, self._read_counters(metrics))
+
+  def _read_counters(self, metrics: Optional[Dict[str, Any]]
+                     ) -> Dict[str, float]:
+    if not metrics or not self._counter_prefixes:
+      return {}
+    import numpy as np
+
+    return {key: float(np.asarray(value).reshape(-1)[-1])
+            for key, value in metrics.items()
+            if key.startswith(self._counter_prefixes)}
 
   def _observe_barrier(self, fetched: Any) -> None:
     """Piggybacks on the barrier's host fetch: non-finite divergence
@@ -240,7 +258,8 @@ class StepStatsRecorder:
       except Exception:  # noqa: BLE001 - non-float leaves etc.
         self._last_barrier_nonfinite = None
 
-  def _emit(self, step: int, now_ns: int) -> None:
+  def _emit(self, step: int, now_ns: int,
+            counters: Dict[str, float]) -> None:
     n = self._steps_in_window
     window_s = max((now_ns - self._window_start_ns) / 1e9, 1e-9)
     data_wait_ms = self._data_wait_ns / 1e6 / n
@@ -264,6 +283,7 @@ class StepStatsRecorder:
     }
     if self._last_barrier_nonfinite is not None:
       record["nonfinite_params"] = self._last_barrier_nonfinite
+    record.update(counters)
     with self._tracer.span("train/record/gauges", cat="train"):
       record.update(self._read_device_gauges())
     self._records.append((int(step), record))

@@ -404,7 +404,8 @@ def train_eval_model(
           else max(int(log_every_n_steps), 1))
     step_stats = stepstats_lib.StepStatsRecorder(
         batch_size=(input_generator_train.batch_size if needs_train else 0),
-        every_n_steps=step_stats_every_n_steps if needs_train else 0)
+        every_n_steps=step_stats_every_n_steps if needs_train else 0,
+        counter_prefixes=getattr(model, "step_counter_prefixes", ()))
     if needs_train:
       provide_input_generator_with_model_information(
           input_generator_train, model, modes_lib.TRAIN)
@@ -828,7 +829,8 @@ def train_eval_model(
       # AFTER next-batch staging — overlap preserved — and BEFORE the
       # per-step metrics fetch, so device_wait_ms absorbs the device wait
       # and the fetch below stays cheap.
-      step_stats.end_step(step, state, num_steps=step - prev_step)
+      step_stats.end_step(step, state, num_steps=step - prev_step,
+                          metrics=stacked if step - prev_step > 1 else metrics)
       if step - prev_step > 1:
         # One host fetch for all K steps' scalars (vs one per step).
         host = {k: np.asarray(v) for k, v in stacked.items()}

@@ -119,6 +119,7 @@ CONFIGS = [
     ((2, 8, 256, 32), True, 128, 128),    # four heads of 32 a program
     ((2, 4, 256, 16), False, 128, 128),   # whole H x D = 64, under 128
     ((1, 3, 256, 64), True, 128, 128),    # whole H x D = 192: 128 divides not
+    ((2, 16, 4096, 256), True, 512, 512),  # the hybrid decoder cell's layer
 ]
 
 
@@ -219,6 +220,19 @@ class TestFlashMosaicLowering:
 
     jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3))).lower(
         xs, ws, ws, ws).compile()
+
+  @pytest.mark.parametrize("batch", [1, 2])
+  def test_sixteen_heads_of_256_compile_at_t4096(self, batch, one_chip):
+    """The gated-attention layer of `configs/train_qwen3next_ep16share.gin`
+    (`qwen3next_train_T4096`: one head of 256 a program, the reduced
+    denominator, T 4096, the longest the forward takes at this head),
+    forward and the one backward kernel, through the chip's whole compiler:
+    the backward states 23.3 MB of VMEM here, past Mosaic's default 16."""
+    shape = jax.ShapeDtypeStruct((batch, 4096, 16 * 256), jnp.bfloat16,
+                                 sharding=one_chip)
+    text = jax.jit(_flash_grads(16, causal=True)).lower(
+        shape, shape, shape).compile().as_text()
+    assert "flash_fwd" in text and "flash_bwd" in text
 
   def test_f32_inputs_lower(self):
     s, h = _flash_shapes((1, 2, 256, 64), jnp.float32)
@@ -466,6 +480,22 @@ class TestShippedStepsCompileForV5e:
         model, _trainer_mesh(v5e_devices[:1]), 4, donate=True)
     found = _activation_transposes(lowered.as_text())
     assert sum("dims = [0, 2, 1, 3]" in line for line in found) == 16, found
+
+  def test_hybrid_decoder_train_step_fits_one_chip(self, v5e_devices):
+    """`configs/train_qwen3next_ep16share.gin` as shipped (1 x T 4096, 626 M
+    parameters under Adam): the step compiles for one v5e, state and
+    temporaries under the chip's 16 GB, with the flash kernels, XLA's
+    grouped products for the experts and one sort a layer in it."""
+    model, batch = _model_from_config(
+        "configs/train_qwen3next_ep16share.gin")
+    compiled = _lower_step_for_mesh(
+        model, _trainer_mesh(v5e_devices[:1]), batch, donate=True).compile()
+    memory = compiled.memory_analysis()
+    assert 7.4e9 < memory.argument_size_in_bytes < 7.6e9   # 626 M x 12 bytes
+    assert memory.argument_size_in_bytes + memory.temp_size_in_bytes < 15e9
+    text = compiled.as_text()
+    assert "flash_fwd" in text and "flash_bwd" in text
+    assert "ragged-dot" in text and " sort(" in text
 
   def test_tuned_grasping44_train_step_fits_one_chip(self, v5e_devices):
     """Grasping44 @472, batch 256, bf16 (train_qtopt_tpu_tuned.gin): the
