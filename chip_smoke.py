@@ -1,7 +1,7 @@
 """chip_smoke: the system's main path, once, on the chip.
 
     python chip_smoke.py              # one chip: train, resume, serve,
-                                      # kernels, barrier
+                                      # kernels, delta_rule, barrier
     python chip_smoke.py --multichip  # four chips: dp x fsdp training and
                                       # ring / ulysses sequence parallelism,
                                       # each against its one-chip reference
@@ -56,8 +56,11 @@ TUNED_GIN = os.path.join(PACKAGE, "research", "qtopt", "configs",
 FLASH_GIN = os.path.join(PACKAGE, "configs", "train_longcontext_flash.gin")
 SERVE_GIN = os.path.join(PACKAGE, "configs", "serve_qtopt.gin")
 SESSION_GIN = os.path.join(PACKAGE, "configs", "serve_session.gin")
+HYBRID_GIN = os.path.join(PACKAGE, "configs",
+                          "train_qwen3next_ep16share.gin")
 
-ONE_CHIP_PHASES = ("train", "resume", "serve", "kernels", "barrier")
+ONE_CHIP_PHASES = ("train", "resume", "serve", "kernels", "delta_rule",
+                   "barrier")
 MULTICHIP_PHASES = ("multichip_dp", "multichip_sp")
 # A phase that needs what an earlier one left on disk is skipped (and
 # the run failed) when that one failed.
@@ -85,6 +88,12 @@ DECODE_VS_STATELESS_ATOL = 5e-2
 # score against the served value for its action, RELATIVE (chip and CPU
 # runs of PR 22: the served rows are bit-identical to the predictor's).
 SERVE_RTOL = 2e-2
+# `gdn_inverse` against XLA's ten float32 products: the values, and the
+# closed-form backward against autodiff through the ten RELATIVE to its
+# largest entry. Mosaic's float32 contraction is what the interpreted
+# tests cannot show (chip runs of PR 34: the values equal to the bit, the
+# backward 1.3e-7 of its largest entry).
+INVERSE_TOL = 1e-5
 
 
 class PhaseFailed(RuntimeError):
@@ -632,12 +641,81 @@ def phase_kernels(out_dir: str, extra_bindings=(), device=("tpu", 1)
           "peak_device_bytes": _peak_device_bytes()}
 
 
+def phase_delta_rule(out_dir: str, extra_bindings=(), device=("tpu", 1)
+                     ) -> dict:
+  """Phase 4: the delta rule's triangular inverse, `gdn_inverse`, at the
+  chunked layout `train_qwen3next_ep16share.gin` gives it (64 chunks x 1
+  x 32 heads of [64, 64] as shipped), the way the model takes it
+  (Mosaic on the TPU), against the XLA doubling product it replaced:
+  the values, and the closed-form backward against autodiff."""
+  del out_dir  # leaves nothing on disk
+  device = _device_record(device)
+  import jax
+  import jax.numpy as jnp
+  import numpy as np
+
+  from tensor2robot_tpu.ops import linear_attention
+  from tensor2robot_tpu.utils import config
+
+  config.clear_config()
+  try:
+    config.parse_config_files_and_bindings([HYBRID_GIN],
+                                           list(extra_bindings))
+    sizes = [config.query_parameter(name) for name in (
+        "HybridDecoderLM.sequence_length",
+        "DefaultRandomInputGenerator.batch_size",
+        "HybridDecoderLM.linear_num_value_heads")]
+    interpret = config.query_parameter(
+        "HybridDecoderLM.device_type") != "tpu"
+  finally:
+    config.clear_config()
+  chunk = 64
+  shape = (sizes[0] // chunk, sizes[1], sizes[2], chunk, chunk)
+  rng = np.random.default_rng(34)
+  a = jnp.asarray(np.tril(rng.normal(size=shape) * 0.25, -1), jnp.float32)
+  probe = jnp.asarray(rng.normal(size=shape), jnp.float32)
+  strictly_lower = jnp.tril(jnp.ones((chunk, chunk), bool), -1)
+
+  arms, text = {}, {}
+  for name, inverse in (
+      ("kernel", lambda x: linear_attention._inverse_of_unit_lower(
+          x, interpret)),
+      ("xla", linear_attention._doubling_inverse)):
+    forward = jax.jit(inverse)
+    backward = jax.jit(jax.grad(
+        lambda x, inverse=inverse: jnp.sum(
+            inverse(jnp.where(strictly_lower, x, 0.0)) * probe)))
+    text[name] = forward.lower(a).compile().as_text()
+    arms[name] = (np.asarray(forward(a)), np.asarray(backward(a)))
+  errors = {
+      "values": float(np.max(np.abs(arms["kernel"][0] - arms["xla"][0]))),
+      "backward": float(np.max(np.abs(arms["kernel"][1] - arms["xla"][1]))),
+  }
+  largest = {"values": float(np.max(np.abs(arms["xla"][0]))),
+             "backward": float(np.max(np.abs(arms["xla"][1])))}
+  _check(all(np.all(np.isfinite(x)) for x in arms["kernel"])
+         and errors["values"] <= INVERSE_TOL * max(1.0, largest["values"])
+         and errors["backward"] <= INVERSE_TOL * largest["backward"],
+         f"gdn_inverse and the XLA product disagree beyond {INVERSE_TOL}: "
+         f"{errors} where the largest entries are {largest}")
+  if device["platform"] == "tpu":
+    _check("gdn_inverse" in text["kernel"]
+           and "tpu_custom_call" in text["kernel"]
+           and "tpu_custom_call" not in text["xla"],
+           "the compiled inverse holds no gdn_inverse custom call")
+  return {"phase": "delta_rule", "ok": True, "device": device,
+          "config": os.path.relpath(HYBRID_GIN, ROOT), "shape": list(shape),
+          "interpreted": interpret, "max_abs_error": errors,
+          "max_abs_entry": largest, "tolerance": INVERSE_TOL,
+          "peak_device_bytes": _peak_device_bytes()}
+
+
 BARRIER_WINDOWS = 5
 BARRIER_STEPS = 4
 
 
 def phase_barrier(out_dir: str, extra_bindings=(), device=("tpu", 1)) -> dict:
-  """Phase 4: is `jax.block_until_ready` a barrier here? The phase-1
+  """Phase 5: is `jax.block_until_ready` a barrier here? The phase-1
   train step on a resident batch, a window of steps closed by
   `block_until_ready`, against the same window closed by a host fetch
   (`utils.backend.state_barrier`), against dispatch alone."""
@@ -832,7 +910,8 @@ def phase_multichip_sp(out_dir: str, extra_bindings=(),
 
 PHASES = {"train": phase_train, "resume": phase_resume,
           "serve": phase_serve, "kernels": phase_kernels,
-          "barrier": phase_barrier, "multichip_dp": phase_multichip_dp,
+          "delta_rule": phase_delta_rule, "barrier": phase_barrier,
+          "multichip_dp": phase_multichip_dp,
           "multichip_sp": phase_multichip_sp}
 
 

@@ -205,7 +205,8 @@ class GatedDeltaNet(nn.Module):
     k = jnp.repeat(k, v_heads // k_heads, axis=2)
     with jax.named_scope("gdn_scan"):
       o, _ = linear_attention.gated_delta_rule_chunked(
-          q, k, v, g, beta, matmul_dtype=self.dtype)
+          q, k, v, g, beta, matmul_dtype=self.dtype,
+          interpret=cfg.flash_interpret)
     o = _rms(o, cfg.rms_norm_eps) * norm_weight.astype(jnp.float32)
     o = o * jax.nn.silu(z.astype(jnp.float32))
     o = o.astype(qkvz.dtype).reshape(b, t, value_dim)

@@ -36,6 +36,12 @@ TINY_SEQUENCE = (
     "SequenceRegressionModel.device_type = 'cpu'",
     "DefaultRandomInputGenerator.batch_size = 8",
 )
+TINY_HYBRID = (
+    "HybridDecoderLM.sequence_length = 128",
+    "HybridDecoderLM.linear_num_value_heads = 4",
+    "HybridDecoderLM.device_type = 'cpu'",
+    "DefaultRandomInputGenerator.batch_size = 2",
+)
 CPU8 = ("cpu", 8)
 
 
@@ -113,6 +119,12 @@ class TestPhaseRehearsal:
     assert decode["arms"]["auto"]["decode_kernel_active"] is False
     assert decode["horizon"] == 32 and decode["lanes"] == 8
     assert max(decode["max_abs_error"].values()) <= 1e-4
+
+  def test_delta_rule_phase(self, out_dir):
+    result = chip_smoke.phase_delta_rule(out_dir, TINY_HYBRID, device=CPU8)
+    assert result["shape"] == [2, 2, 4, 64, 64] and result["interpreted"]
+    for part, gap in result["max_abs_error"].items():
+      assert gap <= result["tolerance"] * result["max_abs_entry"][part]
 
   def test_barrier_phase(self, trained, out_dir):
     result = chip_smoke.phase_barrier(out_dir, TINY_CRITIC, device=CPU8)

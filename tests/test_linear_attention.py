@@ -30,10 +30,11 @@ def test_chunked_rule_matches_the_recurrence(chunk):
   np.testing.assert_allclose(s, s_ref, atol=2e-5, rtol=2e-5)
 
 
-@pytest.mark.parametrize("chunk", [16, 20])
+@pytest.mark.parametrize("chunk", [16, 20, 64])
 def test_chunked_rule_gradients_match_the_recurrence(chunk):
-  args = _inputs(seed=3)
-  probe = jax.random.normal(jax.random.PRNGKey(9), (2, 48, 3, 8))
+  # Two chunks at least: 64, the one size the benchmark's cell runs, at T 128.
+  args = _inputs(seed=3, t=max(48, 2 * chunk))
+  probe = jax.random.normal(jax.random.PRNGKey(9), args[2].shape)
 
   def loss(fn, *xs):
     o, s = fn(*xs)
@@ -68,3 +69,75 @@ def test_inverse_of_unit_lower():
   got = la._inverse_of_unit_lower(jnp.asarray(a, jnp.float32))
   np.testing.assert_allclose(got, np.linalg.inv(np.eye(24) + a), atol=1e-3,
                              rtol=1e-3)
+
+
+def _unit_lower(c, lead=(2, 1, 4), seed=0):
+  a = np.random.default_rng(seed).normal(size=lead + (c, c)) * 0.25
+  return jnp.asarray(np.tril(a, -1), jnp.float32)
+
+
+@pytest.mark.parametrize("c", [8, 16, 64])
+def test_inverse_kernel_is_the_doubling_product(c):
+  """`gdn_inverse`, interpreted, on the chunked layout [N, B, H, C, C]:
+  the XLA product it replaces, and the inverse itself."""
+  a = _unit_lower(c)
+  assert la._kernel_takes(a.shape)
+  got = la._inverse_of_unit_lower(a, interpret=True)
+  np.testing.assert_allclose(got, la._doubling_inverse(a), atol=1e-5,
+                             rtol=1e-5)
+  exact = np.linalg.inv(np.eye(c) + np.asarray(a, np.float64))
+  np.testing.assert_allclose(got, exact, atol=1e-5, rtol=1e-5)
+
+
+@pytest.mark.parametrize("shape", [(2, 1, 4, 7, 7), (2, 1, 4, 20, 20),
+                                   (3, 16, 16)])
+def test_shapes_the_kernel_leaves_to_xla(shape):
+  a = _unit_lower(shape[-1], lead=shape[:-2])
+  assert not la._kernel_takes(a.shape)
+  exact = np.linalg.inv(np.eye(shape[-1]) + np.asarray(a, np.float64))
+  np.testing.assert_allclose(la._inverse_of_unit_lower(a), exact, atol=1e-5,
+                             rtol=1e-5)
+
+
+@pytest.mark.parametrize("c", [16, 64])
+def test_closed_form_backward_is_the_doubling_products_gradient(c):
+  a = _unit_lower(c, seed=1)
+  probe = jnp.asarray(np.random.default_rng(2).normal(size=a.shape),
+                      jnp.float32)
+  # On the operand's strict lower triangle, as the rule calls it.
+  strictly_lower = np.tril(np.ones((c, c), bool), -1)
+  loss = lambda fn: lambda x: jnp.sum(  # noqa: E731
+      fn(jnp.where(strictly_lower, x, 0.0)) * probe)
+  want = jax.grad(loss(la._doubling_inverse))(a)
+  got = jax.grad(loss(lambda x: la._inverse_of_unit_lower(x, True)))(a)
+  np.testing.assert_allclose(got, want,
+                             atol=1e-5 * float(jnp.max(jnp.abs(want))))
+
+
+def _count(jaxpr, primitive):
+  """Equations of `primitive` in a jaxpr and everything it calls."""
+  total = 0
+  for eqn in jaxpr.eqns:
+    total += eqn.primitive.name == primitive
+    for sub in jax.core.jaxprs_in_params(eqn.params):
+      total += _count(sub, primitive)
+  return total
+
+
+@pytest.mark.parametrize("fn,products,residuals", [
+    (la._doubling_inverse, 20, 11),  # autodiff through the ten products
+    (lambda a: la._inverse_of_unit_lower(a, True), 2, 1),
+    (lambda a: la._inverse_of_unit_lower(a[0, 0]), 2, 1),  # XLA's forward
+], ids=["autodiff", "kernel", "xla_forward"])
+def test_backward_of_the_inverse_holds_two_products_and_one_residual(
+    fn, products, residuals):
+  a = _unit_lower(64)
+  out, backward = jax.vjp(fn, a)
+  # Distinct by value: autodiff holds some of its eleven twice, one of
+  # the two with the batch of 1 squeezed out.
+  held = {np.asarray(x).tobytes()
+          for x in jax.tree_util.tree_leaves(backward)
+          if getattr(x, "shape", ())[-2:] == (64, 64)}
+  assert len(held) == residuals
+  assert _count(jax.make_jaxpr(backward)(out).jaxpr,
+                "dot_general") == products

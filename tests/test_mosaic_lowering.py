@@ -27,6 +27,7 @@ from __future__ import annotations
 
 import json
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -36,6 +37,7 @@ from jax.sharding import (Mesh, NamedSharding, PartitionSpec,
                           SingleDeviceSharding)
 
 from tensor2robot_tpu.ops import attention
+from tensor2robot_tpu.ops import linear_attention
 
 _REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 _PACKAGE = os.path.join(_REPO_ROOT, "tensor2robot_tpu")
@@ -240,6 +242,53 @@ class TestFlashMosaicLowering:
         lambda q, k, v: attention.flash_attention(q, k, v, h,
                                                   interpret=False),
         s, s, s)
+
+
+# The chunked layout of the hybrid decoder cell's delta rule: 64 chunks x 1
+# sequence x 32 value heads of [64, 64].
+_CELL_INVERSE = (64, 1, 32, 64, 64)
+
+
+def _keeps_the_chunked_layout(custom_call: str, shape) -> bool:
+  """Operand and result of the instruction name `[N,B,H,C`: the string
+  `gdn_scan_ms` finds the delta rule's ops by."""
+  layout = "[" + ",".join(str(d) for d in shape[:4])
+  result, _, operands = custom_call.partition("custom-call(")
+  return layout in result and layout in operands
+
+
+class TestDeltaRuleInverseMosaicLowering:
+  """`gdn_inverse` (ops/linear_attention.py): the Mosaic lowering, and the
+  chip's compiler on it at the cell's shape."""
+
+  def test_default_interpret_lowers_mosaic_for_tpu(self, tpu_lowering):
+    """interpret=None takes the kernel per lowering platform, forward and
+    through the closed-form backward's residual."""
+    a = jax.ShapeDtypeStruct((4, 2, 4, 64, 64), jnp.float32)
+    inverse = linear_attention._inverse_of_unit_lower
+    assert "tpu_custom_call" in _export_for_tpu(inverse, a).mlir_module()
+    assert "tpu_custom_call" in _export_for_tpu(
+        jax.grad(lambda x: inverse(x).sum()), a).mlir_module()
+
+  @pytest.mark.parametrize("shape", [
+      _CELL_INVERSE, (64, 2, 32, 64, 64), (8, 1, 3, 64, 64),
+      (8, 1, 32, 16, 16), (8, 2, 4, 8, 8), (8, 1, 6, 24, 24),
+      (8, 1, 16, 128, 128)])
+  def test_compiles_for_v5e(self, shape, one_chip):
+    """Every chunk size `_kernel_takes` says Mosaic tiles: heads side by
+    side in the lanes where 128 / C of them divide the group, one a tile
+    where not (C 24, 3 heads, C 128)."""
+    assert linear_attention._kernel_takes(shape)
+    a = jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
+    inverse = lambda x: linear_attention._inverse_of_unit_lower(  # noqa: E731
+        x, False)
+    calls = [line for line in jax.jit(inverse).lower(a).compile()
+             .as_text().splitlines() if "custom-call(" in line]
+    assert len(calls) == 1 and "gdn_inverse" in calls[0]
+    assert _keeps_the_chunked_layout(calls[0], shape), calls[0]
+    text = jax.jit(jax.grad(lambda x: inverse(x).sum())).lower(
+        a).compile().as_text()
+    assert text.count("custom-call(") == 1   # the backward is XLA's own
 
 
 def _decode_tick_shapes(s_sz, t, b, h, d, sharding=None):
@@ -485,7 +534,9 @@ class TestShippedStepsCompileForV5e:
     """`configs/train_qwen3next_ep16share.gin` as shipped (1 x T 4096, 626 M
     parameters under Adam): the step compiles for one v5e, state and
     temporaries under the chip's 16 GB, with the flash kernels, XLA's
-    grouped products for the experts and one sort a layer in it."""
+    grouped products for the experts and one sort a layer in it, and the
+    delta rule's inverse as `gdn_inverse` in the chunked layout, forward
+    and recomputed forward, with two products for its backward."""
     model, batch = _model_from_config(
         "configs/train_qwen3next_ep16share.gin")
     compiled = _lower_step_for_mesh(
@@ -496,6 +547,18 @@ class TestShippedStepsCompileForV5e:
     text = compiled.as_text()
     assert "flash_fwd" in text and "flash_bwd" in text
     assert "ragged-dot" in text and " sort(" in text
+    lines = text.splitlines()
+    inverses = [line for line in lines
+                if "custom-call(" in line and "gdn_inverse" in line]
+    assert len(inverses) == 6          # 3 delta-rule layers x 2 forwards
+    assert all(_keeps_the_chunked_layout(line, _CELL_INVERSE)
+               for line in inverses)
+    # `highest` products with a [.., 64, 64] float32 result: 40 a layer
+    # before PR 34 (10 + 10 recomputed + autodiff's 20), 120 in the step.
+    products = [line for line in lines if "highest" in line
+                and re.search(r"= f32\[[\d,]*64,64\]", line)]
+    assert len(products) <= 6, len(products)
+    assert memory.temp_size_in_bytes <= 5.30e9   # 5.30 GB before PR 34
 
   def test_tuned_grasping44_train_step_fits_one_chip(self, v5e_devices):
     """Grasping44 @472, batch 256, bf16 (train_qtopt_tpu_tuned.gin): the
