@@ -1,7 +1,8 @@
 """chip_smoke: the system's main path, once, on the chip.
 
     python chip_smoke.py              # one chip: train, resume, serve,
-                                      # kernels, delta_rule, barrier
+                                      # kernels, delta_rule, state_space,
+                                      # barrier
     python chip_smoke.py --multichip  # four chips: dp x fsdp training and
                                       # ring / ulysses sequence parallelism,
                                       # each against its one-chip reference
@@ -58,9 +59,11 @@ SERVE_GIN = os.path.join(PACKAGE, "configs", "serve_qtopt.gin")
 SESSION_GIN = os.path.join(PACKAGE, "configs", "serve_session.gin")
 HYBRID_GIN = os.path.join(PACKAGE, "configs",
                           "train_qwen3next_ep16share.gin")
+MAMBA_GIN = os.path.join(PACKAGE, "configs",
+                         "train_nemotron3nano_ep16share.gin")
 
 ONE_CHIP_PHASES = ("train", "resume", "serve", "kernels", "delta_rule",
-                   "barrier")
+                   "state_space", "barrier")
 MULTICHIP_PHASES = ("multichip_dp", "multichip_sp")
 # A phase that needs what an earlier one left on disk is skipped (and
 # the run failed) when that one failed.
@@ -94,6 +97,13 @@ SERVE_RTOL = 2e-2
 # tests cannot show (chip runs of PR 34: the values equal to the bit, the
 # backward 1.3e-7 of its largest entry).
 INVERSE_TOL = 1e-5
+# The chunked state-space scan against the token-by-token recurrence,
+# RELATIVE to the largest entry of the values and of each gradient:
+# float32 products at `highest` differ by the order of their sums alone
+# (CPU: 3e-6); with bfloat16 operands, as the training path holds them,
+# each product rounds its operands to 8 bits of mantissa.
+SCAN_F32_TOL = 1e-4
+SCAN_BF16_TOL = 3e-2
 
 
 class PhaseFailed(RuntimeError):
@@ -710,6 +720,79 @@ def phase_delta_rule(out_dir: str, extra_bindings=(), device=("tpu", 1)
           "peak_device_bytes": _peak_device_bytes()}
 
 
+def phase_state_space(out_dir: str, extra_bindings=(), device=("tpu", 1)
+                      ) -> dict:
+  """Phase 5: the Mamba-2 scan in chunks (`ops/state_space.ssd_chunked`,
+  the training path) against the recurrence it stands for
+  (`ssd_recurrent`), at the heads, groups, state and chunk
+  `train_nemotron3nano_ep16share.gin` gives it and an eighth of its length
+  (the recurrence keeps a [64, 64, 128] state a token for its backward):
+  values and gradients, with float32 products at `highest` and with the
+  bfloat16 operands the model hands it."""
+  del out_dir  # leaves nothing on disk
+  device = _device_record(device)
+  import jax
+  import jax.numpy as jnp
+  import numpy as np
+
+  from tensor2robot_tpu.ops import state_space
+  from tensor2robot_tpu.utils import config
+
+  config.clear_config()
+  try:
+    config.parse_config_files_and_bindings([MAMBA_GIN], list(extra_bindings))
+    t, b, h, p, g, n, chunk = [config.query_parameter(name) for name in (
+        "HybridDecoderLM.sequence_length",
+        "DefaultRandomInputGenerator.batch_size",
+        "HybridDecoderLM.mamba_num_heads", "HybridDecoderLM.mamba_head_dim",
+        "HybridDecoderLM.n_groups", "HybridDecoderLM.ssm_state_size",
+        "HybridDecoderLM.chunk_size")]
+  finally:
+    config.clear_config()
+  t = max(t // 8, 1)
+  rng = np.random.default_rng(35)
+  normal = lambda *shape: jnp.asarray(  # noqa: E731
+      rng.normal(size=shape), jnp.float32)
+  args = (normal(b, t, h, p),
+          jax.nn.softplus(normal(b, t, h) - 3.0),       # dt of 0.01-0.3
+          jnp.log(jnp.arange(1, h + 1, dtype=jnp.float32)),
+          normal(b, t, g, n), normal(b, t, g, n), jnp.ones((h,)))
+  probe = normal(b, t, h, p)
+
+  def arm(scan):
+    def loss(*xs):
+      y, state = scan(*xs)
+      return jnp.sum(y * probe) + jnp.sum(state * state)
+    return jax.jit(lambda *xs: (scan(*xs)[0],)
+                   + jax.grad(loss, argnums=(0, 1, 3, 4))(*xs))
+
+  with jax.default_matmul_precision("highest"):
+    want = [np.asarray(x) for x in arm(state_space.ssd_recurrent)(*args)]
+    got = {"float32": arm(lambda *xs: state_space.ssd_chunked(
+        *xs, chunk_size=chunk))(*args)}
+  got["bfloat16"] = arm(lambda *xs: state_space.ssd_chunked(
+      *xs, chunk_size=chunk, matmul_dtype=jnp.bfloat16))(*args)
+  names = ("values", "dx", "ddt", "db", "dc")
+  largest = {k: float(np.max(np.abs(w))) for k, w in zip(names, want)}
+  errors = {
+      kind: {k: float(np.max(np.abs(np.asarray(x) - w))) / largest[k]
+             for k, x, w in zip(names, outs, want)}
+      for kind, outs in got.items()}
+  for kind, tolerance in (("float32", SCAN_F32_TOL),
+                          ("bfloat16", SCAN_BF16_TOL)):
+    _check(all(np.isfinite(e) and e <= tolerance
+               for e in errors[kind].values()),
+           f"the chunked scan ({kind}) and the recurrence disagree beyond "
+           f"{tolerance} of the largest entry: {errors[kind]}")
+  return {"phase": "state_space", "ok": True, "device": device,
+          "config": os.path.relpath(MAMBA_GIN, ROOT),
+          "shape": {"batch": b, "length": t, "heads": h, "head_dim": p,
+                    "groups": g, "state": n, "chunk": chunk},
+          "relative_error": errors, "max_abs_entry": largest,
+          "tolerance": {"float32": SCAN_F32_TOL, "bfloat16": SCAN_BF16_TOL},
+          "peak_device_bytes": _peak_device_bytes()}
+
+
 BARRIER_WINDOWS = 5
 BARRIER_STEPS = 4
 
@@ -910,7 +993,8 @@ def phase_multichip_sp(out_dir: str, extra_bindings=(),
 
 PHASES = {"train": phase_train, "resume": phase_resume,
           "serve": phase_serve, "kernels": phase_kernels,
-          "delta_rule": phase_delta_rule, "barrier": phase_barrier,
+          "delta_rule": phase_delta_rule, "state_space": phase_state_space,
+          "barrier": phase_barrier,
           "multichip_dp": phase_multichip_dp,
           "multichip_sp": phase_multichip_sp}
 

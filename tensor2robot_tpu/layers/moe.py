@@ -261,13 +261,23 @@ class MixtureOfExperts(nn.Module):
 
 class ShardedExpertsMoE(nn.Module):
   """The experts `experts_held = (first, count)` of a layer of `num_experts`
-  gated-SiLU experts, with the layer's shared expert.
+  experts, with the layer's shared expert. As the fields stand:
 
     p = softmax_float32(x W_r) over all `num_experts`; the `top_k` largest;
     weights p_i / sum of the top_k (over all of them, held here or not);
     expert e: (silu(x W_g^e) * (x W_u^e)) W_d^e, no biases;
     shared: the same form, times sigmoid(x w_s);
     result = shared + the weighted terms of the experts held here.
+
+  Three fields change the form and nothing of the mechanism below.
+  `router_scoring='sigmoid'`: s = sigmoid_float32(x W_r); the picks are the
+  `top_k` largest of s + b, b the buffer `e_score_correction_bias` (zeros;
+  collection `buffers`, so no gradient reaches it); the weights are
+  `routed_scaling_factor` x s_i / (sum of the picks' s + 1e-20), from s and
+  not from s + b. `expert_form='relu2'`: relu(x W_u^e)^2 W_d^e, one up
+  product (`experts_up` [count, hidden, width]) where the gated form has
+  gate and up in one (`experts_gate_up`); the shared expert takes the same
+  form. `shared_gate=False`: the shared expert is added as it is.
 
   What the absent experts would add is left out: on a deployment the other
   chips add it. Mechanism: the N x top_k (token, expert) pairs get the held
@@ -276,7 +286,7 @@ class ShardedExpertsMoE(nn.Module):
   expert; the first `rows` of them (a static buffer: `buffer_factor` x the
   balanced load N x top_k x count / num_experts, rounded up to 128 rows)
   are gathered from the tokens, pass two grouped products
-  (`jax.lax.ragged_dot`: gate and up in one, then down) and are
+  (`jax.lax.ragged_dot`: up, or gate and up in one, then down) and are
   scatter-added back by token. Held pairs beyond the buffer are dropped and
   counted. The rows of the buffer that hold no pair are zero and are given
   to the last group, so the group sizes always add up to the buffer: the
@@ -294,6 +304,10 @@ class ShardedExpertsMoE(nn.Module):
   expert_width: int = 64
   shared_width: int = 64   # 0: no shared expert
   buffer_factor: float = 2.0
+  router_scoring: str = "softmax"   # 'softmax' | 'sigmoid'
+  routed_scaling_factor: float = 1.0   # sigmoid scoring only
+  expert_form: str = "gated_silu"   # 'gated_silu' | 'relu2'
+  shared_gate: bool = True
   dtype: Optional[Any] = None
 
   ROW_TILE = 128  # the buffer is a whole number of these rows
@@ -310,6 +324,11 @@ class ShardedExpertsMoE(nn.Module):
     if not (0 <= first and count > 0 and first + count <= self.num_experts):
       raise ValueError(f"experts_held {self.experts_held} outside the "
                        f"layer's {self.num_experts} experts")
+    if self.router_scoring not in ("softmax", "sigmoid"):
+      raise ValueError(f"unknown router_scoring {self.router_scoring!r}")
+    if self.expert_form not in ("gated_silu", "relu2"):
+      raise ValueError(f"unknown expert_form {self.expert_form!r}")
+    gated = self.expert_form == "gated_silu"
     features = x.shape[-1]
     tokens = x.reshape(-1, features)
     n = tokens.shape[0]
@@ -317,19 +336,37 @@ class ShardedExpertsMoE(nn.Module):
     init = nn.initializers.normal(0.02)
     dense = lambda width, name: nn.Dense(  # noqa: E731
         width, use_bias=False, dtype=self.dtype, kernel_init=init, name=name)
-    w_gate_up = self.param("experts_gate_up", init,
-                           (count, features, 2 * self.expert_width))
+    if gated:
+      w_up = self.param("experts_gate_up", init,
+                        (count, features, 2 * self.expert_width))
+    else:
+      w_up = self.param("experts_up", init,
+                        (count, features, self.expert_width))
     w_down = self.param("experts_down", init,
                         (count, self.expert_width, features))
     if self.dtype is not None:
-      w_gate_up, w_down = w_gate_up.astype(self.dtype), w_down.astype(
-          self.dtype)
+      w_up, w_down = w_up.astype(self.dtype), w_down.astype(self.dtype)
+
+    def activation(up):
+      if gated:
+        gate, up = jnp.split(up, 2, axis=-1)
+        return jax.nn.silu(gate) * up
+      return jnp.square(jax.nn.relu(up))
 
     with jax.named_scope("moe_route"):
-      logits = dense(self.num_experts, "router")(tokens)
-      probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
-      top_probs, top_idx = jax.lax.top_k(probs, self.top_k)
-      top_probs = top_probs / jnp.sum(top_probs, axis=-1, keepdims=True)
+      logits = dense(self.num_experts, "router")(tokens).astype(jnp.float32)
+      if self.router_scoring == "softmax":
+        probs = jax.nn.softmax(logits, axis=-1)
+        top_probs, top_idx = jax.lax.top_k(probs, self.top_k)
+        top_probs = top_probs / jnp.sum(top_probs, axis=-1, keepdims=True)
+      else:
+        scores = jax.nn.sigmoid(logits)
+        bias = self.variable("buffers", "e_score_correction_bias", jnp.zeros,
+                             (self.num_experts,), jnp.float32).value
+        _, top_idx = jax.lax.top_k(scores + bias, self.top_k)
+        top_probs = jnp.take_along_axis(scores, top_idx, axis=-1)
+        top_probs = self.routed_scaling_factor * top_probs / (
+            jnp.sum(top_probs, axis=-1, keepdims=True) + 1e-20)
       local = top_idx.reshape(-1) - first
       held = (local >= 0) & (local < count)
       keys = jnp.where(held, local, count).astype(jnp.int32)
@@ -349,15 +386,13 @@ class ShardedExpertsMoE(nn.Module):
       # rows out, and the same tiles visited in every step.
       sizes = sizes.at[count - 1].add(rows - jnp.sum(sizes))
       buffer = jnp.where(filled[:, None], tokens[token], 0).astype(
-          w_gate_up.dtype)
+          w_up.dtype)
 
     with jax.named_scope("moe_experts"):
-      gate_up = jax.lax.ragged_dot(buffer, w_gate_up, sizes,
-                                   preferred_element_type=jnp.float32)
-      gate, up = jnp.split(gate_up, 2, axis=-1)
-      hidden = (jax.nn.silu(gate) * up).astype(w_down.dtype)
-      out = jax.lax.ragged_dot(hidden, w_down, sizes,
-                               preferred_element_type=jnp.float32)
+      up = jax.lax.ragged_dot(buffer, w_up, sizes,
+                              preferred_element_type=jnp.float32)
+      out = jax.lax.ragged_dot(activation(up).astype(w_down.dtype), w_down,
+                               sizes, preferred_element_type=jnp.float32)
 
     with jax.named_scope("moe_route"):
       routed = jnp.zeros((n, features), jnp.float32).at[token].add(
@@ -366,12 +401,18 @@ class ShardedExpertsMoE(nn.Module):
     result = routed
     if self.shared_width:
       with jax.named_scope("moe_shared"):
-        hidden = jax.nn.silu(dense(self.shared_width, "shared_gate_proj")(
-            tokens)) * dense(self.shared_width, "shared_up_proj")(tokens)
-        shared = dense(features, "shared_down_proj")(hidden)
-        shared_gate = jax.nn.sigmoid(
-            dense(1, "shared_expert_gate")(tokens).astype(jnp.float32))
-        result = result + shared.astype(jnp.float32) * shared_gate
+        if gated:
+          hidden = jax.nn.silu(dense(self.shared_width, "shared_gate_proj")(
+              tokens)) * dense(self.shared_width, "shared_up_proj")(tokens)
+        else:
+          hidden = activation(dense(self.shared_width, "shared_up_proj")(
+              tokens))
+        shared = dense(features, "shared_down_proj")(hidden).astype(
+            jnp.float32)
+        if self.shared_gate:
+          shared = shared * jax.nn.sigmoid(
+              dense(1, "shared_expert_gate")(tokens).astype(jnp.float32))
+        result = result + shared
     counters = {
         "moe_rows_held": rows_held.astype(jnp.float32),
         "moe_buffer_fill": rows_held.astype(jnp.float32) / rows,

@@ -1,15 +1,18 @@
 """A token-in, logits-out T2RModel over `layers/decoder.HybridDecoderBlock`:
-an embedding, a stack of blocks whose mixers follow a layer-type list
-(linear attention by the gated delta rule | gated softmax attention), every
-block with routed experts and a shared expert, a final zero-centred
-RMSNorm and an untied head onto the vocabulary held here.
+an embedding, a stack of blocks whose kinds follow a layer-type list, the
+final RMSNorm of those kinds and an untied head onto the vocabulary held
+here. Two-slot kinds (`linear`: the gated delta rule, `full`: gated softmax
+attention, each followed by routed experts with a shared expert) and
+one-slot kinds (`mamba`: the Mamba-2 state-space mixer, `attention`: plain
+softmax attention, `experts`: sigmoid-routed relu^2 experts with a shared
+expert); `layers/decoder.py` has the equations.
 
 Built for one chip's share of an expert-parallel, vocabulary-parallel
-training job (`configs/train_qwen3next_ep16share.gin`): the expert layer
-holds `experts_held` of the router's `num_experts`, and `vocab_size` is
-the slice of the vocabulary this chip embeds and scores; token ids and
-targets are ids of the slice. Trained through `train_eval_model` like any
-model.
+training job (`configs/train_qwen3next_ep16share.gin`,
+`configs/train_nemotron3nano_ep16share.gin`): an expert layer holds
+`experts_held` of its router's width, and `vocab_size` is the slice of the
+vocabulary this chip embeds and scores; token ids and targets are ids of
+the slice. Trained through `train_eval_model` like any model.
 
 Loss: next-token cross-entropy as the batch gives it: `tokens` and
 `targets` [T] int32 and one float `weight` a row; mean over rows of
@@ -59,16 +62,18 @@ class _HybridDecoder(nn.Module):
     # its own backward is GBs at T 4096, the stream between blocks 16 MB.
     block_cls = nn.remat(decoder.HybridDecoderBlock)
     counters = []
-    for i, mixer in enumerate(cfg.layer_types):
-      x, layer_counters = block_cls(cfg, mixer, self.dtype,
+    for i, kind in enumerate(cfg.layer_types):
+      x, layer_counters = block_cls(cfg, kind, self.dtype,
                                     name=f"layer_{i}")(x)
-      counters.append(layer_counters)
-    x = decoder.ZeroCentredRMSNorm(cfg.rms_norm_eps, name="norm_final")(x)
+      if layer_counters is not None:
+        counters.append(layer_counters)
+    x = decoder.final_norm(cfg, "norm_final")(x)
     head = self.param("head", decoder.matrix_init(),
                       (cfg.hidden_size, self.vocab_size))
     out = specs_lib.SpecStruct()
     for name in COUNTERS:
-      out[name] = jnp.stack([c[name] for c in counters])  # [layers]
+      # one entry a layer that has experts, in the layers' order
+      out[name] = jnp.stack([c[name] for c in counters])
     if mode == modes_lib.PREDICT:
       logits = jnp.dot(x, head.astype(x.dtype))
       out["logits"] = logits
@@ -105,7 +110,8 @@ def chunked_cross_entropy(hidden, head, targets, chunk: int):
 class HybridDecoderLM(abstract_model.T2RModel):
   """[B, T] token ids -> next-token loss (TRAIN/EVAL) or logits (PREDICT)
   through a stack of hybrid decoder blocks; sizes under the names of the
-  public `qwen3_next` config where it has one."""
+  public `qwen3_next` and `nemotron_h` configs where they have one (each
+  layer kind reads its own source's: `layers/decoder.py`)."""
 
   def __init__(self,
                sequence_length: int = 128,
@@ -130,6 +136,16 @@ class HybridDecoderLM(abstract_model.T2RModel):
                moe_intermediate_size: int = 32,
                shared_expert_intermediate_size: int = 32,
                expert_buffer_factor: float = 2.0,
+               norm_eps: float = 1e-5,
+               mamba_num_heads: int = 4,
+               mamba_head_dim: int = 16,
+               ssm_state_size: int = 16,
+               n_groups: int = 2,
+               conv_kernel: int = 4,
+               chunk_size: int = 128,
+               n_routed_experts: int = 8,
+               moe_shared_expert_intermediate_size: int = 32,
+               routed_scaling_factor: float = 2.5,
                loss_chunk: int = 1024,
                **kwargs):
     super().__init__(**kwargs)
@@ -152,6 +168,13 @@ class HybridDecoderLM(abstract_model.T2RModel):
         moe_intermediate_size=moe_intermediate_size,
         shared_expert_intermediate_size=shared_expert_intermediate_size,
         expert_buffer_factor=expert_buffer_factor,
+        norm_eps=norm_eps, mamba_num_heads=mamba_num_heads,
+        mamba_head_dim=mamba_head_dim, ssm_state_size=ssm_state_size,
+        n_groups=n_groups, conv_kernel=conv_kernel, chunk_size=chunk_size,
+        n_routed_experts=n_routed_experts,
+        moe_shared_expert_intermediate_size=(
+            moe_shared_expert_intermediate_size),
+        routed_scaling_factor=routed_scaling_factor,
         # The model knows its target platform (as SequenceRegressionModel).
         flash_interpret=self.device_type != "tpu")
 
@@ -188,8 +211,10 @@ class HybridDecoderLM(abstract_model.T2RModel):
                                  self._loss_chunk)
       weight = labels["weight"].astype(jnp.float32).reshape(-1)
       loss = jnp.mean(weight * jnp.mean(ce, axis=-1))
+    with_experts = [i for i, kind in enumerate(
+        self._decoder_config.layer_types) if decoder.has_experts(kind)]
     scalars = {}
     for name in COUNTERS:
-      for i in range(len(self._decoder_config.layer_types)):
-        scalars[f"{name}/layer_{i}"] = inference_outputs[name][i]
+      for at, i in enumerate(with_experts):
+        scalars[f"{name}/layer_{i}"] = inference_outputs[name][at]
     return loss, scalars

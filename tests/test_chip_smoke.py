@@ -42,6 +42,15 @@ TINY_HYBRID = (
     "HybridDecoderLM.device_type = 'cpu'",
     "DefaultRandomInputGenerator.batch_size = 2",
 )
+TINY_MAMBA = (
+    "HybridDecoderLM.sequence_length = 512",
+    "HybridDecoderLM.mamba_num_heads = 4",
+    "HybridDecoderLM.mamba_head_dim = 16",
+    "HybridDecoderLM.n_groups = 2",
+    "HybridDecoderLM.ssm_state_size = 16",
+    "HybridDecoderLM.chunk_size = 24",
+    "DefaultRandomInputGenerator.batch_size = 2",
+)
 CPU8 = ("cpu", 8)
 
 
@@ -125,6 +134,19 @@ class TestPhaseRehearsal:
     assert result["shape"] == [2, 2, 4, 64, 64] and result["interpreted"]
     for part, gap in result["max_abs_error"].items():
       assert gap <= result["tolerance"] * result["max_abs_entry"][part]
+
+  def test_state_space_phase(self, out_dir):
+    result = chip_smoke.phase_state_space(out_dir, TINY_MAMBA, device=CPU8)
+    # 64 tokens in chunks of 24: the last chunk is padded
+    assert result["shape"] == {"batch": 2, "length": 64, "heads": 4,
+                               "head_dim": 16, "groups": 2, "state": 16,
+                               "chunk": 24}
+    for kind, errors in result["relative_error"].items():
+      assert set(errors) == {"values", "dx", "ddt", "db", "dc"}
+      assert max(errors.values()) <= result["tolerance"][kind]
+    # the bfloat16 arm rounds its operands: it is not the float32 arm again
+    assert (result["relative_error"]["bfloat16"]["values"]
+            > 10 * result["relative_error"]["float32"]["values"])
 
   def test_barrier_phase(self, trained, out_dir):
     result = chip_smoke.phase_barrier(out_dir, TINY_CRITIC, device=CPU8)

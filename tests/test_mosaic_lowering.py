@@ -236,6 +236,17 @@ class TestFlashMosaicLowering:
         shape, shape, shape).compile().as_text()
     assert "flash_fwd" in text and "flash_bwd" in text
 
+  def test_thirty_two_heads_of_128_compile_at_t4096(self, one_chip):
+    """The attention layer of `configs/train_nemotron3nano_ep16share.gin`
+    (`nemotron3nano_train_T4096`: one head of 128 a program, 32 of them at
+    T 4096), forward and the one backward kernel, through the chip's whole
+    compiler: the d 128 side of `lane_block` and `_sum_rides`."""
+    shape = jax.ShapeDtypeStruct((1, 4096, 32 * 128), jnp.bfloat16,
+                                 sharding=one_chip)
+    text = jax.jit(_flash_grads(32, causal=True)).lower(
+        shape, shape, shape).compile().as_text()
+    assert "flash_fwd" in text and "flash_bwd" in text
+
   def test_f32_inputs_lower(self):
     s, h = _flash_shapes((1, 2, 256, 64), jnp.float32)
     _export_for_tpu(
@@ -559,6 +570,31 @@ class TestShippedStepsCompileForV5e:
                 and re.search(r"= f32\[[\d,]*64,64\]", line)]
     assert len(products) <= 6, len(products)
     assert memory.temp_size_in_bytes <= 5.30e9   # 5.30 GB before PR 34
+
+  def test_mamba_experts_decoder_train_step_fits_one_chip(self,
+                                                          v5e_devices):
+    """`configs/train_nemotron3nano_ep16share.gin` as shipped (1 x T 4096,
+    667 M parameters under Adam): the step compiles for one v5e, state and
+    temporaries under the chip's 16 GB, with the flash kernels, XLA's
+    grouped products for the experts, one sort an expert layer, and the
+    state-space scan's loops over chunks carrying one float32
+    [8 groups, 8 heads, 64, 128] state (four layers, forward, recomputed
+    forward and backward)."""
+    model, batch = _model_from_config(
+        "configs/train_nemotron3nano_ep16share.gin")
+    compiled = _lower_step_for_mesh(
+        model, _trainer_mesh(v5e_devices[:1]), batch, donate=True).compile()
+    memory = compiled.memory_analysis()
+    assert 7.9e9 < memory.argument_size_in_bytes < 8.1e9   # 667 M x 12 bytes
+    assert memory.argument_size_in_bytes + memory.temp_size_in_bytes < 15e9
+    assert memory.temp_size_in_bytes <= 4.5e9    # 3.89 GB at PR 35
+    text = compiled.as_text()
+    assert "flash_fwd" in text and "flash_bwd" in text
+    assert "ragged-dot" in text and " sort(" in text
+    scans = [line for line in text.splitlines()
+             if " while(" in line and "f32[1,8,8,64,128]" in line]
+    assert len(scans) >= 12, len(scans)
+    assert "gdn_inverse" not in text
 
   def test_tuned_grasping44_train_step_fits_one_chip(self, v5e_devices):
     """Grasping44 @472, batch 256, bf16 (train_qtopt_tpu_tuned.gin): the

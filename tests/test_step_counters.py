@@ -62,3 +62,34 @@ def test_models_name_no_counters_unless_they_say_so():
       == ("moe_",)
   plain = sequence_model.SequenceRegressionModel(device_type="cpu")
   assert getattr(plain, "step_counter_prefixes", ()) == ()
+
+
+def test_counters_are_named_by_the_layers_own_numbers():
+  """Where only some layers have experts (`MEMEM*EME`: layers 1, 3, 6, 8),
+  a counter carries the number of its layer, not its place among the expert
+  layers, and the record holds those and no others."""
+  import jax.numpy as jnp
+
+  from tensor2robot_tpu.models import hybrid_lm
+
+  kinds = ("mamba", "experts", "mamba", "experts", "mamba", "attention",
+           "experts", "mamba", "experts")
+  model = hybrid_lm.HybridDecoderLM(device_type="cpu", layer_types=kinds,
+                                    sequence_length=8, loss_chunk=8)
+  outputs = {name: jnp.arange(4.0) + 10 * i
+             for i, name in enumerate(hybrid_lm.COUNTERS)}
+  outputs.update(hidden=jnp.zeros((1, 8, 64)), head=jnp.zeros((64, 1024)))
+  labels = {"targets": jnp.zeros((1, 8), jnp.int32),
+            "weight": jnp.ones((1, 1))}
+  _, scalars = model.model_train_fn({}, labels, outputs, "train")
+  assert sorted(scalars) == sorted(
+      f"{name}/layer_{i}" for name in hybrid_lm.COUNTERS
+      for i in (1, 3, 6, 8))
+  assert float(scalars["moe_rows_held/layer_6"]) == 2.0      # the third
+  assert float(scalars["moe_buffer_fill/layer_8"]) == 13.0   # the fourth
+  recorder = _recorder(every_n_steps=1,
+                       counter_prefixes=model.step_counter_prefixes)
+  recorder.start()
+  _step(recorder, 1, dict(scalars, loss=1.0))
+  (_, record), = recorder.drain()
+  assert sorted(k for k in record if k.startswith("moe_")) == sorted(scalars)
