@@ -2,7 +2,7 @@
 
     python chip_smoke.py              # one chip: train, resume, serve,
                                       # kernels, delta_rule, state_space,
-                                      # barrier
+                                      # grouped_matmul, barrier
     python chip_smoke.py --multichip  # four chips: dp x fsdp training and
                                       # ring / ulysses sequence parallelism,
                                       # each against its one-chip reference
@@ -63,7 +63,7 @@ MAMBA_GIN = os.path.join(PACKAGE, "configs",
                          "train_nemotron3nano_ep16share.gin")
 
 ONE_CHIP_PHASES = ("train", "resume", "serve", "kernels", "delta_rule",
-                   "state_space", "barrier")
+                   "state_space", "grouped_matmul", "barrier")
 MULTICHIP_PHASES = ("multichip_dp", "multichip_sp")
 # A phase that needs what an earlier one left on disk is skipped (and
 # the run failed) when that one failed.
@@ -104,6 +104,13 @@ INVERSE_TOL = 1e-5
 # each product rounds its operands to 8 bits of mantissa.
 SCAN_F32_TOL = 1e-4
 SCAN_BF16_TOL = 3e-2
+# `ops/grouped_matmul` with the bfloat16 operands the experts hand it
+# against a float32 loop over the groups at `highest`, RELATIVE to the
+# largest entry: the forward result is a float32 sum of exact products
+# (the order of the sum alone differs); a cotangent leaves in bfloat16,
+# the float32 sum rounded once (2^-9 of an entry).
+GROUPED_F32_TOL = 1e-4
+GROUPED_BF16_TOL = 2.0 ** -7
 
 
 class PhaseFailed(RuntimeError):
@@ -793,12 +800,115 @@ def phase_state_space(out_dir: str, extra_bindings=(), device=("tpu", 1)
           "peak_device_bytes": _peak_device_bytes()}
 
 
+def phase_grouped_matmul(out_dir: str, extra_bindings=(), device=("tpu", 1)
+                         ) -> dict:
+  """Phase 6: the experts' grouped products (`ops/grouped_matmul`, the way
+  the layer calls it: Mosaic on the TPU) at the buffer, widths and held
+  experts `train_nemotron3nano_ep16share.gin` gives an expert layer, up
+  and down, bfloat16 operands: the forward result and both cotangents
+  against a float32 loop over the groups at `highest`. The balanced load
+  is dealt over the groups at random; the rows that hold no pair go to
+  the last group, as the layer gives them."""
+  del out_dir  # leaves nothing on disk
+  device = _device_record(device)
+  import jax
+  import jax.numpy as jnp
+  import numpy as np
+
+  from tensor2robot_tpu.layers import moe
+  from tensor2robot_tpu.ops import grouped_matmul
+  from tensor2robot_tpu.utils import config
+
+  config.clear_config()
+  try:
+    config.parse_config_files_and_bindings([MAMBA_GIN], list(extra_bindings))
+    t, b, hidden, experts, held, top_k, width, factor = [
+        config.query_parameter(name) for name in (
+            "HybridDecoderLM.sequence_length",
+            "DefaultRandomInputGenerator.batch_size",
+            "HybridDecoderLM.hidden_size", "HybridDecoderLM.n_routed_experts",
+            "HybridDecoderLM.experts_held",
+            "HybridDecoderLM.num_experts_per_tok",
+            "HybridDecoderLM.moe_intermediate_size",
+            "HybridDecoderLM.expert_buffer_factor")]
+  finally:
+    config.clear_config()
+  groups = held[1]
+  rows = moe.ShardedExpertsMoE(
+      num_experts=experts, experts_held=tuple(held), top_k=top_k,
+      buffer_factor=factor).buffer_rows(t * b)
+  rng = np.random.default_rng(36)
+  sizes = rng.multinomial(int(rows / factor), np.full(groups, 1.0 / groups))
+  sizes[-1] += rows - sizes.sum()
+  group_sizes = jnp.asarray(sizes, jnp.int32)
+  highest = jax.lax.Precision.HIGHEST
+
+  def loop(product):
+    ends = np.cumsum(sizes)
+    return [product(g, slice(int(end - size), int(end)))
+            for g, (size, end) in enumerate(zip(sizes, ends))]
+
+  def arm(lhs, rhs, cotangent):
+    out, vjp = jax.vjp(
+        lambda x, w: grouped_matmul.grouped_matmul(x, w, group_sizes),
+        lhs, rhs)
+    return (out,) + vjp(cotangent)
+
+  def reference(lhs, rhs, cotangent):
+    lhs, rhs = lhs.astype(jnp.float32), rhs.astype(jnp.float32)
+    dot = lambda x, y: jnp.dot(x, y, precision=highest)  # noqa: E731
+    return (
+        jnp.concatenate(loop(lambda g, r: dot(lhs[r], rhs[g]))),
+        jnp.concatenate(loop(lambda g, r: dot(cotangent[r], rhs[g].T))),
+        jnp.stack(loop(lambda g, r: dot(lhs[r].T, cotangent[r]))))
+
+  names = ("values", "dlhs", "drhs")
+  errors, largest, text = {}, {}, ""
+  for product, (k, n) in (("up", (hidden, width)), ("down", (width, hidden))):
+    lhs = jnp.asarray(rng.normal(size=(rows, k)), jnp.bfloat16)
+    rhs = jnp.asarray(rng.normal(size=(groups, k, n)) * 0.02, jnp.bfloat16)
+    cotangent = jnp.asarray(rng.normal(size=(rows, n)), jnp.float32)
+    compiled = jax.jit(arm).lower(lhs, rhs, cotangent).compile()
+    text += compiled.as_text()
+    got = compiled(lhs, rhs, cotangent)
+    _check(got[0].dtype == jnp.float32 and got[1].dtype == lhs.dtype
+           and got[2].dtype == rhs.dtype,
+           f"grouped_matmul ({product}) returned {[x.dtype for x in got]}")
+    want = jax.jit(reference)(
+        lhs, rhs, cotangent.astype(jnp.bfloat16).astype(jnp.float32))
+    largest[product] = {
+        name: float(jnp.max(jnp.abs(w))) for name, w in zip(names, want)}
+    errors[product] = {
+        name: float(jnp.max(jnp.abs(g.astype(jnp.float32) - w)))
+        / largest[product][name]
+        for name, g, w in zip(names, got, want)}
+    _check(np.isfinite(list(errors[product].values())).all()
+           and errors[product]["values"] <= GROUPED_F32_TOL
+           and max(errors[product]["dlhs"], errors[product]["drhs"])
+           <= GROUPED_BF16_TOL,
+           f"grouped_matmul ({product}) and the loop over the groups "
+           f"disagree: {errors[product]} of the largest entries "
+           f"{largest[product]}")
+  if device["platform"] == "tpu":
+    _check("grouped_matmul" in text and "grouped_matmul_t" in text
+           and "tpu_custom_call" in text and "ragged-dot" not in text,
+           "the compiled products hold no grouped_matmul custom call")
+  return {"phase": "grouped_matmul", "ok": True, "device": device,
+          "config": os.path.relpath(MAMBA_GIN, ROOT),
+          "shape": {"rows": rows, "groups": groups, "hidden": hidden,
+                    "width": width, "group_sizes": sizes.tolist()},
+          "relative_error": errors, "max_abs_entry": largest,
+          "tolerance": {"values": GROUPED_F32_TOL,
+                        "cotangents": GROUPED_BF16_TOL},
+          "peak_device_bytes": _peak_device_bytes()}
+
+
 BARRIER_WINDOWS = 5
 BARRIER_STEPS = 4
 
 
 def phase_barrier(out_dir: str, extra_bindings=(), device=("tpu", 1)) -> dict:
-  """Phase 5: is `jax.block_until_ready` a barrier here? The phase-1
+  """Phase 7: is `jax.block_until_ready` a barrier here? The phase-1
   train step on a resident batch, a window of steps closed by
   `block_until_ready`, against the same window closed by a host fetch
   (`utils.backend.state_barrier`), against dispatch alone."""
@@ -994,6 +1104,7 @@ def phase_multichip_sp(out_dir: str, extra_bindings=(),
 PHASES = {"train": phase_train, "resume": phase_resume,
           "serve": phase_serve, "kernels": phase_kernels,
           "delta_rule": phase_delta_rule, "state_space": phase_state_space,
+          "grouped_matmul": phase_grouped_matmul,
           "barrier": phase_barrier,
           "multichip_dp": phase_multichip_dp,
           "multichip_sp": phase_multichip_sp}

@@ -49,6 +49,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec
 
+from tensor2robot_tpu.ops import grouped_matmul
 from tensor2robot_tpu.parallel import mesh as mesh_lib
 
 __all__ = ["MixtureOfExperts", "ShardedExpertsMoE", "EXPERT_AXIS_PARAM_RULE",
@@ -286,12 +287,13 @@ class ShardedExpertsMoE(nn.Module):
   expert; the first `rows` of them (a static buffer: `buffer_factor` x the
   balanced load N x top_k x count / num_experts, rounded up to 128 rows)
   are gathered from the tokens, pass two grouped products
-  (`jax.lax.ragged_dot`: up, or gate and up in one, then down) and are
-  scatter-added back by token. Held pairs beyond the buffer are dropped and
-  counted. The rows of the buffer that hold no pair are zero and are given
-  to the last group, so the group sizes always add up to the buffer: the
-  products visit every row tile whatever the router picked, and a step's
-  device work does not depend on the weights or the batch.
+  (`ops/grouped_matmul.grouped_matmul`, float32 results: up, or gate and up
+  in one, then down) and are scatter-added back by token. Held pairs beyond
+  the buffer are dropped and counted. The rows of the buffer that hold no
+  pair are zero and are given to the last group, so the group sizes always
+  add up to the buffer: the products visit every row tile whatever the
+  router picked, and a step's device work does not depend on the weights or
+  the batch.
 
   Returns (result, counters): `moe_rows_held` (held pairs), `moe_buffer_fill`
   (held pairs / buffer rows), `moe_rows_dropped`, `moe_load_max_over_mean`
@@ -389,10 +391,9 @@ class ShardedExpertsMoE(nn.Module):
           w_up.dtype)
 
     with jax.named_scope("moe_experts"):
-      up = jax.lax.ragged_dot(buffer, w_up, sizes,
-                              preferred_element_type=jnp.float32)
-      out = jax.lax.ragged_dot(activation(up).astype(w_down.dtype), w_down,
-                               sizes, preferred_element_type=jnp.float32)
+      up = grouped_matmul.grouped_matmul(buffer, w_up, sizes)
+      out = grouped_matmul.grouped_matmul(
+          activation(up).astype(w_down.dtype), w_down, sizes)
 
     with jax.named_scope("moe_route"):
       routed = jnp.zeros((n, features), jnp.float32).at[token].add(

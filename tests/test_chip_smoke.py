@@ -51,6 +51,16 @@ TINY_MAMBA = (
     "HybridDecoderLM.chunk_size = 24",
     "DefaultRandomInputGenerator.batch_size = 2",
 )
+TINY_EXPERTS = (
+    "HybridDecoderLM.sequence_length = 128",
+    "HybridDecoderLM.hidden_size = 128",
+    "HybridDecoderLM.n_routed_experts = 8",
+    "HybridDecoderLM.experts_held = (0, 4)",
+    "HybridDecoderLM.num_experts_per_tok = 2",
+    "HybridDecoderLM.moe_intermediate_size = 116",
+    "HybridDecoderLM.expert_buffer_factor = 2.0",
+    "DefaultRandomInputGenerator.batch_size = 2",
+)
 CPU8 = ("cpu", 8)
 
 
@@ -147,6 +157,23 @@ class TestPhaseRehearsal:
     # the bfloat16 arm rounds its operands: it is not the float32 arm again
     assert (result["relative_error"]["bfloat16"]["values"]
             > 10 * result["relative_error"]["float32"]["values"])
+
+  def test_grouped_matmul_phase(self, out_dir):
+    result = chip_smoke.phase_grouped_matmul(out_dir, TINY_EXPERTS,
+                                             device=CPU8)
+    shape = result["shape"]
+    # 256 tokens x 2 picks, half the experts held, twice the balanced load
+    assert (shape["rows"], shape["groups"], shape["hidden"],
+            shape["width"]) == (512, 4, 128, 116)
+    assert sum(shape["group_sizes"]) == 512
+    assert sum(shape["group_sizes"][:-1]) < 256   # the tail holds the rest
+    for product in ("up", "down"):
+      errors = result["relative_error"][product]
+      assert errors["values"] <= result["tolerance"]["values"]
+      assert max(errors["dlhs"], errors["drhs"]) \
+          <= result["tolerance"]["cotangents"]
+      # the cotangents leave rounded to bfloat16: not the float32 sum again
+      assert errors["drhs"] > 10 * errors["values"]
 
   def test_barrier_phase(self, trained, out_dir):
     result = chip_smoke.phase_barrier(out_dir, TINY_CRITIC, device=CPU8)

@@ -14,6 +14,7 @@ from tensor2robot_tpu.layers import decoder
 from tensor2robot_tpu.layers import moe as moe_lib
 from tensor2robot_tpu.models import hybrid_lm
 from tensor2robot_tpu.ops import attention as attention_ops
+from tensor2robot_tpu.ops import grouped_matmul
 from tensor2robot_tpu.parallel import train_step as ts
 
 SEED = 2_147_483_659  # more than 32 signed bits hold
@@ -240,7 +241,7 @@ def test_group_sizes_fill_the_buffer_whatever_the_router_picks():
   """The grouped products are handed sizes that add up to the buffer, so they
   visit every row tile in every step."""
   seen = []
-  real = jax.lax.ragged_dot
+  real = grouped_matmul.grouped_matmul
 
   def spy(lhs, rhs, group_sizes, **kwargs):
     seen.append((lhs.shape[0], group_sizes))
@@ -253,11 +254,11 @@ def test_group_sizes_fill_the_buffer_whatever_the_router_picks():
       shared_width=0)
   params = {k: v for k, v in _moe_params(sizes).items()
             if not k.startswith("shared")}
-  jax.lax.ragged_dot = spy
+  grouped_matmul.grouped_matmul = spy
   try:
     layer.apply({"params": params}, x)
   finally:
-    jax.lax.ragged_dot = real
+    grouped_matmul.grouped_matmul = real
   assert len(seen) == 2
   for rows, group_sizes in seen:
     assert rows == 512 and int(jnp.sum(group_sizes)) == 512

@@ -37,6 +37,7 @@ from jax.sharding import (Mesh, NamedSharding, PartitionSpec,
                           SingleDeviceSharding)
 
 from tensor2robot_tpu.ops import attention
+from tensor2robot_tpu.ops import grouped_matmul
 from tensor2robot_tpu.ops import linear_attention
 
 _REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -311,6 +312,71 @@ def _decode_tick_shapes(s_sz, t, b, h, d, sharding=None):
   return lane, lane, lane, arena, arena, i32, i32, lanes
 
 
+# The experts' grouped products, [rows, K] x [G, K, N], in both expert
+# cells: nemotron's up and down, qwen3next's gate-and-up and down.
+_CELL_GROUPED = [(6144, 8, 2688, 1856), (6144, 8, 1856, 2688),
+                 (20480, 32, 2048, 1024), (20480, 32, 512, 2048)]
+
+
+def _grouped_calls(text: str) -> list:
+  """The custom calls of a compiled program that run the op's kernels:
+  (kernel, the instruction's line) a call. XLA names the instruction after
+  the kernel (`%grouped_matmul_t.9`, `%transpose_jvp_grouped_matmul__.1`)."""
+  calls = []
+  for line in text.splitlines():
+    name = line.split(" = ")[0]
+    if "custom-call(" in line and "grouped_matmul" in name:
+      calls.append(("grouped_matmul_t" if "grouped_matmul_t" in name
+                    else "grouped_matmul", line))
+  return calls
+
+
+class TestGroupedMatmulMosaicLowering:
+  """`ops/grouped_matmul.py`: the Mosaic lowering, and the chip's compiler
+  on its three products at both expert cells' shapes."""
+
+  def test_default_interpret_lowers_mosaic_for_tpu(self, tpu_lowering):
+    lhs = jax.ShapeDtypeStruct((256, 128), jnp.bfloat16)
+    rhs = jax.ShapeDtypeStruct((4, 128, 116), jnp.bfloat16)
+    sizes = jax.ShapeDtypeStruct((4,), jnp.int32)
+    module = _export_for_tpu(
+        jax.grad(lambda a, b, s: grouped_matmul.grouped_matmul(
+            a, b, s).sum(), argnums=(0, 1)), lhs, rhs, sizes).mlir_module()
+    # the sum's gradient needs no forward product: one kernel a cotangent
+    assert module.count('kernel_name = "grouped_matmul"') == 1
+    assert module.count('kernel_name = "grouped_matmul_t"') == 1
+
+  @pytest.mark.parametrize("rows,groups,k,n", _CELL_GROUPED)
+  def test_three_products_compile_for_v5e(self, rows, groups, k, n,
+                                          one_chip):
+    """Forward, the rows' cotangent and the weights' cotangent: two calls
+    of `grouped_matmul` and one of `grouped_matmul_t`, results float32
+    and the cotangents bfloat16, and no copy of the weights beside them
+    (a width of 1856 goes to the kernels behind the 2688: the docstring's
+    rule)."""
+    shape = lambda dims, dtype: jax.ShapeDtypeStruct(  # noqa: E731
+        dims, dtype, sharding=one_chip)
+
+    def products(lhs, rhs, sizes, cotangent):
+      out, vjp = jax.vjp(lambda a, b: grouped_matmul.grouped_matmul(
+          a, b, sizes, interpret=False), lhs, rhs)
+      return (out,) + vjp(cotangent)
+
+    compiled = jax.jit(products).lower(
+        shape((rows, k), jnp.bfloat16), shape((groups, k, n), jnp.bfloat16),
+        shape((groups,), jnp.int32), shape((rows, n), jnp.float32)).compile()
+    text = compiled.as_text()
+    assert sorted(kernel for kernel, _ in _grouped_calls(text)) == [
+        "grouped_matmul", "grouped_matmul", "grouped_matmul_t"]
+    weights = {f"bf16[{groups},{k},{n}]", f"bf16[{groups},{n},{k}]"}
+    copies = [line for line in text.splitlines() if " copy(" in line
+              and any(w in line.split(" copy(")[0] for w in weights)]
+    assert copies == [], copies
+    out, dlhs, drhs = compiled.out_info
+    assert (out.dtype, dlhs.dtype, drhs.dtype) == (
+        jnp.float32, jnp.bfloat16, jnp.bfloat16)
+
+
 class TestDecodeKernelMosaicLowering:
   """graftkern (ISSUE 20): the fused decode-tick kernel lowers via
   Mosaic for TPU. `interpret=None` resolves from the PROCESS backend at
@@ -483,6 +549,18 @@ def _trainer_mesh(devices):
               ("data", "fsdp", "model"))
 
 
+def _expert_products(text: str) -> tuple:
+  """(forward products, rows' cotangents, weights' cotangents, XLA's own
+  grouped products left) in a compiled step: four expert layers x up and
+  down, each forward twice under rematerialisation, make (16, 8, 8, 0).
+  The forward's result is float32, the rows' cotangent bfloat16."""
+  calls = _grouped_calls(text)
+  plain = [line for kernel, line in calls if kernel == "grouped_matmul"]
+  forward = [line for line in plain if re.search(r" = f32\[", line)]
+  return (len(forward), len(plain) - len(forward), len(calls) - len(plain),
+          text.count("ragged-dot"))
+
+
 class TestShippedStepsCompileForV5e:
   """The steps `chip_smoke.py` trains, from the shipped configs it
   parses, at their shipped size, state donated as the trainer donates
@@ -544,8 +622,9 @@ class TestShippedStepsCompileForV5e:
   def test_hybrid_decoder_train_step_fits_one_chip(self, v5e_devices):
     """`configs/train_qwen3next_ep16share.gin` as shipped (1 x T 4096, 626 M
     parameters under Adam): the step compiles for one v5e, state and
-    temporaries under the chip's 16 GB, with the flash kernels, XLA's
-    grouped products for the experts and one sort a layer in it, and the
+    temporaries under the chip's 16 GB, with the flash kernels, the
+    experts' grouped products as `grouped_matmul` / `grouped_matmul_t` (none
+    of XLA's left) and one sort a layer in it, and the
     delta rule's inverse as `gdn_inverse` in the chunked layout, forward
     and recomputed forward, with two products for its backward."""
     model, batch = _model_from_config(
@@ -557,7 +636,7 @@ class TestShippedStepsCompileForV5e:
     assert memory.argument_size_in_bytes + memory.temp_size_in_bytes < 15e9
     text = compiled.as_text()
     assert "flash_fwd" in text and "flash_bwd" in text
-    assert "ragged-dot" in text and " sort(" in text
+    assert _expert_products(text) == (16, 8, 8, 0) and " sort(" in text
     lines = text.splitlines()
     inverses = [line for line in lines
                 if "custom-call(" in line and "gdn_inverse" in line]
@@ -575,8 +654,9 @@ class TestShippedStepsCompileForV5e:
                                                           v5e_devices):
     """`configs/train_nemotron3nano_ep16share.gin` as shipped (1 x T 4096,
     667 M parameters under Adam): the step compiles for one v5e, state and
-    temporaries under the chip's 16 GB, with the flash kernels, XLA's
-    grouped products for the experts, one sort an expert layer, and the
+    temporaries under the chip's 16 GB, with the flash kernels, the
+    experts' grouped products as `grouped_matmul` / `grouped_matmul_t` (none
+    of XLA's left), one sort an expert layer, and the
     state-space scan's loops over chunks carrying one float32
     [8 groups, 8 heads, 64, 128] state (four layers, forward, recomputed
     forward and backward)."""
@@ -587,14 +667,36 @@ class TestShippedStepsCompileForV5e:
     memory = compiled.memory_analysis()
     assert 7.9e9 < memory.argument_size_in_bytes < 8.1e9   # 667 M x 12 bytes
     assert memory.argument_size_in_bytes + memory.temp_size_in_bytes < 15e9
-    assert memory.temp_size_in_bytes <= 4.5e9    # 3.89 GB at PR 35
+    assert memory.temp_size_in_bytes <= 3.0e9    # 2.59 GB (3.89 at PR 35)
     text = compiled.as_text()
     assert "flash_fwd" in text and "flash_bwd" in text
-    assert "ragged-dot" in text and " sort(" in text
+    assert _expert_products(text) == (16, 8, 8, 0) and " sort(" in text
     scans = [line for line in text.splitlines()
              if " while(" in line and "f32[1,8,8,64,128]" in line]
     assert len(scans) >= 12, len(scans)
     assert "gdn_inverse" not in text
+
+  @pytest.mark.parametrize("config_file,traffic,layers", [
+      ("configs/train_qwen3next_ep16share.gin", "pool_b1_T4096", 4),
+      ("configs/train_nemotron3nano_ep16share.gin", "pool_b1_T4096_v16384",
+       2)])
+  def test_experts_engage_the_op_at_the_rehearsals_sizes(
+      self, config_file, traffic, layers, v5e_devices):
+    """The step of each expert configuration at its traffic file's `tiny`
+    sizes, compiled for the chip: eight `grouped_matmul*` kernels an expert
+    layer (four forward, two and two for the cotangents), none of XLA's
+    grouped products."""
+    with open(os.path.join(_REPO_ROOT, "benchmarks", "traffic",
+                           traffic + ".json")) as f:
+      tiny = json.load(f)["tiny"]
+    model, _ = _model_from_config(config_file, [
+        b for b in tiny["bindings"] if "device_type" not in b])
+    lowered = _lower_step_for_mesh(
+        model, _trainer_mesh(v5e_devices[:1]), tiny["batch_size"],
+        donate=True)
+    assert "ragged_dot" not in lowered.as_text()
+    assert _expert_products(lowered.compile().as_text()) == (
+        4 * layers, 2 * layers, 2 * layers, 0)
 
   def test_tuned_grasping44_train_step_fits_one_chip(self, v5e_devices):
     """Grasping44 @472, batch 256, bf16 (train_qtopt_tpu_tuned.gin): the

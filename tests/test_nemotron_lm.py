@@ -22,6 +22,7 @@ from tensor2robot_tpu.layers import decoder
 from tensor2robot_tpu.layers import moe as moe_lib
 from tensor2robot_tpu.models import hybrid_lm
 from tensor2robot_tpu.ops import attention as attention_ops
+from tensor2robot_tpu.ops import grouped_matmul
 from tensor2robot_tpu.parallel import train_step as ts
 
 SEED = 2_147_483_659  # more than 32 signed bits hold
@@ -415,18 +416,18 @@ def test_relu2_experts_have_one_up_product_and_fill_the_buffer():
   """Un-gated: two grouped products a call (up, down), their group sizes
   adding up to the buffer whatever the router picked."""
   seen = []
-  real = jax.lax.ragged_dot
+  real = grouped_matmul.grouped_matmul
 
   def spy(lhs, rhs, group_sizes, **kwargs):
     seen.append((lhs.shape, rhs.shape, group_sizes))
     return real(lhs, rhs, group_sizes, **kwargs)
 
   x = jax.random.normal(jax.random.PRNGKey(4), (2, 128, 64))
-  jax.lax.ragged_dot = spy
+  grouped_matmul.grouped_matmul = spy
   try:
     _moe().apply({"params": _moe_params(), **_bias(np.zeros(8))}, x)
   finally:
-    jax.lax.ragged_dot = real
+    grouped_matmul.grouped_matmul = real
   assert [(lhs, rhs) for lhs, rhs, _ in seen] == [
       ((512, 64), (4, 64, 32)), ((512, 32), (4, 32, 64))]
   assert all(int(jnp.sum(sizes)) == 512 for _, _, sizes in seen)
