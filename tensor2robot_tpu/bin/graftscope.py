@@ -7,8 +7,11 @@ docs/ARCHITECTURE.md "Observability"); this is the read side:
   python -m tensor2robot_tpu.bin.graftscope <model_dir> [--top N]
       walk the model_dir for `metrics.jsonl` streams, Chrome trace
       JSONs, `runs.jsonl` and `jax.profiler` dirs; render step-time
-      breakdown, counters, slowest spans, and the latest run's
-      xray/compile summary ("report" may be spelled explicitly);
+      breakdown, counters, slowest spans, the latest run's
+      xray/compile summary and, where `hooks.profiler.ProfilerHook`
+      left a `device_scopes.json` beside a trace, the device's time by
+      phase (forward, recompute, backward, optimizer), by declared
+      scope and by module ("report" may be spelled explicitly);
   python -m tensor2robot_tpu.bin.graftscope history <dir-or-runs.jsonl>
       one line per recorded run (index, run_id, key metrics);
   python -m tensor2robot_tpu.bin.graftscope diff <runA> <runB>
@@ -94,6 +97,7 @@ from typing import Any, Dict, List, Optional, Tuple
 from tensor2robot_tpu.obs import flightrec as flightrec_lib
 from tensor2robot_tpu.obs import metrics as metrics_lib
 from tensor2robot_tpu.obs import runlog as runlog_lib
+from tensor2robot_tpu.obs import xray as xray_lib
 
 __all__ = ["build_report", "render_postmortem", "main"]
 
@@ -272,6 +276,22 @@ def _compile_lines(record: dict) -> List[str]:
   return lines
 
 
+def _device_scope_sections(profile_dir: str) -> List[List[str]]:
+  """The device's time by phase, scope and module, where `ProfilerHook`
+  left a `device_scopes.json` beside its trace."""
+  path = os.path.join(profile_dir, "device_scopes.json")
+  if not os.path.isfile(path):
+    return []
+  try:
+    with open(path) as f:
+      reduced = json.load(f)
+    lines = xray_lib.format_device_scopes(reduced)
+  except (OSError, ValueError, KeyError, TypeError):
+    return [[f"{path}: unreadable"]]
+  return [[f"{path} (executable {reduced.get('executable')}, module "
+           f"{reduced.get('module')})"] + lines]
+
+
 def _runlog_sections(model_dir: str) -> Tuple[List[List[str]], int]:
   """(run-history summary + xray compile table sections for the latest
   record, corrupt-line count) — runs.jsonl garbage lands in the same
@@ -331,6 +351,8 @@ def build_report(model_dir: str, top: int = 10) -> Optional[str]:
   if profile_dirs:
     sections.append(["jax.profiler traces (TensorBoard/Perfetto)"]
                     + [f"  {d}" for d in profile_dirs])
+    for directory in profile_dirs:
+      sections.extend(_device_scope_sections(directory))
   if (not metrics_files and not trace_files and not profile_dirs
       and not os.path.isfile(runs_path)):
     return None

@@ -32,9 +32,11 @@ Two tiers:
 Layout: one `<key>.json` metadata sidecar (strict JSON: name, key
 components, byte sizes, sha256 of the blob, the cold process's xray
 record) + one `<key>.bin` pickle blob (serialized executable + in/out
-tree defs) per entry. The sidecar is everything the backend-free readers
-(`graftscope cache` list/verify/evict, `entries`, `verify`) need — only
-`load`/`store` touch jax.
+tree defs) per entry, and beside them, where xray built one, the
+executable's op table as `<key>.ops` (JSON; `load_op_scopes`). The
+sidecar is everything the backend-free readers (`graftscope cache`
+list/verify/evict, `entries`, `verify`) need — only `load`/`store`
+touch jax.
 
 Contracts, same as the rest of `obs/`:
 
@@ -90,7 +92,10 @@ __all__ = ["CACHE_VERSION", "cache_key", "key_components_from_traced",
 # `t2r_train_loop_k<k>`). The key does not hold a function's name, so
 # an executable stored under the old name would come back as
 # `jit_step_fn` in a profiler trace: every older entry is retired once.
-CACHE_VERSION = 4
+# v5: an entry carries the executable's op table beside it (`<key>.ops`,
+# written by `store` only). An older entry has none and misses once, so
+# no reader ever pairs a table with an executable it was not read from.
+CACHE_VERSION = 5
 
 # Where both cache tiers live (ISSUE 22 §4). `JAX_COMPILATION_CACHE_DIR`
 # places them from outside: jax's own persistent cache is then that
@@ -122,6 +127,9 @@ def xla_cache_dir() -> str:
 
 _META_SUFFIX = ".json"
 _BLOB_SUFFIX = ".bin"
+# The executable's op table (`obs.xray.build_op_table`), JSON, beside the
+# entry; `store` writes it, `load_op_scopes` reads it.
+_OPS_SUFFIX = ".ops"
 _KEY_RE = re.compile(r"^[A-Za-z0-9_.-]+$")
 
 
@@ -385,8 +393,11 @@ class ExecutableCache:
   # -- write side -----------------------------------------------------------
 
   def store(self, key: str, compiled, record: Optional[Dict[str, Any]] = None,
-            name: Optional[str] = None) -> bool:
+            name: Optional[str] = None,
+            op_scopes: Optional[Dict[str, Any]] = None) -> bool:
     """Serializes + persists one executable; False (counted) on failure.
+    `op_scopes` (xray's op table) is written beside it, before the
+    sidecar, so an entry that is there is there whole.
 
     The serialized payload is VALIDATED by an in-process deserialize
     before anything touches disk: an executable that itself came out of
@@ -448,6 +459,12 @@ class ExecutableCache:
         with open(tmp, "wb") as f:
           f.write(blob)
         os.replace(tmp, blob_path)
+        if op_scopes is not None:
+          ops_path = os.path.join(self._dir, key + _OPS_SUFFIX)
+          tmp = ops_path + suffix
+          with open(tmp, "w") as f:
+            json.dump(op_scopes, f, separators=(",", ":"))
+          os.replace(tmp, ops_path)
         tmp = meta_path + suffix
         with open(tmp, "w") as f:
           json.dump(meta, f, sort_keys=True)
@@ -462,6 +479,16 @@ class ExecutableCache:
       return False
 
   # -- read side ------------------------------------------------------------
+
+  def load_op_scopes(self, key: str) -> Optional[Dict[str, Any]]:
+    """The op table `store` wrote beside entry `key`, or None: none was
+    stored (the table could not be built) or it does not read back."""
+    try:
+      with open(os.path.join(self._dir, key + _OPS_SUFFIX)) as f:
+        table = json.load(f)
+      return table if "ops" in table and "paths" in table else None
+    except (OSError, ValueError, TypeError):
+      return None
 
   def load(self, key: str) -> Optional[Dict[str, Any]]:
     """Deserializes one entry: {"compiled", "record", "load_ms", "bytes"}
@@ -542,7 +569,8 @@ class ExecutableCache:
           "falling back to a fresh compile", file=sys.stderr)
     try:
       meta_path, blob_path = self._paths(key)
-      for path in (meta_path, blob_path):
+      for path in (meta_path, blob_path,
+                   os.path.join(self._dir, key + _OPS_SUFFIX)):
         try:
           os.unlink(path)
         except OSError:
@@ -643,7 +671,7 @@ class ExecutableCache:
         created = float(entry.get("created_unix") or 0.0)
         if now - created < older_than_secs:
           continue
-      for suffix in (_META_SUFFIX, _BLOB_SUFFIX):
+      for suffix in (_META_SUFFIX, _BLOB_SUFFIX, _OPS_SUFFIX):
         try:
           os.unlink(os.path.join(self._dir, entry["key"] + suffix))
         except OSError:

@@ -29,8 +29,9 @@ Accounting per measured window (`every_n_steps` dispatches, default 1):
   — the split answers "what is the loop's wall clock spent waiting on";
 * `host_ms`       — the remainder (hooks, metric fetch, logging);
 * `step_ms`       — full window wall time / steps;
-* `examples_per_sec`, `compile` (first dispatch, or a dispatch-time
-  spike: re-trace/re-compile), `live_arrays` / `live_bytes` gauges.
+* `examples_per_sec`, `compile` (a dispatch of the window compiled or
+  loaded an executable: counted, not inferred from its duration),
+  `live_arrays` / `live_bytes` gauges.
 
 The barrier costs a real host fetch per measured window and serializes
 the dispatch/prefetch overlap: use `every_n_steps=1` only for CPU/debug
@@ -43,7 +44,8 @@ train-loop integration lives in `train_eval.py` +
 
 Spans (`obs.trace`, children of the loop's `train/iteration`):
 `train/data_wait`, `train/dispatch` (`train/compile_dispatch` marks the
-spikes), `train/barrier`, and `train/record` with its children
+dispatches that compiled or loaded an executable; its `xray/analyze`
+child says which), `train/barrier`, and `train/record` with its children
 `train/record/gauges` and one `train/record/observer` per observer:
 what the host does between a barrier's end and the next dispatch is
 what the device waits for.
@@ -66,12 +68,31 @@ __all__ = ["StepStatsRecorder"]
 # skips it.
 BARRIER_DOMINATED_RESIDUAL = 0.2
 
-# A dispatch call taking longer than BOTH this floor and 10x the running
-# median is counted as a compile event (tracing + XLA compile happen
-# synchronously inside the dispatch call; execution is async).
-COMPILE_FLOOR_MS = 50.0
-_COMPILE_SPIKE_FACTOR = 10.0
-_DISPATCH_HISTORY = 32
+# Backend compiles of this process, counted by a `jax.monitoring`
+# listener that the first recorder to `start()` installs (jax has no way
+# to take one listener out again, so there is one for the process). A
+# dispatch that blocks on a full device queue is slow and compiles
+# nothing: the marker comes from this count, never from a duration.
+_BACKEND_COMPILES = [0]
+_LISTENING = [False]
+
+
+def _count_backend_compiles() -> None:
+  """Installs the listener once. Where jax is absent or refuses, no
+  compile is ever counted and only cache hits mark a dispatch."""
+  if _LISTENING[0]:
+    return
+  _LISTENING[0] = True
+  try:
+    import jax.monitoring
+
+    def listener(event: str, _seconds: float, **_) -> None:
+      if event.endswith("backend_compile_duration"):
+        _BACKEND_COMPILES[0] += 1
+
+    jax.monitoring.register_event_duration_secs_listener(listener)
+  except Exception:  # noqa: BLE001 - telemetry never breaks the loop
+    pass
 
 
 class _WaitTimer:
@@ -158,8 +179,8 @@ class StepStatsRecorder:
     self._steps_in_window = 0
     self._dispatches_in_window = 0
     self._last_record_step: Optional[int] = None
-    self._dispatch_history_ms: List[float] = []
     self._t_dispatch_ns = 0
+    self._compiles_before = (0, 0.0)  # (backend compiles, cache hits)
     self._dispatch_span = trace_lib.Span(None, "", "", None)
     self._compile_in_window = 0
     self._observers: List[Callable[[int, Dict[str, float]], Any]] = []
@@ -182,6 +203,7 @@ class StepStatsRecorder:
   def start(self) -> None:
     """Marks the start of the first measurement window."""
     if self._enabled:
+      _count_backend_compiles()
       self._window_start_ns = time.perf_counter_ns()
 
   def data_wait(self):
@@ -191,7 +213,14 @@ class StepStatsRecorder:
   def before_dispatch(self) -> None:
     if self._enabled:
       self._dispatch_span = self._tracer.open("train/dispatch", cat="train")
+      self._compiles_before = self._compile_counts()
       self._t_dispatch_ns = time.perf_counter_ns()
+
+  def _compile_counts(self) -> Tuple[int, float]:
+    """(backend compiles of the process, executables `obs.excache`
+    loaded): what a dispatch can do instead of only launching."""
+    return (_BACKEND_COMPILES[0],
+            self._registry.counter("cache/hits").value)
 
   def after_dispatch(self) -> None:
     """Call immediately after the (async) step dispatch returns."""
@@ -201,19 +230,13 @@ class StepStatsRecorder:
     self._dispatch_span.close()
     self._dispatch_ns += dur_ns
     self._dispatches_in_window += 1
-    dispatch_ms = dur_ns / 1e6
-    history = self._dispatch_history_ms
-    median = sorted(history)[len(history) // 2] if history else 0.0
-    if not history or dispatch_ms > max(COMPILE_FLOOR_MS,
-                                        _COMPILE_SPIKE_FACTOR * median):
-      # First dispatch always compiles; later spikes are re-traces.
+    if self._compile_counts() != self._compiles_before:
+      # The dispatch compiled (a first call, a re-trace at new shapes)
+      # or took its executable from the cache (xray's `cache_hit`).
       self._compile_in_window += 1
       self._registry.counter("stepstats/compile_events").inc()
       self._tracer.add_complete("train/compile_dispatch",
                                 self._t_dispatch_ns, dur_ns, cat="train")
-    history.append(dispatch_ms)
-    if len(history) > _DISPATCH_HISTORY:
-      history.pop(0)
 
   def end_step(self, step: int, state: Any, num_steps: int = 1,
                metrics: Optional[Dict[str, Any]] = None) -> None:

@@ -135,6 +135,11 @@ class Span:
   def close(self) -> None:
     self.__exit__(None, None, None)
 
+  def set_arg(self, key: str, value: Any) -> None:
+    """Sets one of the open span's `args` (what only its end knows)."""
+    if self._tracer is not None:
+      self._args = dict(self._args or {}, **{key: value})
+
 
 _NULL_SPAN = Span(None, "", "", None)
 

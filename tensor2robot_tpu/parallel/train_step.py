@@ -269,8 +269,11 @@ def _build_step_fn(model) -> Callable:
         opt_state=new_opt_state,
         mutable_state=new_mutable if new_mutable else state.mutable_state,
         ema_params=new_ema)
-    metrics = {"loss": loss,
-               "global_gradient_norm": optax.global_norm(grads),
+    # A scope of its own (`obs.xray.DEVICE_SCOPES`): the norm reads every
+    # gradient once more, outside `loss` and `optimizer`.
+    with jax.named_scope("metrics"):
+      gradient_norm = optax.global_norm(grads)
+    metrics = {"loss": loss, "global_gradient_norm": gradient_norm,
                **scalars}
     return new_state, metrics
 
