@@ -34,16 +34,17 @@ repository's layouts:
   embedding (the source's attention module applies none; its state-space
   layers carry position).
 * `GatedDeltaNet`: one projection to [q, k, v, z] and one to [b, a], a
-  causal depthwise convolution and SiLU over [q, k, v], the gated delta
-  rule per value head (`ops/linear_attention`), RMSNorm of the result
-  times SiLU(z), the output projection. The source interleaves q, k, v, z
+  causal depthwise convolution and SiLU over [q, k, v] (`ops/short_conv`,
+  which reads the projection's columns in place), the gated delta rule
+  per value head (`ops/linear_attention`), RMSNorm of the result times
+  SiLU(z), the output projection. The source interleaves q, k, v, z
   per key head inside its projection; here they lie one after the other
   (a permutation of the projection's columns).
 * `Mamba2Mixer`: one projection to [z | x, B, C | dt], a causal depthwise
-  convolution with a bias and SiLU over [x, B, C], dt = softplus(dt +
-  dt_bias), the state-space scan per head (`ops/state_space`), the result
-  times SiLU(z) and then RMSNorm over each of `n_groups` groups of
-  channels, the output projection.
+  convolution with a bias and SiLU over [x, B, C] (`ops/short_conv`),
+  dt = softplus(dt + dt_bias), the state-space scan per head
+  (`ops/state_space`), the result times SiLU(z) and then RMSNorm over
+  each of `n_groups` groups of channels, the output projection.
 
 Norms, gates, the mixers' states and the softmax are float32; projections
 and products take `dtype`.
@@ -61,6 +62,7 @@ import jax.numpy as jnp
 from tensor2robot_tpu.layers import moe as moe_lib
 from tensor2robot_tpu.ops import attention as attention_ops
 from tensor2robot_tpu.ops import linear_attention
+from tensor2robot_tpu.ops import short_conv
 from tensor2robot_tpu.ops import state_space
 
 __all__ = ["DecoderConfig", "ZeroCentredRMSNorm", "RMSNorm", "GatedAttention",
@@ -150,18 +152,6 @@ class DecoderConfig:
 def _dense(features: int, dtype, name: str):
   return nn.Dense(features, use_bias=False, dtype=dtype,
                   kernel_init=matrix_init(), name=name)
-
-
-def _causal_conv_silu(x, kernel, bias=None):
-  """silu(causal depthwise convolution of x [B, T, C] with `kernel`
-  [width, C], plus `bias` [C]), in float32, returned in x's dtype."""
-  width, t = kernel.shape[0], x.shape[1]
-  padded = jnp.pad(x.astype(jnp.float32), ((0, 0), (width - 1, 0), (0, 0)))
-  taps = kernel.astype(jnp.float32)
-  mixed = sum(padded[:, j:j + t] * taps[j] for j in range(width))
-  if bias is not None:
-    mixed = mixed + bias.astype(jnp.float32)
-  return jax.nn.silu(mixed).astype(x.dtype)
 
 
 def _rms(x, eps: float):
@@ -291,8 +281,8 @@ class GatedDeltaNet(nn.Module):
     norm_weight = self.param("norm_weight", nn.initializers.ones, (d_v,))
 
     with jax.named_scope("gdn_conv"):
-      mixed = _causal_conv_silu(qkvz[..., :2 * key_dim + value_dim],
-                                conv_kernel)
+      mixed = short_conv.causal_conv_silu(
+          qkvz, conv_kernel, interpret=cfg.flash_interpret)
     q = mixed[..., :key_dim].reshape(b, t, k_heads, d_k)
     k = mixed[..., key_dim:2 * key_dim].reshape(b, t, k_heads, d_k)
     v = mixed[..., 2 * key_dim:].reshape(b, t, v_heads, d_v)
@@ -336,8 +326,9 @@ class Mamba2Mixer(nn.Module):
     norm_weight = self.param("norm_weight", nn.initializers.ones, (inner,))
 
     with jax.named_scope("ssm_conv"):
-      mixed = _causal_conv_silu(zxbcdt[..., inner:inner + conv_dim],
-                                conv_kernel, conv_bias)
+      mixed = short_conv.causal_conv_silu(
+          zxbcdt, conv_kernel, conv_bias, start=inner,
+          interpret=cfg.flash_interpret)
     z = zxbcdt[..., :inner].astype(jnp.float32)
     dt = jax.nn.softplus(zxbcdt[..., inner + conv_dim:].astype(jnp.float32)
                          + dt_bias.astype(jnp.float32))

@@ -39,6 +39,7 @@ from jax.sharding import (Mesh, NamedSharding, PartitionSpec,
 from tensor2robot_tpu.ops import attention
 from tensor2robot_tpu.ops import grouped_matmul
 from tensor2robot_tpu.ops import linear_attention
+from tensor2robot_tpu.ops import short_conv
 
 _REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 _PACKAGE = os.path.join(_REPO_ROOT, "tensor2robot_tpu")
@@ -377,6 +378,86 @@ class TestGroupedMatmulMosaicLowering:
         jnp.float32, jnp.bfloat16, jnp.bfloat16)
 
 
+# The two mixers' short convolution in both expert cells: (operand, first
+# column, channels, bias): qwen3next's [q, k, v] of `in_proj_qkvz`,
+# nemotron's [x, B, C] of `in_proj`.
+_CELL_CONV = [((1, 4096, 12288), 0, 8192, False),
+              ((1, 4096, 10304), 4096, 6144, True)]
+
+
+def _conv_calls(text: str) -> list:
+  """(kernel, the instruction's line) for each custom call of a compiled
+  program that runs `ops/short_conv.py`'s kernels (XLA names the
+  instruction after the kernel: `%short_conv.6`, `%short_conv_bwd.3`)."""
+  calls = []
+  for line in text.splitlines():
+    name = line.split(" = ")[0].strip().lstrip("ROOT ").lstrip("%")
+    if "custom-call(" in line and name.startswith("short_conv"):
+      calls.append((name.rsplit(".", 1)[0], line))
+  return calls
+
+
+def _first_operand_shape(text: str, line: str) -> str:
+  """The shape, as `bf16[1,4096,12288]`, of the instruction's first
+  operand, looked up by its name in the program's text."""
+  first = re.match(r"%?([\w.\-]+)", line.split("custom-call(")[1]).group(1)
+  found = re.search(rf"^\s*(?:ROOT )?%{re.escape(first)} = (\w+\[[\d,]*\])",
+                    text, re.M)
+  return found.group(1)
+
+
+def _channel_copies(text: str, channels: int) -> list:
+  """Synchronous copies of a [1, 4096, channels] array: a slice of the
+  conv's channels laid out again beside the kernel."""
+  return [line for line in text.splitlines()
+          if re.search(rf"= \w+\[1,4096,{channels}\]\{{[^}}]*\}} copy\(",
+                       line)]
+
+
+class TestShortConvMosaicLowering:
+  """`ops/short_conv.py`: the Mosaic lowering, and the chip's compiler on
+  the forward and backward kernels at both expert cells' shapes."""
+
+  def test_default_interpret_lowers_mosaic_for_tpu(self, tpu_lowering):
+    x = jax.ShapeDtypeStruct((1, 64, 512), jnp.bfloat16)
+    kernel = jax.ShapeDtypeStruct((4, 256), jnp.bfloat16)
+    bias = jax.ShapeDtypeStruct((256,), jnp.bfloat16)
+    # (a square, so that the cotangent needs the forward's result)
+    module = _export_for_tpu(
+        jax.grad(lambda a, k, b: jnp.square(short_conv.causal_conv_silu(
+            a, k, b, 128).astype(jnp.float32)).sum(), argnums=(0, 1, 2)),
+        x, kernel, bias).mlir_module()
+    assert module.count('kernel_name = "short_conv"') == 1
+    assert module.count('kernel_name = "short_conv_bwd"') == 1
+
+  @pytest.mark.parametrize("shape,start,channels,bias", _CELL_CONV)
+  def test_forward_and_backward_compile_for_v5e(self, shape, start,
+                                                channels, bias, one_chip):
+    """One call of each kernel, both reading the projection's result in
+    place (its whole shape is their operand: no slice of the channels is
+    written), the cotangents in the operands' dtypes."""
+    spec = lambda dims: jax.ShapeDtypeStruct(  # noqa: E731
+        dims, jnp.bfloat16, sharding=one_chip)
+
+    def conv(x, kernel, b, dy):
+      y, vjp = jax.vjp(lambda *a: short_conv.causal_conv_silu(
+          *a, start=start, interpret=False), x, kernel, b)
+      return (y,) + vjp(dy)
+
+    compiled = jax.jit(conv).lower(
+        spec(shape), spec((4, channels)), spec((channels,)) if bias else None,
+        spec(shape[:2] + (channels,))).compile()
+    text = compiled.as_text()
+    calls = _conv_calls(text)
+    assert sorted(kernel for kernel, _ in calls) == [
+        "short_conv", "short_conv_bwd"]
+    whole = "bf16[" + ",".join(str(d) for d in shape) + "]"
+    assert [_first_operand_shape(text, line) for _, line in calls] == [
+        whole, whole]
+    assert {info.dtype for info in jax.tree_util.tree_leaves(
+        compiled.out_info)} == {jnp.dtype(jnp.bfloat16)}
+
+
 class TestDecodeKernelMosaicLowering:
   """graftkern (ISSUE 20): the fused decode-tick kernel lowers via
   Mosaic for TPU. `interpret=None` resolves from the PROCESS backend at
@@ -549,6 +630,27 @@ def _trainer_mesh(devices):
               ("data", "fsdp", "model"))
 
 
+def _assert_convs_in_place(text, layers, operand, channels, scope):
+  """The mixers' short convolution as `ops/short_conv.py`'s kernels, one
+  call a layer in the forward, the recomputed forward and the backward
+  (so the op table names them, under the mixer's scope), each reading the
+  in-projection's whole result (no slice of the channels laid out again
+  beside it)."""
+  from tensor2robot_tpu.obs import xray
+
+  calls = _conv_calls(text)
+  assert sorted(kernel for kernel, _ in calls) == (
+      ["short_conv"] * 2 * layers + ["short_conv_bwd"] * layers)
+  table = xray.build_op_table(text)
+  entries = [xray.op_entry(table, line) for _, line in calls]
+  assert {e["scope"] for e in entries} == {scope}
+  assert sorted(e["phase"] for e in entries) == sorted(
+      ["forward", "recompute", "backward"] * layers)
+  whole = "bf16[" + ",".join(str(d) for d in operand) + "]"
+  assert {_first_operand_shape(text, line) for _, line in calls} == {whole}
+  assert _channel_copies(text, channels) == []
+
+
 def _expert_products(text: str) -> tuple:
   """(forward products, rows' cotangents, weights' cotangents, XLA's own
   grouped products left) in a compiled step: four expert layers x up and
@@ -626,7 +728,8 @@ class TestShippedStepsCompileForV5e:
     experts' grouped products as `grouped_matmul` / `grouped_matmul_t` (none
     of XLA's left) and one sort a layer in it, and the
     delta rule's inverse as `gdn_inverse` in the chunked layout, forward
-    and recomputed forward, with two products for its backward."""
+    and recomputed forward, with two products for its backward; the
+    short convolution as `short_conv` / `short_conv_bwd` in place."""
     model, batch = _model_from_config(
         "configs/train_qwen3next_ep16share.gin")
     compiled = _lower_step_for_mesh(
@@ -649,6 +752,7 @@ class TestShippedStepsCompileForV5e:
                 and re.search(r"= f32\[[\d,]*64,64\]", line)]
     assert len(products) <= 6, len(products)
     assert memory.temp_size_in_bytes <= 5.30e9   # 5.30 GB before PR 34
+    _assert_convs_in_place(text, 3, (1, 4096, 12288), 8192, "gdn_conv")
 
   def test_mamba_experts_decoder_train_step_fits_one_chip(self,
                                                           v5e_devices):
@@ -659,7 +763,8 @@ class TestShippedStepsCompileForV5e:
     of XLA's left), one sort an expert layer, and the
     state-space scan's loops over chunks carrying one float32
     [8 groups, 8 heads, 64, 128] state (four layers, forward, recomputed
-    forward and backward)."""
+    forward and backward); the short convolution as `short_conv` /
+    `short_conv_bwd` in place."""
     model, batch = _model_from_config(
         "configs/train_nemotron3nano_ep16share.gin")
     compiled = _lower_step_for_mesh(
@@ -675,17 +780,19 @@ class TestShippedStepsCompileForV5e:
              if " while(" in line and "f32[1,8,8,64,128]" in line]
     assert len(scans) >= 12, len(scans)
     assert "gdn_inverse" not in text
+    _assert_convs_in_place(text, 4, (1, 4096, 10304), 6144, "ssm_conv")
 
-  @pytest.mark.parametrize("config_file,traffic,layers", [
-      ("configs/train_qwen3next_ep16share.gin", "pool_b1_T4096", 4),
+  @pytest.mark.parametrize("config_file,traffic,layers,mixers", [
+      ("configs/train_qwen3next_ep16share.gin", "pool_b1_T4096", 4, 3),
       ("configs/train_nemotron3nano_ep16share.gin", "pool_b1_T4096_v16384",
-       2)])
+       2, 1)])
   def test_experts_engage_the_op_at_the_rehearsals_sizes(
-      self, config_file, traffic, layers, v5e_devices):
+      self, config_file, traffic, layers, mixers, v5e_devices):
     """The step of each expert configuration at its traffic file's `tiny`
     sizes, compiled for the chip: eight `grouped_matmul*` kernels an expert
     layer (four forward, two and two for the cotangents), none of XLA's
-    grouped products."""
+    grouped products; three `short_conv*` kernels a linear mixer (128
+    channels: nemotron's start at column 64, so they take the slice)."""
     with open(os.path.join(_REPO_ROOT, "benchmarks", "traffic",
                            traffic + ".json")) as f:
       tiny = json.load(f)["tiny"]
@@ -695,8 +802,10 @@ class TestShippedStepsCompileForV5e:
         model, _trainer_mesh(v5e_devices[:1]), tiny["batch_size"],
         donate=True)
     assert "ragged_dot" not in lowered.as_text()
-    assert _expert_products(lowered.compile().as_text()) == (
-        4 * layers, 2 * layers, 2 * layers, 0)
+    text = lowered.compile().as_text()
+    assert _expert_products(text) == (4 * layers, 2 * layers, 2 * layers, 0)
+    assert sorted(kernel for kernel, _ in _conv_calls(text)) == (
+        ["short_conv"] * 2 * mixers + ["short_conv_bwd"] * mixers)
 
   def test_tuned_grasping44_train_step_fits_one_chip(self, v5e_devices):
     """Grasping44 @472, batch 256, bf16 (train_qtopt_tpu_tuned.gin): the

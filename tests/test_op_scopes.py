@@ -59,6 +59,16 @@ DECODER = "transpose(jvp(_HybridDecoder))/loss/jvp(_HybridDecoder)/checkpoint"
     (f"{ROOT}/loss/{DECODER}/layer_1/moe/moe_experts/cond/branch_0_fun/"
      "jit(_tgmm)/grouped_matmul_t/while/body/dot_general",
      ("backward", "moe_experts", "layer_1/moe/grouped_matmul_t")),
+    # the short convolution's kernels: the custom call carries its scope
+    (f"{ROOT}/loss/jvp(_HybridDecoder)/layer_0/mixer/gdn_conv/jit(_forward)/"
+     "short_conv/pallas_call",
+     ("forward", "gdn_conv", "layer_0/mixer/short_conv")),
+    (f"{ROOT}/loss/{DECODER}/rematted_computation/layer_4/mixer/ssm_conv/"
+     "jit(_forward)/short_conv/pallas_call",
+     ("recompute", "ssm_conv", "layer_4/mixer/short_conv")),
+    (f"{ROOT}/loss/{DECODER}/layer_2/mixer/gdn_conv/jit(_backward)/"
+     "short_conv_bwd/pallas_call",
+     ("backward", "gdn_conv", "layer_2/mixer/short_conv_bwd")),
     (f"{ROOT}/loss/transpose(jvp(lm_loss))/while/body/closed_call/checkpoint/"
      "rematted_computation/jit(take_along_axis)/gather",
      ("recompute", "lm_loss", "")),
