@@ -1,25 +1,22 @@
-"""Kernels: the two flash backward kernels' (dq, and dk with dv) share of
-their roofline, in %, taken together: the algorithm has one backward.
-
-FLOPs: the five products the backward needs over half the square (the scores
-recomputed once, dP, dV, dQ, dK). The repository's two kernels each recompute
-the scores and dP, seven products in all; the two extra are recomputation and
-count nothing, so a backward that streams q once can gain here. Bytes: q, k,
-v, o, do read and dq, dk, dv written once in bf16; log-sum-exp and delta read
-in float32.
+"""Kernels: the one flash backward kernel's share of its roofline at the
+sequence cell's shape (128 x 2048 x 8 heads of 64), in %. The kernel is
+found by its name, `flash_bwd` (`flash_kernels.named_events`); a trace of
+the two-kernel backward it replaced has none, and reads nothing. FLOPs and
+bytes as `flash_d256_bwd_roofline.py` counts them: the five products the
+algorithm needs over the causal half square (one score recomputation, dP,
+dV, dK, dQ); q, k, v and dO read and dq, dk, dv written once in bf16, the
+log-sum-exp and delta rows in float32. Compute-bound: 6.98 ms of FLOPs
+against 2.31 ms of bytes a call on the v5e.
 """
 
+from benchmarks.layer_metrics import flash_d256_bwd_roofline
 from benchmarks.layer_metrics import flash_kernels
 
-
-def flops(bh: int, t: int, d: int) -> float:
-  return 5 * 2.0 * bh * (t * t / 2.0) * d
-
-
-def hbm_bytes(bh: int, t: int, d: int) -> float:
-  return 8.0 * bh * t * d * 2 + 2.0 * bh * t * 4
+flops = flash_d256_bwd_roofline.flops
+hbm_bytes = flash_d256_bwd_roofline.hbm_bytes
 
 
 def read(run):
-  return flash_kernels.roofline_share(run, ("dq", "dkv"), flops, hbm_bytes,
-                                      calls_of="dq")
+  return flash_kernels.roofline_share(
+      run, lambda events: flash_kernels.named_events(events, "flash_bwd"),
+      flops, hbm_bytes)

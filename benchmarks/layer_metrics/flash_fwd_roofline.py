@@ -6,8 +6,8 @@ calls x least time over the summed device time of the kernel's events in the
 traced window. At T 4096 and head size 64 the bound is compute (2.8 ms of
 FLOPs against 0.7 ms of bytes a call on the v5e).
 
-The three `pallas_call`s in `ops/attention.py` carry no `name=`;
-`flash_kernels.py` says how the trace shows them.
+The kernel is found by what it returns, which reads in a trace recorded
+before the kernels had names as well as in one after (`flash_kernels.py`).
 """
 
 from benchmarks.layer_metrics import flash_kernels
@@ -25,4 +25,5 @@ def hbm_bytes(bh: int, t: int, d: int) -> float:
 
 
 def read(run):
-  return flash_kernels.roofline_share(run, ("fwd",), flops, hbm_bytes)
+  return flash_kernels.roofline_share(run, flash_kernels.forward_events,
+                                      flops, hbm_bytes)

@@ -3,7 +3,8 @@ are told apart in a trace, and its sizes. No metric of its own.
 
 `trace_reduce.read_xplane` keeps a device op's HLO instruction text only,
 which carries no scope name, so an op is known by what its instruction says:
-its name (a Pallas kernel's `name=`, XLA's own `ragged-dot`), its opcode
+its name (a Pallas kernel's `name=`, such as the program's grouped products
+`grouped_matmul` and `grouped_matmul_t`, or XLA's own `ragged-dot`), its opcode
 (`sort`, `while`) or a shape only one part of the model has. All readers
 count top-level ops only (a loop's body lies inside the loop's event).
 """
@@ -13,9 +14,14 @@ import re
 from benchmarks.harness import trace_reduce
 
 RAGGED_DOT = "ragged-dot-none"   # XLA's grouped product; `-metadata` is not it
+# The program's own grouped products (`ops/grouped_matmul.py`): `grouped_matmul`
+# (forward, and the rows' cotangent) and `grouped_matmul_t` (the weights').
+GROUPED_MATMUL = "grouped_matmul"
 _BYTES = {"bf16": 2, "f32": 4, "s32": 4, "u32": 4, "f16": 2, "pred": 1,
           "s8": 1, "u8": 1}
 _SHAPE = re.compile(r"\b(bf16|f32|s32|u32|f16|pred|s8|u8)\[([\d,]*)\]")
+# Where the operand list closes and the attributes begin: `), name=`.
+_ATTRIBUTES = re.compile(r"\), [a-z_]+=")
 
 
 def sizes_of(run):
@@ -37,10 +43,12 @@ def step_ops(run):
 
 
 def shapes(text: str):
-  """[(element type, dims)] of every shape an instruction's text names, the
-  outputs first."""
+  """[(element type, dims)] of the instruction's results and operands, the
+  results first. Its attributes are not read: a Pallas kernel's
+  `operand_layout_constraints={...}` names every operand's shape again."""
+  head = _ATTRIBUTES.split(text, maxsplit=1)[0]
   return [(m.group(1), tuple(int(d) for d in m.group(2).split(",") if d))
-          for m in _SHAPE.finditer(text)]
+          for m in _SHAPE.finditer(head)]
 
 
 def nbytes(shape) -> float:
@@ -62,8 +70,14 @@ def own_name(text: str) -> str:
   return text.partition(" = ")[0].strip().lstrip("%")
 
 
+def kernel_name(text: str) -> str:
+  """`%short_conv_bwd.3 = ...` -> `short_conv_bwd`: the `name=` a Pallas
+  kernel was given."""
+  return own_name(text).partition(".")[0]
+
+
 def is_grouped_product(text: str) -> bool:
-  return own_name(text).startswith(RAGGED_DOT)
+  return own_name(text).startswith((RAGGED_DOT, GROUPED_MATMUL))
 
 
 def counter_records(run, name: str):

@@ -1,15 +1,35 @@
 """Kernels: the grouped expert products' share of their roofline, in %.
 
-The products are XLA's `ragged-dot` kernels (`jax.lax.ragged_dot`; eight a
-layer and step: gate-and-up and down, each forward, forward again under
-rematerialisation, and backward for the rows and for the weights). Least
-time of a call = the larger of FLOPs / bf16 peak and bytes / HBM bandwidth:
-FLOPs 2 x rows held x the expert's matrix (the rows the router really sent,
-by the program's counter `moe_rows_held`, not the buffer's rows: tiles that
-hold no pair are visited and count nothing); bytes the expert weights read
-or written whole and the row operands at the share of the buffer that is
-filled. The share is the calls' summed least time over their summed device
-time. At 160 rows an expert the weights' bytes and the FLOPs are of one size.
+The products are the program's Pallas kernels (`ops/grouped_matmul.py`),
+found by their own names, or XLA's `ragged-dot` where a program still runs
+`jax.lax.ragged_dot` (`hybrid_ops.is_grouped_product`): eight a layer and
+step, each of the layer's two matrices in its forward, its forward again
+under rematerialisation and its backward for the rows and for the weights.
+What a kernel's instruction names, and what is read of it (every shape of
+two dimensions or more; the three 1-D `s32` scalar-prefetch operands, the
+groups' offsets and the visit lists, are left out by that rule):
+
+    grouped_matmul    lhs bf16 [rows, K], weights [G, K, N] (or [G, N, K]
+                      where the op hands them over swapped so that the
+                      lane-aligned width is last) -> f32 [rows, N]; the
+                      rows' cotangent is the same kernel with the weights
+                      read transposed, -> [rows, K] in the rows' dtype
+    grouped_matmul_t  lhs [rows, K], the cotangent [rows, N] -> weights'
+                      cotangent [G, K, N]
+
+The weights are the shapes of three dimensions that lead with the G
+experts held (read, or in `grouped_matmul_t` written); the rows are the
+shapes of two, all of the static buffer's `rows`.
+
+Least time of a call = the larger of FLOPs / bf16 peak and bytes / HBM
+bandwidth. FLOPs: 2 x the rows held x the expert's matrix (K x N), the rows
+held being the program's counter `moe_rows_held` (the pairs the router
+really sent), not the buffer's rows. Bytes: the weights whole plus the row
+operands at the buffer's fill (rows held / buffer rows). Tiles that hold no
+pair are visited all the same and count nothing, so a change that stops
+visiting them gains here. The share is the calls' summed least time over
+their summed device time. At 160 rows an expert the weights' bytes and the
+FLOPs are of one size.
 """
 
 import statistics

@@ -1,7 +1,6 @@
 """The phase and scope readers on a run written by hand (a device's op line
 and an op table), without a table, and on what a rehearsal of a cell leaves
-behind. `BENCHMARK.json` does not list them yet (two accepted tests pin its
-`per_layer` list: PERF.md section 7), so they are read here by file name."""
+behind; and their entries in `BENCHMARK.json`."""
 
 import pytest
 
@@ -22,6 +21,24 @@ SCOPE_METRICS = ["scope_gdn_scan_ms", "scope_ssm_scan_ms",
 SPAN_METRICS = ["step_trace_s", "step_compile_s"]
 NEW_METRICS = PHASE_METRICS + SCOPE_METRICS + SPAN_METRICS
 EXPERT_CELLS = ["qwen3next_train_T4096", "nemotron3nano_train_T4096"]
+EVERY_CELL = ["g44_train_b256", "seq_train_T2048"] + EXPERT_CELLS
+# name -> (unit, layer, the cells it is listed for); `moves` follows the
+# layer: the compile cache moves `setup_s`, the others `examples_per_s`.
+LISTED = {
+    "step_forward_ms": ("ms", "step program", EVERY_CELL),
+    "step_backward_ms": ("ms", "step program", EVERY_CELL),
+    "step_unscoped_share": ("%", "step program", EVERY_CELL),
+    "step_recompute_ms": ("ms", "step program", EXPERT_CELLS),
+    "step_optimizer_ms": ("ms", "step program", EXPERT_CELLS),
+    "scope_lm_loss_ms": ("ms", "step program", EXPERT_CELLS),
+    "scope_gdn_scan_ms": ("ms", "linear attention", EXPERT_CELLS[:1]),
+    "scope_ssm_scan_ms": ("ms", "state space", EXPERT_CELLS[1:]),
+    "scope_moe_route_ms": ("ms", "experts", EXPERT_CELLS),
+    "scope_moe_experts_ms": ("ms", "experts", EXPERT_CELLS),
+    "scope_attn_ms": ("ms", "kernels", EXPERT_CELLS),
+    "step_trace_s": ("s", "compile cache", EVERY_CELL),
+    "step_compile_s": ("s", "compile cache", EVERY_CELL),
+}
 
 
 def _table():
@@ -195,3 +212,23 @@ def test_rehearsal_leaves_the_table_and_the_compile_spans(capsys, cell):
   assert manifest.layer_metric_reader("step_trace_s")(run) > 0
   assert manifest.layer_metric_reader("step_compile_s")(run) > 0
   assert result["metrics"] == {}  # a rehearsal puts no number under a name
+
+
+def check_listing(benchmark):
+  """Each phase, scope and compile-span metric is in `benchmark` with its
+  unit, layer, source and cells, lower being better: whatever entries later
+  PRs append."""
+  per_layer = {m["name"]: m for m in benchmark["per_layer"]}
+  for name, (unit, layer, cells) in LISTED.items():
+    metric = per_layer[name]
+    spans = layer == "compile cache"
+    assert (metric["unit"], metric["layer"], metric["better"]) == (
+        unit, layer, "lower"), name
+    assert metric["source"] == ("program_span" if spans else "device_trace")
+    assert metric["moves"] == ("setup_s" if spans else "examples_per_s")
+    assert set(cells) <= set(metric["workloads"]), name
+  assert sorted(LISTED) == sorted(NEW_METRICS)
+
+
+def test_new_metrics_are_listed_with_their_cells():
+  check_listing(manifest.load_benchmark())

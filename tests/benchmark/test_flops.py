@@ -60,13 +60,12 @@ def test_flash_kernel_counts_by_hand():
   # q, k, v read and o written once (bf16), the log-sum-exp written (f32)
   assert fwd.hbm_bytes(bh, t, d) == pytest.approx(
       4 * bh * t * d * 2 + bh * t * 4)
-  # backward: 5 products over the half square (scores, dP, dQ; scores, dP,
-  # dK, dV share two recomputations: 2 in the dq kernel + ... = 7 in all, of
-  # which the algorithm needs 5: dV, dP, dQ, dK and one score recomputation).
+  # backward: the 5 products the algorithm needs over the half square (dV,
+  # dP, dQ, dK and one score recomputation), however many a kernel runs.
   assert bwd.flops(bh, t, d) == pytest.approx(5 * 2 * square * d)
-  # q, k, v, o, do read; dq, dk, dv written (bf16); lse and delta read (f32)
+  # q, k, v, do read; dq, dk, dv written (bf16); lse and delta read (f32)
   assert bwd.hbm_bytes(bh, t, d) == pytest.approx(
-      8 * bh * t * d * 2 + 2 * bh * t * 4)
+      7 * bh * t * d * 2 + 2 * bh * t * 4)
 
 
 def test_peaks_table():

@@ -14,15 +14,24 @@ from benchmarks.layer_metrics import moe_route_ms
 from benchmarks.references import qwen3next_80b_a3b_ep16share as ref
 
 CELL = "qwen3next_train_T4096"
+CONFIG = "qwen3next_80b_a3b_ep16share"
 D0 = "/device:TPU:0"
-NEW_METRICS = ["gdn_scan_ms", "moe_route_ms", "moe_experts_roofline",
-               "flash_d256_fwd_roofline", "flash_d256_bwd_roofline",
-               "moe_buffer_fill", "moe_load_max_over_mean"]
+# The cell's own metrics, each with its layer.
+METRIC_LAYERS = {
+    "gdn_scan_ms": "linear attention", "moe_route_ms": "experts",
+    "moe_experts_roofline": "kernels", "flash_d256_fwd_roofline": "kernels",
+    "flash_d256_bwd_roofline": "kernels", "moe_buffer_fill": "experts",
+    "moe_load_max_over_mean": "experts"}
+NEW_METRICS = list(METRIC_LAYERS)
+# The metrics with no `workloads` list, which every cell reports.
+GENERIC_METRICS = {"first_step_s", "data_wait_ms", "host_gap_ms",
+                   "step_device_ms", "step_mfu", "device_idle_share",
+                   "hbm_peak_gb"}
 
 
 def _config():
   with open(os.path.join(manifest.BENCH_DIR, "configs",
-                         "qwen3next_80b_a3b_ep16share.json")) as f:
+                         CONFIG + ".json")) as f:
     return json.load(f)
 
 
@@ -253,18 +262,25 @@ def test_shares_stay_under_their_ceiling_on_the_hand_trace():
       assert 0 < manifest.layer_metric_reader(name)(_run()) <= 105.0
 
 
+def check_listing(benchmark):
+  """This cell, its configuration and its own metrics are in `benchmark`,
+  each metric with the layer and the end-to-end metric it had when the cell
+  came, and the cell reports them and the generic ones: whatever entries
+  later PRs append."""
+  per_layer = {m["name"]: m for m in benchmark["per_layer"]}
+  for name, layer in METRIC_LAYERS.items():
+    assert CELL in per_layer[name]["workloads"], name
+    assert per_layer[name]["layer"] == layer, name
+    assert per_layer[name]["moves"] == "examples_per_s", name
+  reported = {m["name"] for m in manifest.Cell(CELL, benchmark).metrics(
+      "per_layer")}
+  assert reported >= set(NEW_METRICS) | GENERIC_METRICS
+  assert CELL in {w["name"] for w in benchmark["workloads"]}
+  assert CONFIG in {c["name"] for c in benchmark["configs"]}
+
+
 def test_new_metrics_are_listed_with_their_cell():
-  per_layer = {m["name"]: m for m in manifest.load_benchmark()["per_layer"]}
-  for name in NEW_METRICS:
-    assert per_layer[name]["workloads"] == [CELL]
-    assert per_layer[name]["moves"] == "examples_per_s"
-  assert per_layer["gdn_scan_ms"]["layer"] == "linear attention"
-  assert {per_layer[n]["layer"] for n in NEW_METRICS if "moe" in n} == {
-      "experts", "kernels"}
-  # no accepted metric's list gained the cell
-  for name, metric in per_layer.items():
-    if name not in NEW_METRICS:
-      assert CELL not in metric.get("workloads", [])
+  check_listing(manifest.load_benchmark())
 
 
 def test_buffer_rows_are_the_programs():

@@ -10,6 +10,9 @@ import re
 import pytest
 
 from benchmarks.harness import manifest
+from tests.benchmark import test_device_scopes
+from tests.benchmark import test_hybrid_cell
+from tests.benchmark import test_nemotron_cell
 
 BENCHMARK = manifest.load_benchmark()
 METRICS = BENCHMARK["end_to_end"] + BENCHMARK["per_layer"]
@@ -133,6 +136,65 @@ def test_a_later_pr_adds_a_cell_with_files_and_entries_alone(tmp_path):
   assert "flash_fwd_roofline" not in [
       m["name"] for m in cell.metrics("per_layer")]
   assert importlib.import_module("benchmarks.drivers.trainer").run
+
+
+LATER_CELL = "later_train_T2048"
+LATER_METRIC = "later_kernel_roofline"
+
+
+def _later_benchmark(tmp_path):
+  """The real `BENCHMARK.json` with what a later `model_config` PR appends: a
+  fifth configuration (its sizes, traffic mix and limits laid in `tmp_path`,
+  its reference one already in the checkout), a fifth cell and a per-layer
+  metric listed for an accepted cell and the new one. Returns it with the
+  new cell as the harness finds it."""
+  benchmark = copy.deepcopy(BENCHMARK)
+  bench = benchmark["paths"][0]
+  accepted = manifest.Cell(test_nemotron_cell.CELL)
+  config = dict(accepted.config, name="later_model")
+  traffic = dict(accepted.traffic, model={"sequence_length": 2048},
+                 bindings=["HybridDecoderLM.sequence_length = 2048"])
+  for folder, name, data in (("configs", "later_model", config),
+                             ("traffic", "pool_b1_T2048_later", traffic),
+                             ("limits", LATER_CELL, accepted.limits)):
+    (tmp_path / bench / folder).mkdir(parents=True, exist_ok=True)
+    (tmp_path / bench / folder / f"{name}.json").write_text(json.dumps(data))
+  benchmark["configs"].append({
+      "name": "later_model", "source": config["source"],
+      "file": f"{bench}/configs/later_model.json",
+      "reduced": config["reduced"], "why": "a later PR's configuration"})
+  benchmark["workloads"].append({
+      "name": LATER_CELL, "config": "later_model",
+      "traffic": "pool_b1_T2048_later", "chips": 1,
+      "why": "a later PR's cell"})
+  benchmark["per_layer"].append({
+      "name": LATER_METRIC, "unit": "%", "better": "higher",
+      "source": "device_trace", "layer": "kernels",
+      "moves": "examples_per_s",
+      "workloads": [*test_device_scopes.EXPERT_CELLS, LATER_CELL]})
+  cell = manifest.Cell(LATER_CELL, benchmark,
+                       roots=(str(tmp_path), manifest.ROOT))
+  return benchmark, cell
+
+
+@pytest.mark.parametrize("check", [
+    test_hybrid_cell.check_listing, test_nemotron_cell.check_listing,
+    test_device_scopes.check_listing], ids=lambda c: c.__module__)
+def test_accepted_listing_checks_hold_after_a_later_pr_appends(tmp_path,
+                                                              check):
+  """A fifth configuration, cell and metric appended to the real
+  `BENCHMARK.json` leave every accepted cell's listing check true: those
+  checks ask for their own entries, never for a count or a position."""
+  benchmark, cell = _later_benchmark(tmp_path)
+  assert len(benchmark["configs"]) == len(BENCHMARK["configs"]) + 1
+  assert cell.config_name == "later_model"
+  assert cell.traffic["model"] == {"sequence_length": 2048}
+  assert cell.reference().__name__.endswith(test_nemotron_cell.CONFIG)
+  reported = [m["name"] for m in cell.metrics("per_layer")]
+  assert LATER_METRIC in reported
+  assert test_hybrid_cell.GENERIC_METRICS <= set(reported)
+  assert "moe_e128_route_ms" not in reported  # listed for its own cell only
+  check(benchmark)
 
 
 def test_unknown_cell_is_an_error():

@@ -122,9 +122,13 @@ def test_recorded_sequence_trace():
   assert durations[0] == pytest.approx(0.1868, rel=2e-3)
   busy = tr.device_busy(events)
   assert busy["busy_s"] / busy["window_s"] > 0.999   # back-to-back steps
-  # 2 blocks x 2 steps of each kernel, told apart by what they return
-  for kernel, per_call_ms in (("fwd", 13.8), ("dq", 13.5), ("dkv", 24.0)):
-    calls = flash_kernels.kernel_events(events, kernel)
+  # 2 blocks x 2 steps of each kernel, told apart by what they return: the
+  # forward (o, log-sum-exp), dq, and dk with dv
+  for outputs, per_call_ms in ((flash_kernels.FORWARD_OUTPUTS, 13.8),
+                               (["bf16"], 13.5), (["bf16", "bf16"], 24.0)):
+    calls = [e for e in tr.select(events, plane=D0, line=OPS,
+                                  name_has=flash_kernels.PALLAS_TARGET)
+             if tr.output_shapes(e[2]) == outputs]
     assert len(calls) == 4
     assert sum(e[4] for e in calls) / 4 / 1e6 == pytest.approx(per_call_ms,
                                                               rel=0.02)
@@ -144,9 +148,42 @@ def test_recorded_flash_rooflines():
                    "sequence_length": 2048}}
   # forward: 0.55 TFLOP a call = 2.79 ms at the peak, against 13.8 ms
   assert flash_fwd_roofline.read(run) == pytest.approx(20.2, abs=0.3)
-  assert flash_bwd_roofline.read(run) == pytest.approx(18.6, abs=0.3)
+  # The two-kernel backward of that program has no kernel named `flash_bwd`:
+  # nothing to read.
+  assert flash_bwd_roofline.read(run) is None
   assert flash_fwd_roofline.read(dict(run, events=_recorded(
       "g44_train_b256_two_steps"))) is None   # no kernel: nothing to read
+
+
+def test_recorded_named_kernels_trace():
+  """Two steps of the sequence cell's program with its one backward kernel
+  (TPU v5 lite), each kernel named by its `name=`."""
+  from benchmarks.harness import peaks
+  from benchmarks.layer_metrics import flash_bwd_roofline
+  from benchmarks.layer_metrics import flash_fwd_roofline
+  from benchmarks.layer_metrics import flash_kernels
+
+  events = _recorded("seq_train_T2048_named_kernels_two_steps")
+  name, durations = tr.heaviest_module(events, D0)
+  assert name == "jit_t2r_train_step"
+  assert durations == [pytest.approx(0.1315, rel=2e-3)] * 2
+  busy = tr.device_busy(events)
+  assert busy["busy_s"] / busy["window_s"] > 0.999
+  # 2 blocks x 2 steps of each kernel, found by name
+  for kernel, per_call_ms in (("flash_fwd", 11.64), ("flash_bwd", 22.65)):
+    calls = flash_kernels.named_events(events, kernel)
+    assert len(calls) == 4
+    assert sum(e[4] for e in calls) / 4 / 1e6 == pytest.approx(per_call_ms,
+                                                              rel=0.01)
+  assert len(flash_kernels.forward_events(events)) == 4
+  run = {"events": events, "peaks": peaks.peaks_for("TPU v5 lite"),
+         "batch_size": 128,
+         "sizes": {"num_heads": 8, "hidden_size": 512,
+                   "sequence_length": 2048}}
+  # backward: 5 x 2 x 1024 x 2048^2 / 2 x 64 FLOPs = 6.98 ms at the peak,
+  # against 22.65 ms a call; forward 2.79 ms against 11.64
+  assert flash_bwd_roofline.read(run) == pytest.approx(30.8, abs=0.1)
+  assert flash_fwd_roofline.read(run) == pytest.approx(23.97, abs=0.1)
 
 
 def test_recorded_grasping44_trace():
