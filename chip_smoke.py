@@ -104,6 +104,15 @@ INVERSE_TOL = 1e-5
 # each product rounds its operands to 8 bits of mantissa.
 SCAN_F32_TOL = 1e-4
 SCAN_BF16_TOL = 3e-2
+# `ops/state_space.ssd_scan`'s Mosaic kernels against autodiff of the XLA
+# form they replaced, both with bfloat16 operands, RELATIVE to the largest
+# entry of y, the last state and each cotangent: the same products of the
+# same rounded operands summed in another order, where one operand that
+# rounds the other way moves its term by 2^-8 of itself, and the
+# cotangent of the convolution's result leaves in bfloat16 (one rounding,
+# up to 2^-7 of the largest entry; CPU, interpreted: 7e-3 at most; the
+# chip at the shipped length: 6.4e-3).
+SCAN_KERNEL_TOL = 1e-2
 # `ops/grouped_matmul` with the bfloat16 operands the experts hand it
 # against a float32 loop over the groups at `highest`, RELATIVE to the
 # largest entry: the forward result is a float32 sum of exact products
@@ -735,7 +744,11 @@ def phase_state_space(out_dir: str, extra_bindings=(), device=("tpu", 1)
   `train_nemotron3nano_ep16share.gin` gives it and an eighth of its length
   (the recurrence keeps a [64, 64, 128] state a token for its backward):
   values and gradients, with float32 products at `highest` and with the
-  bfloat16 operands the model hands it."""
+  bfloat16 operands the model hands it. Then the op the model runs,
+  `ssd_scan` (Mosaic kernels on the TPU), against autodiff of
+  `ssd_chunked` at the shipped length and batch, x, B and C read from one
+  [B, T, H P + 2 G N] operand as the mixer hands them: y, the last state
+  and every cotangent (x, B, C, dt, a_log, D)."""
   del out_dir  # leaves nothing on disk
   device = _device_record(device)
   import jax
@@ -756,7 +769,7 @@ def phase_state_space(out_dir: str, extra_bindings=(), device=("tpu", 1)
         "HybridDecoderLM.chunk_size")]
   finally:
     config.clear_config()
-  t = max(t // 8, 1)
+  length, t = t, max(t // 8, 1)
   rng = np.random.default_rng(35)
   normal = lambda *shape: jnp.asarray(  # noqa: E731
       rng.normal(size=shape), jnp.float32)
@@ -791,13 +804,75 @@ def phase_state_space(out_dir: str, extra_bindings=(), device=("tpu", 1)
                for e in errors[kind].values()),
            f"the chunked scan ({kind}) and the recurrence disagree beyond "
            f"{tolerance} of the largest entry: {errors[kind]}")
+  kernels = _scan_kernels_against_xla(length, b, h, p, g, n, chunk, rng)
+  mosaic = kernels.pop("mosaic")
+  _check(device["platform"] != "tpu" or mosaic,
+         "the compiled op holds no ssd_scan / ssd_scan_bwd custom call")
   return {"phase": "state_space", "ok": True, "device": device,
           "config": os.path.relpath(MAMBA_GIN, ROOT),
           "shape": {"batch": b, "length": t, "heads": h, "head_dim": p,
                     "groups": g, "state": n, "chunk": chunk},
           "relative_error": errors, "max_abs_entry": largest,
           "tolerance": {"float32": SCAN_F32_TOL, "bfloat16": SCAN_BF16_TOL},
-          "peak_device_bytes": _peak_device_bytes()}
+          "kernels": kernels, "peak_device_bytes": _peak_device_bytes()}
+
+
+def _scan_kernels_against_xla(t, b, h, p, g, n, chunk, rng) -> dict:
+  """`ssd_scan` against autodiff of `ssd_chunked` on one [B, T, H P + 2 G N]
+  operand, bfloat16 operands, at the given sizes; checked against
+  SCAN_KERNEL_TOL. Returns the errors relative to each largest entry and
+  whether the compiled op holds both kernels."""
+  import jax
+  import jax.numpy as jnp
+  import numpy as np
+
+  from tensor2robot_tpu.ops import state_space
+
+  normal = lambda *shape: jnp.asarray(  # noqa: E731
+      rng.normal(size=shape), jnp.float32)
+  x_end, b_end = h * p, h * p + g * n
+  args = (normal(b, t, b_end + g * n).astype(jnp.bfloat16),
+          jax.nn.softplus(normal(b, t, h) - 3.0),
+          jnp.log(jnp.arange(1, h + 1, dtype=jnp.float32)), normal(h))
+  cotangents = (normal(b, t, h * p), normal(b, h, p, n))
+
+  def xla(mixed, dt, a_log, d):
+    y, last = state_space.ssd_chunked(
+        mixed[..., :x_end].reshape(b, t, h, p), dt, a_log,
+        mixed[..., x_end:b_end].reshape(b, t, g, n),
+        mixed[..., b_end:].reshape(b, t, g, n), d, chunk_size=chunk,
+        matmul_dtype=jnp.bfloat16)
+    return y.reshape(b, t, h * p), last
+
+  def kernel(*xs):
+    return state_space.ssd_scan(*xs, g, n, chunk_size=chunk,
+                                matmul_dtype=jnp.bfloat16)
+
+  def arm(scan):
+    def run(*xs):
+      out, vjp = jax.vjp(scan, *xs)
+      return out + vjp(cotangents)
+    return jax.jit(run)
+
+  names = ("y", "state", "mixed", "dt", "a_log", "d")
+  want = [np.asarray(v, np.float32) for v in arm(xla)(*args)]
+  got = [np.asarray(v, np.float32) for v in arm(kernel)(*args)]
+  parts = dict(zip(names, zip(got, want)))
+  cotangent = parts.pop("mixed")
+  for name, cols in (("x", slice(0, x_end)), ("b", slice(x_end, b_end)),
+                     ("c", slice(b_end, None))):
+    parts[name] = tuple(v[..., cols] for v in cotangent)
+  errors = {k: float(np.max(np.abs(x - w)) / np.max(np.abs(w)))
+            for k, (x, w) in parts.items()}
+  _check(all(np.isfinite(e) and e <= SCAN_KERNEL_TOL
+             for e in errors.values()),
+         f"ssd_scan and autodiff of ssd_chunked disagree beyond "
+         f"{SCAN_KERNEL_TOL} of the largest entry: {errors}")
+  text = arm(kernel).lower(*args).compile().as_text()
+  return {"length": t, "relative_error": errors,
+          "tolerance": SCAN_KERNEL_TOL,
+          "mosaic": "%ssd_scan." in text and "%ssd_scan_bwd." in text
+                    and "tpu_custom_call" in text}
 
 
 def phase_grouped_matmul(out_dir: str, extra_bindings=(), device=("tpu", 1)

@@ -43,8 +43,10 @@ repository's layouts:
 * `Mamba2Mixer`: one projection to [z | x, B, C | dt], a causal depthwise
   convolution with a bias and SiLU over [x, B, C] (`ops/short_conv`),
   dt = softplus(dt + dt_bias), the state-space scan per head
-  (`ops/state_space`), the result times SiLU(z) and then RMSNorm over
-  each of `n_groups` groups of channels, the output projection.
+  (`ops/state_space.ssd_scan`, which reads x, B and C from the
+  convolution's result in place), the result times SiLU(z) and then
+  RMSNorm over each of `n_groups` groups of channels, the output
+  projection.
 
 Norms, gates, the mixers' states and the softmax are float32; projections
 and products take `dtype`.
@@ -332,15 +334,12 @@ class Mamba2Mixer(nn.Module):
     z = zxbcdt[..., :inner].astype(jnp.float32)
     dt = jax.nn.softplus(zxbcdt[..., inner + conv_dim:].astype(jnp.float32)
                          + dt_bias.astype(jnp.float32))
-    split = inner + groups * state
     with jax.named_scope("ssm_scan"):
-      y, _ = state_space.ssd_chunked(
-          mixed[..., :inner].reshape(b, t, heads, p), dt, a_log,
-          mixed[..., inner:split].reshape(b, t, groups, state),
-          mixed[..., split:].reshape(b, t, groups, state), skip,
-          chunk_size=cfg.chunk_size, matmul_dtype=self.dtype)
+      y, _ = state_space.ssd_scan(
+          mixed, dt, a_log, skip, groups, state, chunk_size=cfg.chunk_size,
+          matmul_dtype=self.dtype, interpret=cfg.flash_interpret)
     # The gate first, then the norm over each group of inner / groups.
-    y = (y.reshape(b, t, inner) * jax.nn.silu(z)).reshape(b, t, groups, -1)
+    y = (y * jax.nn.silu(z)).reshape(b, t, groups, -1)
     y = _rms(y, cfg.norm_eps).reshape(b, t, inner) * norm_weight.astype(
         jnp.float32)
     return _dense(cfg.hidden_size, self.dtype, "out_proj")(

@@ -42,14 +42,17 @@ TINY_HYBRID = (
     "HybridDecoderLM.device_type = 'cpu'",
     "DefaultRandomInputGenerator.batch_size = 2",
 )
+# The smallest sizes the scan's kernels take (heads of 64 two to a
+# 128-lane block, N 128, chunks of 128): the phase's kernel comparison runs
+# them interpreted at T 512; its recurrence check runs T / 8 = 64 tokens.
 TINY_MAMBA = (
     "HybridDecoderLM.sequence_length = 512",
     "HybridDecoderLM.mamba_num_heads = 4",
-    "HybridDecoderLM.mamba_head_dim = 16",
+    "HybridDecoderLM.mamba_head_dim = 64",
     "HybridDecoderLM.n_groups = 2",
-    "HybridDecoderLM.ssm_state_size = 16",
-    "HybridDecoderLM.chunk_size = 24",
-    "DefaultRandomInputGenerator.batch_size = 2",
+    "HybridDecoderLM.ssm_state_size = 128",
+    "HybridDecoderLM.chunk_size = 128",
+    "DefaultRandomInputGenerator.batch_size = 1",
 )
 TINY_EXPERTS = (
     "HybridDecoderLM.sequence_length = 128",
@@ -147,16 +150,22 @@ class TestPhaseRehearsal:
 
   def test_state_space_phase(self, out_dir):
     result = chip_smoke.phase_state_space(out_dir, TINY_MAMBA, device=CPU8)
-    # 64 tokens in chunks of 24: the last chunk is padded
-    assert result["shape"] == {"batch": 2, "length": 64, "heads": 4,
-                               "head_dim": 16, "groups": 2, "state": 16,
-                               "chunk": 24}
+    # 64 tokens in a chunk of 128: the chunk is padded
+    assert result["shape"] == {"batch": 1, "length": 64, "heads": 4,
+                               "head_dim": 64, "groups": 2, "state": 128,
+                               "chunk": 128}
     for kind, errors in result["relative_error"].items():
       assert set(errors) == {"values", "dx", "ddt", "db", "dc"}
       assert max(errors.values()) <= result["tolerance"][kind]
     # the bfloat16 arm rounds its operands: it is not the float32 arm again
     assert (result["relative_error"]["bfloat16"]["values"]
             > 10 * result["relative_error"]["float32"]["values"])
+    # the kernels (interpreted) against the XLA form, every cotangent
+    kernels = result["kernels"]
+    assert kernels["length"] == 512
+    assert set(kernels["relative_error"]) == {
+        "y", "state", "x", "b", "c", "dt", "a_log", "d"}
+    assert max(kernels["relative_error"].values()) <= kernels["tolerance"]
 
   def test_grouped_matmul_phase(self, out_dir):
     result = chip_smoke.phase_grouped_matmul(out_dir, TINY_EXPERTS,

@@ -40,6 +40,7 @@ from tensor2robot_tpu.ops import attention
 from tensor2robot_tpu.ops import grouped_matmul
 from tensor2robot_tpu.ops import linear_attention
 from tensor2robot_tpu.ops import short_conv
+from tensor2robot_tpu.ops import state_space
 
 _REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 _PACKAGE = os.path.join(_REPO_ROOT, "tensor2robot_tpu")
@@ -385,14 +386,14 @@ _CELL_CONV = [((1, 4096, 12288), 0, 8192, False),
               ((1, 4096, 10304), 4096, 6144, True)]
 
 
-def _conv_calls(text: str) -> list:
+def _named_calls(text: str, prefix: str) -> list:
   """(kernel, the instruction's line) for each custom call of a compiled
-  program that runs `ops/short_conv.py`'s kernels (XLA names the
-  instruction after the kernel: `%short_conv.6`, `%short_conv_bwd.3`)."""
+  program whose kernel's name starts with `prefix` (XLA names the
+  instruction after the kernel: `%short_conv.6`, `%ssd_scan_bwd.3`)."""
   calls = []
   for line in text.splitlines():
     name = line.split(" = ")[0].strip().lstrip("ROOT ").lstrip("%")
-    if "custom-call(" in line and name.startswith("short_conv"):
+    if "custom-call(" in line and name.startswith(prefix):
       calls.append((name.rsplit(".", 1)[0], line))
   return calls
 
@@ -448,7 +449,7 @@ class TestShortConvMosaicLowering:
         spec(shape), spec((4, channels)), spec((channels,)) if bias else None,
         spec(shape[:2] + (channels,))).compile()
     text = compiled.as_text()
-    calls = _conv_calls(text)
+    calls = _named_calls(text, "short_conv")
     assert sorted(kernel for kernel, _ in calls) == [
         "short_conv", "short_conv_bwd"]
     whole = "bf16[" + ",".join(str(d) for d in shape) + "]"
@@ -456,6 +457,56 @@ class TestShortConvMosaicLowering:
         whole, whole]
     assert {info.dtype for info in jax.tree_util.tree_leaves(
         compiled.out_info)} == {jnp.dtype(jnp.bfloat16)}
+
+
+class TestStateSpaceMosaicLowering:
+  """`ops/state_space.py`: the Mosaic lowering, and the chip's compiler on
+  the forward and backward kernels at the nemotron cell's shape."""
+
+  def test_default_interpret_lowers_mosaic_for_tpu(self, tpu_lowering):
+    """interpret=None takes the kernels per lowering platform; one forward
+    (the one that keeps the states) and one backward a gradient."""
+    shapes = (jax.ShapeDtypeStruct((1, 256, 2 * 64 + 2 * 128), jnp.bfloat16),
+              jax.ShapeDtypeStruct((1, 256, 2), jnp.float32),
+              jax.ShapeDtypeStruct((2,), jnp.float32),
+              jax.ShapeDtypeStruct((2,), jnp.float32))
+    module = _export_for_tpu(
+        jax.grad(lambda *a: state_space.ssd_scan(
+            *a, 1, 128, matmul_dtype=jnp.bfloat16)[0].sum(),
+                 argnums=(0, 1, 2, 3)), *shapes).mlir_module()
+    assert module.count('kernel_name = "ssd_scan"') == 1
+    assert module.count('kernel_name = "ssd_scan_bwd"') == 1
+
+  def test_forward_and_backward_compile_for_v5e(self, one_chip):
+    """T 4096, 64 heads of 64, 8 groups, N 128, chunks of 128: one call of
+    each kernel, both reading the convolution's whole result in place, the
+    cotangent of that result in its dtype and dt's, a_log's and D's in
+    float32; no loop over chunks and nothing laid out again."""
+    spec = lambda dims, dtype: jax.ShapeDtypeStruct(  # noqa: E731
+        dims, dtype, sharding=one_chip)
+    mixed = (1, 4096, 6144)
+
+    def scan(m, dt, a_log, d, dy, dlast):
+      out, vjp = jax.vjp(lambda *a: state_space.ssd_scan(
+          *a, 8, 128, matmul_dtype=jnp.bfloat16, interpret=False),
+                         m, dt, a_log, d)
+      return out + vjp((dy, dlast))
+
+    compiled = jax.jit(scan).lower(
+        spec(mixed, jnp.bfloat16), spec((1, 4096, 64), jnp.float32),
+        spec((64,), jnp.float32), spec((64,), jnp.float32),
+        spec((1, 4096, 4096), jnp.float32),
+        spec((1, 64, 64, 128), jnp.float32)).compile()
+    text = compiled.as_text()
+    calls = _named_calls(text, "ssd_scan")
+    assert sorted(kernel for kernel, _ in calls) == [
+        "ssd_scan", "ssd_scan_bwd"]
+    assert [_first_operand_shape(text, line) for _, line in calls] == [
+        "bf16[1,4096,6144]"] * 2
+    assert " while(" not in text
+    assert _channel_copies(text, 6144) == []
+    assert [info.dtype for info in compiled.out_info[2:]] == [
+        jnp.dtype(jnp.bfloat16)] + [jnp.dtype(jnp.float32)] * 3
 
 
 class TestDecodeKernelMosaicLowering:
@@ -638,7 +689,7 @@ def _assert_convs_in_place(text, layers, operand, channels, scope):
   beside it)."""
   from tensor2robot_tpu.obs import xray
 
-  calls = _conv_calls(text)
+  calls = _named_calls(text, "short_conv")
   assert sorted(kernel for kernel, _ in calls) == (
       ["short_conv"] * 2 * layers + ["short_conv_bwd"] * layers)
   table = xray.build_op_table(text)
@@ -649,6 +700,31 @@ def _assert_convs_in_place(text, layers, operand, channels, scope):
   whole = "bf16[" + ",".join(str(d) for d in operand) + "]"
   assert {_first_operand_shape(text, line) for _, line in calls} == {whole}
   assert _channel_copies(text, channels) == []
+
+
+def _assert_scans_in_place(text, layers, operand):
+  """The state-space scan as `ops/state_space.py`'s kernels under the
+  mixer's scope: `ssd_scan` a layer in the forward and the recomputed
+  forward, `ssd_scan_bwd` a layer in the backward, each reading the
+  convolution's whole result; no XLA loop carrying a group's
+  [8 heads, 64, 128] state over chunks, and none of the result's columns
+  laid out again."""
+  from tensor2robot_tpu.obs import xray
+
+  calls = _named_calls(text, "ssd_scan")
+  assert sorted(kernel for kernel, _ in calls) == (
+      ["ssd_scan"] * 2 * layers + ["ssd_scan_bwd"] * layers)
+  table = xray.build_op_table(text)
+  entries = [xray.op_entry(table, line) for _, line in calls]
+  assert {e["scope"] for e in entries} == {"ssm_scan"}
+  assert sorted(e["phase"] for e in entries) == sorted(
+      ["forward", "recompute", "backward"] * layers)
+  whole = "bf16[" + ",".join(str(d) for d in operand) + "]"
+  assert {_first_operand_shape(text, line) for _, line in calls} == {whole}
+  loops = [line for line in text.splitlines()
+           if " while(" in line and "f32[1,8,8,64,128]" in line]
+  assert loops == [], loops
+  assert _channel_copies(text, operand[2]) == []
 
 
 def _expert_products(text: str) -> tuple:
@@ -760,10 +836,9 @@ class TestShippedStepsCompileForV5e:
     667 M parameters under Adam): the step compiles for one v5e, state and
     temporaries under the chip's 16 GB, with the flash kernels, the
     experts' grouped products as `grouped_matmul` / `grouped_matmul_t` (none
-    of XLA's left), one sort an expert layer, and the
-    state-space scan's loops over chunks carrying one float32
-    [8 groups, 8 heads, 64, 128] state (four layers, forward, recomputed
-    forward and backward); the short convolution as `short_conv` /
+    of XLA's left), one sort an expert layer, the state-space scan as
+    `ssd_scan` / `ssd_scan_bwd` reading the convolution's result in place
+    (no loop over chunks left); the short convolution as `short_conv` /
     `short_conv_bwd` in place."""
     model, batch = _model_from_config(
         "configs/train_nemotron3nano_ep16share.gin")
@@ -776,11 +851,9 @@ class TestShippedStepsCompileForV5e:
     text = compiled.as_text()
     assert "flash_fwd" in text and "flash_bwd" in text
     assert _expert_products(text) == (16, 8, 8, 0) and " sort(" in text
-    scans = [line for line in text.splitlines()
-             if " while(" in line and "f32[1,8,8,64,128]" in line]
-    assert len(scans) >= 12, len(scans)
     assert "gdn_inverse" not in text
     _assert_convs_in_place(text, 4, (1, 4096, 10304), 6144, "ssm_conv")
+    _assert_scans_in_place(text, 4, (1, 4096, 6144))
 
   @pytest.mark.parametrize("config_file,traffic,layers,mixers", [
       ("configs/train_qwen3next_ep16share.gin", "pool_b1_T4096", 4, 3),
@@ -804,7 +877,8 @@ class TestShippedStepsCompileForV5e:
     assert "ragged_dot" not in lowered.as_text()
     text = lowered.compile().as_text()
     assert _expert_products(text) == (4 * layers, 2 * layers, 2 * layers, 0)
-    assert sorted(kernel for kernel, _ in _conv_calls(text)) == (
+    calls = _named_calls(text, "short_conv")
+    assert sorted(kernel for kernel, _ in calls) == (
         ["short_conv"] * 2 * mixers + ["short_conv_bwd"] * mixers)
 
   def test_tuned_grasping44_train_step_fits_one_chip(self, v5e_devices):

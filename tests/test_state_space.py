@@ -106,6 +106,119 @@ def test_padding_tokens_write_nothing_and_decay_nothing():
   _close(state, want, 2e-5)
 
 
+def _mixed(seed, b, t, h, p, g, n, dtype):
+  """The mixer's convolution output [B, T, H P + 2 G N] (x, B, C one after
+  another), dt, a_log and D."""
+  keys = jax.random.split(jax.random.PRNGKey(seed), 4)
+  mixed = jax.random.normal(keys[0], (b, t, h * p + 2 * g * n)).astype(dtype)
+  dt = jax.nn.softplus(jax.random.normal(keys[1], (b, t, h)) - 2.0)
+  a_log = jnp.log(jnp.arange(1, h + 1, dtype=jnp.float32)) - 2.0
+  return mixed, dt, a_log, jax.random.normal(keys[2], (h,))
+
+
+def _xla_form(groups, n, matmul_dtype, chunk=128):
+  """`ssd_chunked` on slices of the mixer's operand: the kernels'
+  yardstick."""
+  def scan(mixed, dt, a_log, d):
+    b, t, h = dt.shape
+    x_end = mixed.shape[2] - 2 * groups * n
+    y, last = ss.ssd_chunked(
+        mixed[..., :x_end].reshape(b, t, h, -1), dt, a_log,
+        mixed[..., x_end:x_end + groups * n].reshape(b, t, groups, n),
+        mixed[..., x_end + groups * n:].reshape(b, t, groups, n), d,
+        chunk_size=chunk, matmul_dtype=matmul_dtype)
+    return y.reshape(b, t, -1), last
+  return scan
+
+
+def _value_and_cotangents(scan, args, seed):
+  """(y, last state) and the cotangents of `args` for random ones of
+  those, in one jitted program."""
+  def run(*xs):
+    out, vjp = jax.vjp(scan, *xs)
+    keys = jax.random.split(jax.random.PRNGKey(seed), 2)
+    return out + vjp(tuple(jax.random.normal(k, v.shape)
+                           for k, v in zip(keys, out)))
+  return jax.jit(run)(*args)
+
+
+# Tolerances, relative to the largest entry. float32 operands: the same
+# terms summed in another order, some float32 ulps (2e-5); a state or a dS
+# held in bfloat16 (2^-9 of each entry) fails every one of them. bfloat16
+# operands, as the training path holds them: both sides round the same
+# products' operands, but one computed in float32 in another order (dt x,
+# its decays) can round the other way and move its term by 2^-8 of itself:
+# 2e-3 for y and the last state (4e-4 and 7e-5 read at most); D's cotangent
+# takes no product (2e-5); the other cotangents 1e-2: the cotangent of the
+# convolution's result leaves in bfloat16 (one rounding, up to 2^-7 of the
+# largest entry), and the CPU's autodiff keeps float32 cotangents as
+# operands of its transposed products where the kernels round them, as the
+# chip's default precision does.
+_FLOAT32_TOL = 2e-5
+_BFLOAT16_TOL = {"y": 2e-3, "state": 2e-3, "d": 2e-5}
+
+
+@pytest.mark.parametrize("b,t,h,groups,operand", [
+    (1, 256, 2, 1, "float32"),      # one group, two chunks
+    (2, 512, 4, 2, "float32"),      # two groups, four chunks, two sequences
+    (1, 512, 2, 1, "bfloat16"),
+    (1, 256, 4, 2, "bfloat16"),
+])
+def test_kernels_match_autodiff_of_the_xla_form(b, t, h, groups, operand):
+  """`ssd_scan`'s kernels, interpreted, against `ssd_chunked` and its
+  autodiff: y, the last state, and the cotangents of x, B, C (one operand),
+  dt, a_log and D. Heads of 64, two to a 128-lane block, N 128."""
+  p, n = 64, 128
+  dtype = jnp.dtype(operand)
+  matmul_dtype = None if operand == "float32" else jnp.bfloat16
+  args = _mixed(17, b, t, h, p, groups, n, dtype)
+  got = _value_and_cotangents(lambda *a: ss.ssd_scan(
+      *a, groups, n, matmul_dtype=matmul_dtype, interpret=True), args, 3)
+  want = _value_and_cotangents(_xla_form(groups, n, matmul_dtype), args, 3)
+  assert [v.dtype for v in got] == [v.dtype for v in want]
+  x_end, b_end = h * p, h * p + groups * n
+  names = dict(y=0, state=1, dt=3, a_log=4, d=5)
+  parts = {k: (got[i], want[i]) for k, i in names.items()}
+  for name, cols in (("x", slice(0, x_end)), ("b", slice(x_end, b_end)),
+                     ("c", slice(b_end, None))):
+    parts[name] = (got[2][..., cols].astype(jnp.float32),
+                   want[2][..., cols].astype(jnp.float32))
+  for name, (a, w) in parts.items():
+    share = _FLOAT32_TOL if operand == "float32" else _BFLOAT16_TOL.get(
+        name, 1e-2)
+    _close(a, w, share, name)
+
+
+def test_a_value_is_laid_over_its_heads_lanes_to_the_bit():
+  """`_lay`: the three bfloat16 parts of a float32 value, against a 0/1
+  matrix, give the value itself, over decays from 1e-30 to 1e30 and their
+  logarithms of either sign."""
+  r, size, p = 8, 128, 64
+  keys = jax.random.split(jax.random.PRNGKey(23), 2)
+  v_t = (jax.random.normal(keys[0], (r, size))
+         * 10.0 ** jax.random.randint(keys[1], (r, size), -30, 31))
+  head = jnp.arange(r)[:, None]
+  whose = jnp.arange(r * p)[None, :] // p == head
+  got = ss._lay(ss._parts(v_t), whose)
+  np.testing.assert_array_equal(got, jnp.repeat(v_t.T, p, axis=1))
+  column = ss._lay(ss._parts(v_t), jnp.broadcast_to(head == 3, (r, size)))
+  np.testing.assert_array_equal(column, jnp.broadcast_to(v_t[3][:, None],
+                                                         (size, size)))
+
+
+def test_other_shapes_take_the_xla_form():
+  """A state of 16 and chunks of 32 (the rehearsal's sizes) are no kernel's
+  shape: the op is `ssd_chunked` on the operand's slices, to the bit, and
+  no Pallas call is traced."""
+  args = _mixed(19, 1, 96, 4, 16, 2, 16, jnp.float32)
+  op = lambda *a: ss.ssd_scan(*a, 2, 16, chunk_size=32)  # noqa: E731
+  assert not ss._kernel_takes(96, 4, 16, 2, 16, 32)
+  assert "pallas_call" not in str(jax.make_jaxpr(op)(*args))
+  for a, w in zip(_value_and_cotangents(op, args, 5), _value_and_cotangents(
+      _xla_form(2, 16, None, chunk=32), args, 5)):
+    np.testing.assert_array_equal(a, w)
+
+
 def test_bfloat16_operands_round_the_products_only():
   """`matmul_dtype` rounds what the products read; decays, dt and the state
   stay float32, so the result stays within bfloat16's rounding of the
