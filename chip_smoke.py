@@ -113,6 +113,12 @@ SCAN_BF16_TOL = 3e-2
 # up to 2^-7 of the largest entry; CPU, interpreted: 7e-3 at most; the
 # chip at the shipped length: 6.4e-3).
 SCAN_KERNEL_TOL = 1e-2
+# `ops/linear_attention.gated_delta_rule`'s Mosaic kernels against autodiff
+# of the XLA form they replaced, both with bfloat16 operands, RELATIVE to
+# the largest entry of o, the last state and each cotangent: as
+# SCAN_KERNEL_TOL (CPU, interpreted: 7.3e-3 at most, the q columns of the
+# convolution's cotangent, which leaves in bfloat16).
+RULE_KERNEL_TOL = 1e-2
 # `ops/grouped_matmul` with the bfloat16 operands the experts hand it
 # against a float32 loop over the groups at `highest`, RELATIVE to the
 # largest entry: the forward result is a float32 sum of exact products
@@ -671,9 +677,14 @@ def phase_delta_rule(out_dir: str, extra_bindings=(), device=("tpu", 1)
                      ) -> dict:
   """Phase 4: the delta rule's triangular inverse, `gdn_inverse`, at the
   chunked layout `train_qwen3next_ep16share.gin` gives it (64 chunks x 1
-  x 32 heads of [64, 64] as shipped), the way the model takes it
+  x 32 heads of [64, 64] as shipped), the way the chunked form takes it
   (Mosaic on the TPU), against the XLA doubling product it replaced:
-  the values, and the closed-form backward against autodiff."""
+  the values, and the closed-form backward against autodiff. Then the op
+  the model runs, `gated_delta_rule` (Mosaic kernels on the TPU), against
+  autodiff of `gated_delta_rule_chunked` at the shipped length, batch and
+  heads, q, k and v read from one [B, T, 2 H_k d_k + H_v d_v] operand as
+  the mixer hands them: o, the last state and every cotangent (the q, k
+  and v columns, g, beta)."""
   del out_dir  # leaves nothing on disk
   device = _device_record(device)
   import jax
@@ -690,7 +701,10 @@ def phase_delta_rule(out_dir: str, extra_bindings=(), device=("tpu", 1)
     sizes = [config.query_parameter(name) for name in (
         "HybridDecoderLM.sequence_length",
         "DefaultRandomInputGenerator.batch_size",
-        "HybridDecoderLM.linear_num_value_heads")]
+        "HybridDecoderLM.linear_num_value_heads",
+        "HybridDecoderLM.linear_num_key_heads",
+        "HybridDecoderLM.linear_key_head_dim",
+        "HybridDecoderLM.linear_value_head_dim")]
     interpret = config.query_parameter(
         "HybridDecoderLM.device_type") != "tpu"
   finally:
@@ -729,11 +743,76 @@ def phase_delta_rule(out_dir: str, extra_bindings=(), device=("tpu", 1)
            and "tpu_custom_call" in text["kernel"]
            and "tpu_custom_call" not in text["xla"],
            "the compiled inverse holds no gdn_inverse custom call")
+  kernels = _rule_kernels_against_xla(sizes[0], sizes[1], *sizes[2:], chunk,
+                                      rng)
+  mosaic = kernels.pop("mosaic")
+  _check(device["platform"] != "tpu" or mosaic,
+         "the compiled op holds no gdn_scan / gdn_scan_bwd custom call")
   return {"phase": "delta_rule", "ok": True, "device": device,
           "config": os.path.relpath(HYBRID_GIN, ROOT), "shape": list(shape),
           "interpreted": interpret, "max_abs_error": errors,
           "max_abs_entry": largest, "tolerance": INVERSE_TOL,
-          "peak_device_bytes": _peak_device_bytes()}
+          "kernels": kernels, "peak_device_bytes": _peak_device_bytes()}
+
+
+def _rule_kernels_against_xla(t, b, v_heads, k_heads, d_k, d_v, chunk,
+                              rng) -> dict:
+  """`gated_delta_rule` against autodiff of `gated_delta_rule_chunked` on
+  one [B, T, 2 H_k d_k + H_v d_v] operand, bfloat16 operands, at the given
+  sizes; checked against RULE_KERNEL_TOL. Returns the errors relative to
+  each largest entry and whether the compiled op holds both kernels."""
+  import jax
+  import jax.numpy as jnp
+  import numpy as np
+
+  from tensor2robot_tpu.ops import linear_attention
+
+  normal = lambda *shape: jnp.asarray(  # noqa: E731
+      rng.normal(size=shape), jnp.float32)
+  key_dim = k_heads * d_k
+  args = (normal(b, t, 2 * key_dim + v_heads * d_v).astype(jnp.bfloat16),
+          # g as the decoder makes it: -A softplus(.), A from 1 to 16
+          -jnp.linspace(1.0, 16.0, v_heads) * jax.nn.softplus(
+              normal(b, t, v_heads) - 4.0),
+          jax.nn.sigmoid(normal(b, t, v_heads)))
+  cotangents = (normal(b, t, v_heads, d_v), normal(b, v_heads, d_k, d_v))
+
+  def xla(mixed, g, beta):
+    q, k = (jnp.repeat(mixed[..., i * key_dim:(i + 1) * key_dim].reshape(
+        b, t, k_heads, d_k), v_heads // k_heads, axis=2) for i in (0, 1))
+    v = mixed[..., 2 * key_dim:].reshape(b, t, v_heads, d_v)
+    return linear_attention.gated_delta_rule_chunked(
+        q, k, v, g, beta, chunk_size=chunk, matmul_dtype=jnp.bfloat16)
+
+  def kernel(*xs):
+    return linear_attention.gated_delta_rule(
+        *xs, k_heads, d_k, chunk_size=chunk, matmul_dtype=jnp.bfloat16)
+
+  def arm(rule):
+    def run(*xs):
+      out, vjp = jax.vjp(rule, *xs)
+      return out + vjp(cotangents)
+    return jax.jit(run)
+
+  names = ("o", "state", "mixed", "g", "beta")
+  want = [np.asarray(v, np.float32) for v in arm(xla)(*args)]
+  got = [np.asarray(v, np.float32) for v in arm(kernel)(*args)]
+  parts = dict(zip(names, zip(got, want)))
+  cotangent = parts.pop("mixed")
+  for i, name in enumerate("qkv"):
+    cols = slice(i * key_dim, (i + 1) * key_dim if i < 2 else None)
+    parts[name] = tuple(v[..., cols] for v in cotangent)
+  errors = {k: float(np.max(np.abs(x - w)) / np.max(np.abs(w)))
+            for k, (x, w) in parts.items()}
+  _check(all(np.isfinite(e) and e <= RULE_KERNEL_TOL
+             for e in errors.values()),
+         f"gated_delta_rule and autodiff of gated_delta_rule_chunked "
+         f"disagree beyond {RULE_KERNEL_TOL} of the largest entry: {errors}")
+  text = arm(kernel).lower(*args).compile().as_text()
+  return {"length": t, "relative_error": errors,
+          "tolerance": RULE_KERNEL_TOL,
+          "mosaic": "%gdn_scan." in text and "%gdn_scan_bwd." in text
+                    and "tpu_custom_call" in text}
 
 
 def phase_state_space(out_dir: str, extra_bindings=(), device=("tpu", 1)

@@ -285,19 +285,15 @@ class GatedDeltaNet(nn.Module):
     with jax.named_scope("gdn_conv"):
       mixed = short_conv.causal_conv_silu(
           qkvz, conv_kernel, interpret=cfg.flash_interpret)
-    q = mixed[..., :key_dim].reshape(b, t, k_heads, d_k)
-    k = mixed[..., key_dim:2 * key_dim].reshape(b, t, k_heads, d_k)
-    v = mixed[..., 2 * key_dim:].reshape(b, t, v_heads, d_v)
     z = qkvz[..., 2 * key_dim + value_dim:].reshape(b, t, v_heads, d_v)
     beta = jax.nn.sigmoid(ba[..., :v_heads].astype(jnp.float32))
     g = -jnp.exp(a_log.astype(jnp.float32)) * jax.nn.softplus(
         ba[..., v_heads:].astype(jnp.float32) + dt_bias.astype(jnp.float32))
-    # Value head h reads key head h // (v_heads / k_heads).
-    q = jnp.repeat(q, v_heads // k_heads, axis=2)
-    k = jnp.repeat(k, v_heads // k_heads, axis=2)
     with jax.named_scope("gdn_scan"):
-      o, _ = linear_attention.gated_delta_rule_chunked(
-          q, k, v, g, beta, matmul_dtype=self.dtype,
+      # q, k and v read from `mixed` in place; value head h reads key head
+      # h // (v_heads / k_heads).
+      o, _ = linear_attention.gated_delta_rule(
+          mixed, g, beta, k_heads, d_k, matmul_dtype=self.dtype,
           interpret=cfg.flash_interpret)
     o = _rms(o, cfg.rms_norm_eps) * norm_weight.astype(jnp.float32)
     o = o * jax.nn.silu(z.astype(jnp.float32))

@@ -36,9 +36,12 @@ TINY_SEQUENCE = (
     "SequenceRegressionModel.device_type = 'cpu'",
     "DefaultRandomInputGenerator.batch_size = 8",
 )
+# Two key heads of 128 for the four value heads: the smallest sizes the
+# rule's kernels take (the phase runs them interpreted at T 128).
 TINY_HYBRID = (
     "HybridDecoderLM.sequence_length = 128",
     "HybridDecoderLM.linear_num_value_heads = 4",
+    "HybridDecoderLM.linear_num_key_heads = 2",
     "HybridDecoderLM.device_type = 'cpu'",
     "DefaultRandomInputGenerator.batch_size = 2",
 )
@@ -147,6 +150,11 @@ class TestPhaseRehearsal:
     assert result["shape"] == [2, 2, 4, 64, 64] and result["interpreted"]
     for part, gap in result["max_abs_error"].items():
       assert gap <= result["tolerance"] * result["max_abs_entry"][part]
+    kernels = result["kernels"]
+    assert kernels["length"] == 128
+    assert set(kernels["relative_error"]) == {"o", "state", "q", "k", "v",
+                                              "g", "beta"}
+    assert max(kernels["relative_error"].values()) <= kernels["tolerance"]
 
   def test_state_space_phase(self, out_dir):
     result = chip_smoke.phase_state_space(out_dir, TINY_MAMBA, device=CPU8)

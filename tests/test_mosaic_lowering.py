@@ -305,6 +305,57 @@ class TestDeltaRuleInverseMosaicLowering:
     assert text.count("custom-call(") == 1   # the backward is XLA's own
 
 
+class TestDeltaRuleScanMosaicLowering:
+  """`gated_delta_rule` (ops/linear_attention.py): the Mosaic lowering, and
+  the chip's compiler on the forward and backward kernels at the hybrid
+  cell's shape."""
+
+  def test_default_interpret_lowers_mosaic_for_tpu(self, tpu_lowering):
+    """interpret=None takes the kernels per lowering platform; one forward
+    (the one that keeps the states and inverses) and one backward a
+    gradient."""
+    shapes = (jax.ShapeDtypeStruct((1, 128, 4 * 128), jnp.bfloat16),
+              jax.ShapeDtypeStruct((1, 128, 2), jnp.float32),
+              jax.ShapeDtypeStruct((1, 128, 2), jnp.float32))
+    module = _export_for_tpu(
+        jax.grad(lambda *a: linear_attention.gated_delta_rule(
+            *a, 1, 128, matmul_dtype=jnp.bfloat16)[0].sum(),
+                 argnums=(0, 1, 2)), *shapes).mlir_module()
+    assert module.count('kernel_name = "gdn_scan"') == 1
+    assert module.count('kernel_name = "gdn_scan_bwd"') == 1
+
+  def test_forward_and_backward_compile_for_v5e(self, one_chip):
+    """T 4096, 16 key heads and 32 value heads of 128, chunks of 64: one
+    call of each kernel, both reading the convolution's whole result in
+    place (no slice of q, k or v and no repeat laid out), the cotangent of
+    that result in its dtype and g's and beta's in float32; no loop over
+    chunks."""
+    spec = lambda dims, dtype: jax.ShapeDtypeStruct(  # noqa: E731
+        dims, dtype, sharding=one_chip)
+
+    def rule(m, g, beta, do, dlast):
+      out, vjp = jax.vjp(lambda *a: linear_attention.gated_delta_rule(
+          *a, 16, 128, matmul_dtype=jnp.bfloat16, interpret=False),
+                         m, g, beta)
+      return out + vjp((do, dlast))
+
+    compiled = jax.jit(rule).lower(
+        spec((1, 4096, 8192), jnp.bfloat16), spec((1, 4096, 32), jnp.float32),
+        spec((1, 4096, 32), jnp.float32),
+        spec((1, 4096, 32, 128), jnp.float32),
+        spec((1, 32, 128, 128), jnp.float32)).compile()
+    text = compiled.as_text()
+    calls = _named_calls(text, "gdn_scan")
+    assert sorted(kernel for kernel, _ in calls) == [
+        "gdn_scan", "gdn_scan_bwd"]
+    assert [_first_operand_shape(text, line) for _, line in calls] == [
+        "bf16[1,4096,8192]"] * 2
+    assert " while(" not in text and "gdn_inverse" not in text
+    assert _channel_copies(text, 2048) == []
+    assert [info.dtype for info in compiled.out_info[2:]] == [
+        jnp.dtype(jnp.bfloat16)] + [jnp.dtype(jnp.float32)] * 2
+
+
 def _decode_tick_shapes(s_sz, t, b, h, d, sharding=None):
   kw = {} if sharding is None else {"sharding": sharding}
   lane = jax.ShapeDtypeStruct((b, h, d), jnp.float32, **kw)
@@ -727,6 +778,30 @@ def _assert_scans_in_place(text, layers, operand):
   assert _channel_copies(text, operand[2]) == []
 
 
+def _assert_rules_in_place(text, layers, operand):
+  """The gated delta rule as `ops/linear_attention.py`'s kernels under the
+  mixer's scope: `gdn_scan` a layer in the forward and the recomputed
+  forward, `gdn_scan_bwd` a layer in the backward, each reading the
+  convolution's whole result; no XLA loop carrying the [32, 128, 128]
+  state over chunks, and none of the result's columns laid out again."""
+  from tensor2robot_tpu.obs import xray
+
+  calls = _named_calls(text, "gdn_scan")
+  assert sorted(kernel for kernel, _ in calls) == (
+      ["gdn_scan"] * 2 * layers + ["gdn_scan_bwd"] * layers)
+  table = xray.build_op_table(text)
+  entries = [xray.op_entry(table, line) for _, line in calls]
+  assert {e["scope"] for e in entries} == {"gdn_scan"}
+  assert sorted(e["phase"] for e in entries) == sorted(
+      ["forward", "recompute", "backward"] * layers)
+  whole = "bf16[" + ",".join(str(d) for d in operand) + "]"
+  assert {_first_operand_shape(text, line) for _, line in calls} == {whole}
+  loops = [line for line in text.splitlines()
+           if " while(" in line and "f32[1,32,128,128]" in line]
+  assert loops == [], loops
+  assert _channel_copies(text, operand[2]) == []
+
+
 def _expert_products(text: str) -> tuple:
   """(forward products, rows' cotangents, weights' cotangents, XLA's own
   grouped products left) in a compiled step: four expert layers x up and
@@ -802,10 +877,11 @@ class TestShippedStepsCompileForV5e:
     parameters under Adam): the step compiles for one v5e, state and
     temporaries under the chip's 16 GB, with the flash kernels, the
     experts' grouped products as `grouped_matmul` / `grouped_matmul_t` (none
-    of XLA's left) and one sort a layer in it, and the
-    delta rule's inverse as `gdn_inverse` in the chunked layout, forward
-    and recomputed forward, with two products for its backward; the
-    short convolution as `short_conv` / `short_conv_bwd` in place."""
+    of XLA's left) and one sort a layer in it, the delta rule as
+    `gdn_scan` / `gdn_scan_bwd` reading the convolution's result in place
+    (no loop over chunks, no `gdn_inverse`, no product of the chunked
+    layout left); the short convolution as `short_conv` / `short_conv_bwd`
+    in place."""
     model, batch = _model_from_config(
         "configs/train_qwen3next_ep16share.gin")
     compiled = _lower_step_for_mesh(
@@ -817,18 +893,18 @@ class TestShippedStepsCompileForV5e:
     assert "flash_fwd" in text and "flash_bwd" in text
     assert _expert_products(text) == (16, 8, 8, 0) and " sort(" in text
     lines = text.splitlines()
-    inverses = [line for line in lines
-                if "custom-call(" in line and "gdn_inverse" in line]
-    assert len(inverses) == 6          # 3 delta-rule layers x 2 forwards
-    assert all(_keeps_the_chunked_layout(line, _CELL_INVERSE)
-               for line in inverses)
+    assert not any("custom-call(" in line and "gdn_inverse" in line
+                   for line in lines)
     # `highest` products with a [.., 64, 64] float32 result: 40 a layer
-    # before PR 34 (10 + 10 recomputed + autodiff's 20), 120 in the step.
+    # with the inverse in XLA (10 + 10 recomputed + autodiff's 20), 120 in
+    # the step; 6 with `gdn_inverse` (its closed-form backward); none with
+    # the rule as one op.
     products = [line for line in lines if "highest" in line
                 and re.search(r"= f32\[[\d,]*64,64\]", line)]
-    assert len(products) <= 6, len(products)
+    assert products == [], products
     assert memory.temp_size_in_bytes <= 5.30e9   # 5.30 GB before PR 34
     _assert_convs_in_place(text, 3, (1, 4096, 12288), 8192, "gdn_conv")
+    _assert_rules_in_place(text, 3, (1, 4096, 8192))
 
   def test_mamba_experts_decoder_train_step_fits_one_chip(self,
                                                           v5e_devices):
