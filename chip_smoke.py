@@ -61,9 +61,11 @@ HYBRID_GIN = os.path.join(PACKAGE, "configs",
                           "train_qwen3next_ep16share.gin")
 MAMBA_GIN = os.path.join(PACKAGE, "configs",
                          "train_nemotron3nano_ep16share.gin")
+LAGUNA_GIN = os.path.join(PACKAGE, "configs",
+                          "train_laguna_xs2_ep16share.gin")
 
 ONE_CHIP_PHASES = ("train", "resume", "serve", "kernels", "delta_rule",
-                   "state_space", "grouped_matmul", "barrier")
+                   "state_space", "grouped_matmul", "window", "barrier")
 MULTICHIP_PHASES = ("multichip_dp", "multichip_sp")
 # A phase that needs what an earlier one left on disk is skipped (and
 # the run failed) when that one failed.
@@ -126,6 +128,13 @@ RULE_KERNEL_TOL = 1e-2
 # the float32 sum rounded once (2^-9 of an entry).
 GROUPED_F32_TOL = 1e-4
 GROUPED_BF16_TOL = 2.0 ** -7
+# The windowed flash kernels (`flash_window_fwd` / `flash_window_bwd`) with
+# the bfloat16 operands a sliding layer hands them, against the reference
+# `attention(..., window=W)` in float32 at `highest`, RELATIVE to the largest
+# entry of the output and of each cotangent: the kernels feed the MXU bf16
+# and accumulate in float32, and each output leaves rounded to bfloat16
+# (2^-9 of an entry), as the causal kernels do.
+WINDOW_TOL = 1e-2
 
 
 class PhaseFailed(RuntimeError):
@@ -1061,8 +1070,80 @@ BARRIER_WINDOWS = 5
 BARRIER_STEPS = 4
 
 
+def phase_window(out_dir: str, extra_bindings=(), device=("tpu", 1)) -> dict:
+  """Phase 7: the sliding-window flash kernels at the window, head width and
+  length `train_laguna_xs2_ep16share.gin` gives its sliding layers (8 of
+  their 64 heads: the reference holds a head's whole [T, T] scores), the
+  op the model calls (Mosaic on the TPU) against `attention(..., window=W)`:
+  the output and dq, dk, dv, and the compiled op holds the two windowed
+  kernels and no causal one."""
+  del out_dir  # leaves nothing on disk
+  device = _device_record(device)
+  import jax
+  import jax.numpy as jnp
+  import numpy as np
+
+  from tensor2robot_tpu.ops import attention
+  from tensor2robot_tpu.utils import config
+
+  config.clear_config()
+  try:
+    config.parse_config_files_and_bindings([LAGUNA_GIN], list(extra_bindings))
+    t, b, d, window = [config.query_parameter(name) for name in (
+        "HybridDecoderLM.sequence_length",
+        "DefaultRandomInputGenerator.batch_size",
+        "HybridDecoderLM.head_dim", "HybridDecoderLM.sliding_window")]
+  finally:
+    config.clear_config()
+  heads = 8
+  rng = np.random.default_rng(42)
+  q, k, v, do = (jnp.asarray(rng.normal(size=(b, t, heads * d)), jnp.float32)
+                 for _ in range(4))
+
+  def split(x):
+    return x.reshape(b, t, heads, d).transpose(0, 2, 1, 3)
+
+  def reference(q, k, v):
+    out = attention.attention(split(q), split(k), split(v), causal=True,
+                              window=window)
+    return out.transpose(0, 2, 1, 3).reshape(b, t, heads * d)
+
+  def kernel(q, k, v):
+    return attention.flash_attention(
+        q.astype(jnp.bfloat16), k.astype(jnp.bfloat16),
+        v.astype(jnp.bfloat16), heads, causal=True, window=window)
+
+  def arm(fn):
+    def run(q, k, v):
+      out, vjp = jax.vjp(fn, q, k, v)
+      return (out,) + vjp(do.astype(out.dtype))
+    return jax.jit(run)
+
+  with jax.default_matmul_precision("highest"):
+    want = [np.asarray(x, np.float32) for x in arm(reference)(q, k, v)]
+  got = [np.asarray(x, np.float32) for x in arm(kernel)(q, k, v)]
+  names = ("out", "dq", "dk", "dv")
+  errors = {n: float(np.max(np.abs(g - w)) / np.max(np.abs(w)))
+            for n, g, w in zip(names, got, want)}
+  _check(all(np.isfinite(e) and e <= WINDOW_TOL for e in errors.values()),
+         f"the windowed flash kernels and the band-masked reference "
+         f"disagree beyond {WINDOW_TOL} of the largest entry: {errors}")
+  text = arm(kernel).lower(q, k, v).compile().as_text()
+  mosaic = ("%flash_window_fwd." in text and "%flash_window_bwd." in text
+            and "%flash_fwd." not in text and "tpu_custom_call" in text)
+  _check(device["platform"] != "tpu" or mosaic,
+         "the compiled op holds no flash_window_fwd / flash_window_bwd "
+         "custom call, or a causal one")
+  return {"phase": "window", "ok": True, "device": device,
+          "config": os.path.relpath(LAGUNA_GIN, ROOT),
+          "shape": {"batch": b, "length": t, "heads": heads, "head_dim": d,
+                    "window": window},
+          "relative_error": errors, "tolerance": WINDOW_TOL,
+          "mosaic": mosaic, "peak_device_bytes": _peak_device_bytes()}
+
+
 def phase_barrier(out_dir: str, extra_bindings=(), device=("tpu", 1)) -> dict:
-  """Phase 7: is `jax.block_until_ready` a barrier here? The phase-1
+  """Phase 8: is `jax.block_until_ready` a barrier here? The phase-1
   train step on a resident batch, a window of steps closed by
   `block_until_ready`, against the same window closed by a host fetch
   (`utils.backend.state_barrier`), against dispatch alone."""
@@ -1259,7 +1340,7 @@ PHASES = {"train": phase_train, "resume": phase_resume,
           "serve": phase_serve, "kernels": phase_kernels,
           "delta_rule": phase_delta_rule, "state_space": phase_state_space,
           "grouped_matmul": phase_grouped_matmul,
-          "barrier": phase_barrier,
+          "window": phase_window, "barrier": phase_barrier,
           "multichip_dp": phase_multichip_dp,
           "multichip_sp": phase_multichip_sp}
 

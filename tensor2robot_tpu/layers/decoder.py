@@ -7,6 +7,9 @@ slots it has, each behind an RMSNorm and added to the residual stream:
   `mamba`      the Mamba-2 state-space mixer              `nemotron_h`
   `attention`  plain grouped-query softmax attention      `nemotron_h`
   `experts`    routed experts with a shared expert        `nemotron_h`
+  `global`     gated causal attention, then a feed-forward  `laguna`
+  `sliding`    gated attention over a window, then a        `laguna`
+               feed-forward
 
 The two-slot kinds follow the public `qwen3_next` modelling code
 (huggingface transformers, `modeling_qwen3_next.py`: `Qwen3NextRMSNorm`,
@@ -14,21 +17,33 @@ The two-slot kinds follow the public `qwen3_next` modelling code
 `Qwen3NextDecoderLayer`), the one-slot kinds the public `nemotron_h`
 modelling code (`modeling_nemotron_h.py`: `NemotronHRMSNorm`,
 `MambaRMSNormGated`, `NemotronHMamba2Mixer`, `NemotronHAttention`,
-`NemotronHMOE`, `NemotronHBlock`); each kind reads the sizes of
-`DecoderConfig` that its source's config names. Written for this
-repository's layouts:
+`NemotronHMOE`, `NemotronHBlock`), the `laguna` kinds the public config of
+poolside's Laguna-XS.2 (`layer_types`, `sliding_window`,
+`num_attention_heads_per_layer`, `rope_parameters`, `mlp_layer_types`; where
+it leaves a form open the benchmark's configuration file states the one taken,
+under `assumed`); each kind reads the sizes of `DecoderConfig` that its
+source's config names. Written for this repository's layouts:
 
 * `ZeroCentredRMSNorm`: x / sqrt(mean(x^2) + eps) * (1 + w), w from 0
   (`linear`, `full`). `RMSNorm`: x / sqrt(mean(x^2) + eps) * w, w from 1
-  (the one-slot kinds).
+  (the one-slot kinds, eps `norm_eps`; the `laguna` kinds, eps
+  `rms_norm_eps`).
 * `GatedAttention`: q with an output gate from one projection, per-head
   RMSNorm of q and k, rotary embedding on the leading `rotary_fraction` of
   each head (half-split pairing), grouped-query causal softmax attention,
-  the result times sigmoid(gate). The attention itself is
+  the result times sigmoid(gate). The `laguna` kinds take the same layer
+  with plain RMSNorms of q and k, their layer's head count
+  (`num_attention_heads_per_layer`) and their kind's rotary
+  (`rope_parameters`): YaRN on the leading half of each head on `global`
+  layers, the plain rotary on the whole head on `sliding` ones, whose
+  attention sees `sliding_window` keys a row. The attention itself is
   `ops/attention.flash_attention` on [B, T, H x D]; its kernels take as
   many key/value heads as query heads, so k and v are repeated to the
   query heads ahead of it (their gradients sum over the group): a
   departure in layout, not in mathematics.
+* `DenseMLP`: (silu(x W_gate) * (x W_up)) W_down at `intermediate_size`,
+  the feed-forward of a `laguna` layer that `mlp_layer_types` calls `dense`
+  (a `sparse` one has the routed experts).
 * `PlainAttention`: q, k, v projections, the same grouped-query causal
   attention, the output projection: no gate, no q/k norm and no positional
   embedding (the source's attention module applies none; its state-space
@@ -55,6 +70,7 @@ and products take `dtype`.
 from __future__ import annotations
 
 import dataclasses
+import math
 from typing import Any, Optional, Tuple
 
 import flax.linen as nn
@@ -68,17 +84,26 @@ from tensor2robot_tpu.ops import short_conv
 from tensor2robot_tpu.ops import state_space
 
 __all__ = ["DecoderConfig", "ZeroCentredRMSNorm", "RMSNorm", "GatedAttention",
-           "PlainAttention", "GatedDeltaNet", "Mamba2Mixer",
+           "PlainAttention", "DenseMLP", "GatedDeltaNet", "Mamba2Mixer",
            "HybridDecoderBlock", "final_norm", "has_experts", "rotary_tables",
-           "apply_partial_rotary", "matrix_init", "TWO_SLOT_KINDS",
-           "ONE_SLOT_KINDS"]
+           "yarn_rotary_tables", "apply_partial_rotary", "matrix_init",
+           "TWO_SLOT_KINDS", "ONE_SLOT_KINDS", "LAGUNA_KINDS"]
 
 INIT_STDDEV = 0.02
 TWO_SLOT_KINDS = ("linear", "full")
 ONE_SLOT_KINDS = ("mamba", "attention", "experts")
+LAGUNA_KINDS = ("global", "sliding")
+# Each `laguna` kind's entry of `rope_parameters`, and its scope.
+ROPE_ENTRY = {"global": "full_attention", "sliding": "sliding_attention"}
+_ATTENTION_SCOPE = {"full": lambda: jax.named_scope("attn_gated"),
+                    "global": lambda: jax.named_scope("attn_global"),
+                    "sliding": lambda: jax.named_scope("attn_window")}
 
 
-def has_experts(kind: str) -> bool:
+def has_experts(cfg: "DecoderConfig", layer: int) -> bool:
+  kind = cfg.layer_types[layer]
+  if kind in LAGUNA_KINDS:
+    return cfg.mlp_layer_types[layer] == "sparse"
   return kind in TWO_SLOT_KINDS or kind == "experts"
 
 
@@ -147,8 +172,32 @@ class DecoderConfig:
   n_routed_experts: int = 128       # the router's width
   moe_shared_expert_intermediate_size: int = 3712
   routed_scaling_factor: float = 2.5
+  # the `laguna` kinds (norms at `rms_norm_eps`; experts as the two-slot
+  # kinds size them, routed by sigmoid scores and scaled by
+  # `routed_scaling_factor`): a head count a layer, the window of the
+  # `sliding` layers, each layer's feed-forward (`dense` or `sparse`), the
+  # dense one's width, and each kind's rotary as
+  # ((entry, ((key, value), ...)), ...) of the config's `rope_parameters`
+  num_attention_heads_per_layer: Optional[Tuple[int, ...]] = None
+  sliding_window: Optional[int] = None
+  mlp_layer_types: Optional[Tuple[str, ...]] = None
+  intermediate_size: int = 8192
+  rope_parameters: Tuple[Tuple[str, Tuple[Tuple[str, Any], ...]], ...] = ()
   # Pallas interpreted (off the TPU) or not; None: by lowering platform.
   flash_interpret: Optional[bool] = None
+
+  def rope(self, kind: str) -> dict:
+    """The `rope_parameters` entry of a `laguna` kind."""
+    return dict(dict(self.rope_parameters)[ROPE_ENTRY[kind]])
+
+
+def freeze_rope_parameters(rope_parameters) -> tuple:
+  """A config's `rope_parameters` mapping as `DecoderConfig` holds it: its
+  per-kind entries, each a sorted tuple of items (the rest is dropped)."""
+  return tuple(sorted(
+      (entry, tuple(sorted(values.items())))
+      for entry, values in dict(rope_parameters or {}).items()
+      if isinstance(values, dict)))
 
 
 def _dense(features: int, dtype, name: str):
@@ -188,6 +237,51 @@ def rotary_tables(length: int, rotary_dim: int, theta: float):
   return jnp.cos(angles), jnp.sin(angles)
 
 
+def yarn_rotary_tables(length: int, head_dim: int, rope: dict):
+  """(cos, sin) [length, rotary_dim / 2] of YaRN, as the public
+  `_compute_yarn_parameters` of huggingface transformers computes them,
+  from one entry of a config's `rope_parameters` (`rope_theta`, `factor`,
+  `original_max_position_embeddings`, `beta_fast`, `beta_slow`,
+  `attention_factor`, `partial_rotary_factor`): on rotary_dim =
+  head_dim x partial_rotary_factor, inv_freq_extra = theta^(-2j / dim),
+  inv_freq_inter = inv_freq_extra / factor, the correction range [low, high]
+  = floor / ceil of dim ln(original / (beta 2 pi)) / (2 ln theta) at
+  beta_fast / beta_slow, clamped to [0, dim - 1], ramp = clip((j - low) /
+  (high - low), 0, 1), inv_freq = inv_freq_inter ramp + inv_freq_extra
+  (1 - ramp); cos and sin times `attention_factor`."""
+  dim = int(head_dim * rope.get("partial_rotary_factor", 1.0))
+  base, factor = float(rope["rope_theta"]), float(rope["factor"])
+  original = float(rope["original_max_position_embeddings"])
+
+  def correction(rotations):
+    return dim * math.log(original / (rotations * 2 * math.pi)) / (
+        2 * math.log(base))
+
+  low = max(math.floor(correction(float(rope["beta_fast"]))), 0)
+  high = min(math.ceil(correction(float(rope["beta_slow"]))), dim - 1)
+  if low == high:
+    high += 0.001
+  extra = base ** (-jnp.arange(0, dim, 2, dtype=jnp.float32) / dim)
+  ramp = jnp.clip((jnp.arange(dim // 2, dtype=jnp.float32) - low)
+                  / (high - low), 0.0, 1.0)
+  inv_freq = extra / factor * ramp + extra * (1.0 - ramp)
+  angles = jnp.arange(length, dtype=jnp.float32)[:, None] * inv_freq[None]
+  scale = float(rope["attention_factor"])
+  return jnp.cos(angles) * scale, jnp.sin(angles) * scale
+
+
+def laguna_rotary_tables(cfg: DecoderConfig, kind: str, length: int):
+  """The rotary of a `laguna` kind, from its `rope_parameters` entry."""
+  rope = cfg.rope(kind)
+  if rope.get("rope_type", "default") == "yarn":
+    return yarn_rotary_tables(length, cfg.head_dim, rope)
+  if rope.get("rope_type", "default") != "default":
+    raise ValueError(f"unknown rope_type {rope['rope_type']!r}")
+  return rotary_tables(
+      length, int(cfg.head_dim * rope.get("partial_rotary_factor", 1.0)),
+      rope["rope_theta"])
+
+
 def apply_partial_rotary(x, cos, sin):
   """Rotates the leading 2 x cos.shape[-1] dimensions of each head of x
   [B, T, H, D], pairing dimension i with i + half (the source's
@@ -201,45 +295,72 @@ def apply_partial_rotary(x, cos, sin):
   return out.astype(x.dtype)
 
 
-def _grouped_flash(q, k, v, cfg: DecoderConfig):
-  """Causal attention of q [B, T, heads, d] over k, v [B, T, kv_heads, d];
-  key/value head j serves query heads j x group .. (j + 1) x group - 1.
-  Returns [B, T, heads x d]."""
+def _grouped_flash(q, k, v, cfg: DecoderConfig, window=None):
+  """Causal attention of q [B, T, heads, d] over k, v [B, T, kv_heads, d],
+  over the last `window` keys a row where one is given; key/value head j
+  serves query heads j x group .. (j + 1) x group - 1. Returns
+  [B, T, heads x d]."""
   b, t, heads, d = q.shape
   group = heads // k.shape[2]
   k, v = jnp.repeat(k, group, axis=2), jnp.repeat(v, group, axis=2)
   return attention_ops.flash_attention(
       q.reshape(b, t, heads * d), k.reshape(b, t, heads * d),
       v.reshape(b, t, heads * d), heads, causal=True,
-      interpret=cfg.flash_interpret)
+      interpret=cfg.flash_interpret, window=window)
 
 
 class GatedAttention(nn.Module):
+  """Gated attention of a `full` layer, or of a `laguna` kind (`global`,
+  `sliding`) with `heads` query heads."""
+
+  config: DecoderConfig
+  dtype: Optional[Any] = None
+  kind: str = "full"
+  heads: Optional[int] = None
+
+  @nn.compact
+  def __call__(self, x):
+    cfg, kind = self.config, self.kind
+    b, t, _ = x.shape
+    heads = cfg.num_attention_heads if self.heads is None else self.heads
+    kv_heads, d = cfg.num_key_value_heads, cfg.head_dim
+    norm, window = ZeroCentredRMSNorm, None
+    if kind in LAGUNA_KINDS:
+      norm = RMSNorm
+      window = cfg.sliding_window if kind == "sliding" else None
+    with _ATTENTION_SCOPE[kind]():
+      q, gate = jnp.split(
+          _dense(2 * heads * d, self.dtype, "q_proj")(x), 2, axis=-1)
+      k = _dense(kv_heads * d, self.dtype, "k_proj")(x)
+      v = _dense(kv_heads * d, self.dtype, "v_proj")(x)
+      q = norm(cfg.rms_norm_eps, name="q_norm")(q.reshape(b, t, heads, d))
+      k = norm(cfg.rms_norm_eps, name="k_norm")(k.reshape(b, t, kv_heads, d))
+      if kind in LAGUNA_KINDS:
+        cos, sin = laguna_rotary_tables(cfg, kind, t)
+      else:
+        cos, sin = rotary_tables(t, int(d * cfg.partial_rotary_factor),
+                                 cfg.rope_theta)
+      q = apply_partial_rotary(q, cos, sin)
+      k = apply_partial_rotary(k, cos, sin)
+      out = _grouped_flash(q, k, v.reshape(b, t, kv_heads, d), cfg, window)
+      out = out * jax.nn.sigmoid(gate.astype(jnp.float32)).astype(out.dtype)
+      return _dense(cfg.hidden_size, self.dtype, "o_proj")(out)
+
+
+class DenseMLP(nn.Module):
   config: DecoderConfig
   dtype: Optional[Any] = None
 
   @nn.compact
   def __call__(self, x):
     cfg = self.config
-    b, t, _ = x.shape
-    heads, kv_heads, d = (cfg.num_attention_heads, cfg.num_key_value_heads,
-                          cfg.head_dim)
-    with jax.named_scope("attn_gated"):
-      q, gate = jnp.split(
-          _dense(2 * heads * d, self.dtype, "q_proj")(x), 2, axis=-1)
-      k = _dense(kv_heads * d, self.dtype, "k_proj")(x)
-      v = _dense(kv_heads * d, self.dtype, "v_proj")(x)
-      q = ZeroCentredRMSNorm(cfg.rms_norm_eps, name="q_norm")(
-          q.reshape(b, t, heads, d))
-      k = ZeroCentredRMSNorm(cfg.rms_norm_eps, name="k_norm")(
-          k.reshape(b, t, kv_heads, d))
-      cos, sin = rotary_tables(t, int(d * cfg.partial_rotary_factor),
-                               cfg.rope_theta)
-      q = apply_partial_rotary(q, cos, sin)
-      k = apply_partial_rotary(k, cos, sin)
-      out = _grouped_flash(q, k, v.reshape(b, t, kv_heads, d), cfg)
-      out = out * jax.nn.sigmoid(gate.astype(jnp.float32)).astype(out.dtype)
-      return _dense(cfg.hidden_size, self.dtype, "o_proj")(out)
+    with jax.named_scope("mlp_dense"):
+      width = cfg.intermediate_size
+      hidden = jax.nn.silu(_dense(width, self.dtype, "gate_proj")(x).astype(
+          jnp.float32)) * _dense(width, self.dtype, "up_proj")(x).astype(
+              jnp.float32)
+      return _dense(cfg.hidden_size, self.dtype, "down_proj")(
+          hidden.astype(x.dtype))
 
 
 class PlainAttention(nn.Module):
@@ -355,6 +476,13 @@ def _experts(cfg: DecoderConfig, kind: str, dtype):
         router_scoring="sigmoid",
         routed_scaling_factor=cfg.routed_scaling_factor,
         expert_form="relu2", shared_gate=False, **shared)
+  if kind in LAGUNA_KINDS:
+    return moe_lib.ShardedExpertsMoE(
+        num_experts=cfg.num_experts,
+        shared_width=cfg.shared_expert_intermediate_size,
+        router_scoring="sigmoid",
+        routed_scaling_factor=cfg.routed_scaling_factor,
+        expert_form="gated_silu", shared_gate=False, **shared)
   return moe_lib.ShardedExpertsMoE(
       num_experts=cfg.num_experts,
       shared_width=cfg.shared_expert_intermediate_size, **shared)
@@ -368,18 +496,24 @@ def final_norm(cfg: DecoderConfig, name: str):
   """The norm after the last layer: that of the layers' kinds."""
   if cfg.layer_types[-1] in ONE_SLOT_KINDS:
     return RMSNorm(cfg.norm_eps, name=name)
+  if cfg.layer_types[-1] in LAGUNA_KINDS:
+    return RMSNorm(cfg.rms_norm_eps, name=name)
   return ZeroCentredRMSNorm(cfg.rms_norm_eps, name=name)
 
 
 class HybridDecoderBlock(nn.Module):
-  """One layer of kind `mixer`. `linear`, `full`: x + mixer(norm(x)), then
-  x + experts(norm(x)). `mamba`, `attention`, `experts`: x + f(norm(x)), f
-  the one slot the kind names. Returns the stream and the expert layer's
-  counters (None where the layer has no experts)."""
+  """Layer `layer`, of kind `mixer`. `linear`, `full`: x + mixer(norm(x)),
+  then x + experts(norm(x)). `global`, `sliding`: x + attention(norm(x)),
+  then x + ffn(norm(x)), ffn the dense feed-forward or the experts as
+  `mlp_layer_types` gives it. `mamba`, `attention`, `experts`: x +
+  f(norm(x)), f the one slot the kind names. Returns the stream and the
+  expert layer's counters (None where the layer has no experts)."""
 
   config: DecoderConfig
-  mixer: str = "linear"   # a kind of TWO_SLOT_KINDS or ONE_SLOT_KINDS
+  # a kind of TWO_SLOT_KINDS, LAGUNA_KINDS or ONE_SLOT_KINDS
+  mixer: str = "linear"
   dtype: Optional[Any] = None
+  layer: int = 0
 
   @nn.compact
   def __call__(self, x):
@@ -387,14 +521,23 @@ class HybridDecoderBlock(nn.Module):
     if kind in TWO_SLOT_KINDS:
       norm = lambda slot: ZeroCentredRMSNorm(  # noqa: E731
           cfg.rms_norm_eps, name=f"norm_{slot}")
+    elif kind in LAGUNA_KINDS:
+      norm = lambda slot: RMSNorm(  # noqa: E731
+          cfg.rms_norm_eps, name=f"norm_{slot}")
     elif kind in ONE_SLOT_KINDS:
       norm = lambda slot: RMSNorm(cfg.norm_eps, name="norm")  # noqa: E731
     else:
       raise ValueError(f"unknown layer kind {kind!r}")
     counters = None
-    if kind in _MIXERS:
+    if kind in LAGUNA_KINDS:
+      x = x + GatedAttention(
+          cfg, self.dtype, kind, cfg.num_attention_heads_per_layer[self.layer],
+          name="mixer")(norm("mixer")(x))
+      if cfg.mlp_layer_types[self.layer] == "dense":
+        return x + DenseMLP(cfg, self.dtype, name="mlp")(norm("mlp")(x)), None
+    elif kind in _MIXERS:
       x = x + _MIXERS[kind](cfg, self.dtype, name="mixer")(norm("mixer")(x))
-    if has_experts(kind):
+    if kind in TWO_SLOT_KINDS + LAGUNA_KINDS or kind == "experts":
       y, counters = _experts(cfg, kind, self.dtype)(norm("moe")(x))
       x = x + y
     return x, counters

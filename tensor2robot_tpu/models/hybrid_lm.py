@@ -2,14 +2,17 @@
 an embedding, a stack of blocks whose kinds follow a layer-type list, the
 final RMSNorm of those kinds and an untied head onto the vocabulary held
 here. Two-slot kinds (`linear`: the gated delta rule, `full`: gated softmax
-attention, each followed by routed experts with a shared expert) and
-one-slot kinds (`mamba`: the Mamba-2 state-space mixer, `attention`: plain
-softmax attention, `experts`: sigmoid-routed relu^2 experts with a shared
-expert); `layers/decoder.py` has the equations.
+attention, each followed by routed experts with a shared expert; `global`,
+`sliding`: gated softmax attention over the whole causal past or over a
+window, each followed by a dense feed-forward or sigmoid-routed experts as
+`mlp_layer_types` says) and one-slot kinds (`mamba`: the Mamba-2 state-space
+mixer, `attention`: plain softmax attention, `experts`: sigmoid-routed relu^2
+experts with a shared expert); `layers/decoder.py` has the equations.
 
 Built for one chip's share of an expert-parallel, vocabulary-parallel
 training job (`configs/train_qwen3next_ep16share.gin`,
-`configs/train_nemotron3nano_ep16share.gin`): an expert layer holds
+`configs/train_nemotron3nano_ep16share.gin`,
+`configs/train_laguna_xs2_ep16share.gin`): an expert layer holds
 `experts_held` of its router's width, and `vocab_size` is the slice of the
 vocabulary this chip embeds and scores; token ids and targets are ids of
 the slice. Trained through `train_eval_model` like any model.
@@ -63,7 +66,7 @@ class _HybridDecoder(nn.Module):
     block_cls = nn.remat(decoder.HybridDecoderBlock)
     counters = []
     for i, kind in enumerate(cfg.layer_types):
-      x, layer_counters = block_cls(cfg, kind, self.dtype,
+      x, layer_counters = block_cls(cfg, kind, self.dtype, i,
                                     name=f"layer_{i}")(x)
       if layer_counters is not None:
         counters.append(layer_counters)
@@ -110,8 +113,9 @@ def chunked_cross_entropy(hidden, head, targets, chunk: int):
 class HybridDecoderLM(abstract_model.T2RModel):
   """[B, T] token ids -> next-token loss (TRAIN/EVAL) or logits (PREDICT)
   through a stack of hybrid decoder blocks; sizes under the names of the
-  public `qwen3_next` and `nemotron_h` configs where they have one (each
-  layer kind reads its own source's: `layers/decoder.py`)."""
+  public `qwen3_next`, `nemotron_h` and `laguna` configs where they have one
+  (each layer kind reads its own source's: `layers/decoder.py`).
+  `rope_parameters` is the `laguna` config's mapping as published."""
 
   def __init__(self,
                sequence_length: int = 128,
@@ -146,6 +150,11 @@ class HybridDecoderLM(abstract_model.T2RModel):
                n_routed_experts: int = 8,
                moe_shared_expert_intermediate_size: int = 32,
                routed_scaling_factor: float = 2.5,
+               num_attention_heads_per_layer: Optional[Sequence[int]] = None,
+               sliding_window: Optional[int] = None,
+               mlp_layer_types: Optional[Sequence[str]] = None,
+               intermediate_size: int = 128,
+               rope_parameters: Optional[dict] = None,
                loss_chunk: int = 1024,
                **kwargs):
     super().__init__(**kwargs)
@@ -175,6 +184,14 @@ class HybridDecoderLM(abstract_model.T2RModel):
         moe_shared_expert_intermediate_size=(
             moe_shared_expert_intermediate_size),
         routed_scaling_factor=routed_scaling_factor,
+        num_attention_heads_per_layer=(
+            None if num_attention_heads_per_layer is None
+            else tuple(int(h) for h in num_attention_heads_per_layer)),
+        sliding_window=None if sliding_window is None else int(sliding_window),
+        mlp_layer_types=(None if mlp_layer_types is None
+                         else tuple(mlp_layer_types)),
+        intermediate_size=int(intermediate_size),
+        rope_parameters=decoder.freeze_rope_parameters(rope_parameters),
         # The model knows its target platform (as SequenceRegressionModel).
         flash_interpret=self.device_type != "tpu")
 
@@ -211,8 +228,9 @@ class HybridDecoderLM(abstract_model.T2RModel):
                                  self._loss_chunk)
       weight = labels["weight"].astype(jnp.float32).reshape(-1)
       loss = jnp.mean(weight * jnp.mean(ce, axis=-1))
-    with_experts = [i for i, kind in enumerate(
-        self._decoder_config.layer_types) if decoder.has_experts(kind)]
+    cfg = self._decoder_config
+    with_experts = [i for i in range(len(cfg.layer_types))
+                    if decoder.has_experts(cfg, i)]
     scalars = {}
     for name in COUNTERS:
       for at, i in enumerate(with_experts):

@@ -201,6 +201,7 @@ DEVICE_SCOPES = (
     "loss", "optimizer", "ema", "metrics", "param_cast",
     "gdn_conv", "gdn_scan", "attn_gated",
     "ssm_conv", "ssm_scan", "attn_plain",
+    "attn_global", "attn_window", "mlp_dense",
     "moe_route", "moe_experts", "moe_shared",
     "lm_loss",
 )

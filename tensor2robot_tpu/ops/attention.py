@@ -44,13 +44,17 @@ def _mask_value(dtype) -> jnp.ndarray:
 
 
 def attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
-              causal: bool = False) -> jnp.ndarray:
-  """Reference softmax attention, [B, H, T, D]."""
+              causal: bool = False,
+              window: Optional[int] = None) -> jnp.ndarray:
+  """Reference softmax attention, [B, H, T, D]. `window` (causal only): query
+  i sees keys i - window + 1 .. i, `window` keys counting its own."""
   scale = 1.0 / math.sqrt(q.shape[-1])
   scores = jnp.einsum("bhqd,bhkd->bhqk", q, k) * scale
   if causal:
     tq, tk = scores.shape[-2], scores.shape[-1]
     mask = jnp.tril(jnp.ones((tq, tk), bool), k=tk - tq)
+    if window is not None:
+      mask &= ~jnp.tril(jnp.ones((tq, tk), bool), k=tk - tq - window)
     scores = jnp.where(mask, scores, _mask_value(scores.dtype))
   weights = jax.nn.softmax(scores.astype(jnp.float32), axis=-1)
   return jnp.einsum("bhqk,bhkd->bhqd", weights.astype(q.dtype), v)
@@ -244,9 +248,11 @@ def _normalize(l, o, d: int):
 
 
 def _valid_mask(q_start, k_start, q_block, k_block, causal: bool,
-                valid_len: int, padded_len: int, q_axis: int = 0):
-  """Score-entry validity: causal triangle + key/query padding. The tile
-  is [q_block, k_block], or its transpose where `q_axis` is 1."""
+                valid_len: int, padded_len: int, q_axis: int = 0,
+                window: Optional[int] = None):
+  """Score-entry validity: causal triangle, the band's lower edge where
+  there is a `window`, key/query padding. The tile is [q_block, k_block],
+  or its transpose where `q_axis` is 1."""
   if not causal and valid_len == padded_len:
     return None
   shape = (q_block, k_block) if q_axis == 0 else (k_block, q_block)
@@ -255,6 +261,8 @@ def _valid_mask(q_start, k_start, q_block, k_block, causal: bool,
   mask = jnp.ones(shape, bool)
   if causal:
     mask &= q_pos >= k_pos
+  if window is not None:
+    mask &= q_pos - k_pos < window
   if valid_len != padded_len:
     mask &= (k_pos < valid_len) & (q_pos < valid_len)
   return mask
@@ -301,25 +309,55 @@ def _only(in_head, x, other=0):
   return x if in_head is None else jnp.where(in_head, x, other)
 
 
-def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *,
-                      head_dim: int, block_k: int, causal: bool,
-                      q_block: int, valid_len: int):
-  """One (batch, lane block, q_block) program: stream K/V blocks through
-  VMEM, every head of the lane block in the one loop."""
-  tq_idx = pl.program_id(2)
-  seq_len, lanes = k_ref.shape
-  num_k_blocks = seq_len // block_k
+def _k_block_range(q_idx, q_block: int, block_k: int, num_k_blocks: int,
+                   causal: bool, window: Optional[int]):
+  """[first, end) of the key blocks that q block `q_idx` streams: every
+  block that holds an entry its rows may see, and no other."""
+  first = 0
   if causal:
     # Future blocks are fully masked: stop the stream at the diagonal.
     num_k_blocks = jnp.minimum(
         num_k_blocks,
-        ((tq_idx + 1) * q_block + block_k - 1) // block_k)
+        ((q_idx + 1) * q_block + block_k - 1) // block_k)
+  if window is not None:
+    # Blocks wholly below the band are fully masked too: start the stream
+    # at the block of the first row's earliest key.
+    first = jnp.maximum(0, (q_idx * q_block - window + 1) // block_k)
+  return first, num_k_blocks
+
+
+def _q_block_range(k_idx, k_block: int, block_q: int, num_q_blocks: int,
+                   causal: bool, window: Optional[int]):
+  """[first, end) of the query blocks whose rows see a key of k block
+  `k_idx`: the backward's loop over them."""
+  first = 0
+  if causal:
+    # Blocks strictly above the diagonal see no unmasked entries.
+    first = (k_idx * k_block) // block_q
+  if window is not None:
+    # Nor do blocks wholly past the band: the last row that sees a key of
+    # this block is its last key's + window - 1.
+    num_q_blocks = jnp.minimum(
+        num_q_blocks, (k_idx * k_block + k_block + window - 2) // block_q + 1)
+  return first, num_q_blocks
+
+
+def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *,
+                      head_dim: int, block_k: int, causal: bool,
+                      q_block: int, valid_len: int,
+                      window: Optional[int] = None):
+  """One (batch, lane block, q_block) program: stream K/V blocks through
+  VMEM, every head of the lane block in the one loop."""
+  tq_idx = pl.program_id(2)
+  seq_len, lanes = k_ref.shape
+  first_k_block, num_k_blocks = _k_block_range(
+      tq_idx, q_block, block_k, seq_len // block_k, causal, window)
   q = q_ref[:]  # [block_q, lanes]
 
   def tile(kb):
     rows = pl.ds(kb * block_k, block_k)
     mask = _valid_mask(tq_idx * q_block, kb * block_k, q_block, block_k,
-                       causal, valid_len, seq_len)
+                       causal, valid_len, seq_len, window=window)
     return k_ref[rows, :], v_ref[rows, :], mask
 
   if lanes == head_dim:
@@ -328,7 +366,8 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *,
       k_blk, v_blk, mask = tile(kb)
       return _online_block_update(q, k_blk, v_blk, *carry, mask)
 
-    m, l, o = jax.lax.fori_loop(0, num_k_blocks, body, _online_init(q))
+    m, l, o = jax.lax.fori_loop(first_k_block, num_k_blocks, body,
+                                _online_init(q))
     out, l = _normalize(l, o, head_dim)
     stats = [(m, l)]
   else:
@@ -360,7 +399,7 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *,
     out, stats = jnp.zeros((q_block, lanes), jnp.float32), []
     for group in _head_groups(lanes, head_dim):
       carries = jax.lax.fori_loop(
-          0, num_k_blocks,
+          first_k_block, num_k_blocks,
           functools.partial(
               body, group=group,
               q_heads=[_only(in_head, q) for _, in_head in group]),
@@ -402,7 +441,7 @@ def _delta(do, o):
 def _flash_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                       dq_ref, dk_ref, dv_ref, dq_acc, *, head_dim: int,
                       block_q: int, causal: bool, k_block: int,
-                      valid_len: int):
+                      valid_len: int, window: Optional[int] = None):
   """The whole backward for one k block, from one recomputation of each
   score tile: dV = P^T.dO, dK = scale * dS^T.Q, and this k block's term of
   dQ = scale * dS.K.
@@ -426,11 +465,8 @@ def _flash_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
   scale = 1.0 / math.sqrt(head_dim)
   tk_idx = pl.program_id(2)
   seq_len, lanes = q_ref.shape
-  num_q_blocks = seq_len // block_q
-  start_q = 0
-  if causal:
-    # Blocks strictly above the diagonal see no unmasked entries.
-    start_q = (tk_idx * k_block) // block_q
+  start_q, num_q_blocks = _q_block_range(
+      tk_idx, k_block, block_q, seq_len // block_q, causal, window)
 
   @pl.when(tk_idx == 0)
   def _():
@@ -441,7 +477,7 @@ def _flash_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     q_blk = q_ref[rows, :]
     do_blk = do_ref[rows, :].astype(jnp.float32)
     mask = _valid_mask(qb * block_q, tk_idx * k_block, block_q, k_block,
-                       causal, valid_len, seq_len, q_axis=1)
+                       causal, valid_len, seq_len, q_axis=1, window=window)
     new, dq = [], []
     for (h, _), k_h, v_h, (dk, dv) in zip(group, k_heads, v_heads, carries):
       st = jnp.matmul(k_h, q_blk.T,
@@ -489,7 +525,7 @@ def _block_specs(t: int, block: int, lanes: int, head_dim: int):
 
 
 def _flash_forward(q, k, v, num_heads, causal, block_q, block_k, valid_len,
-                   interpret):
+                   interpret, window=None):
   """q, k, v [B, T, H x D] -> (out [B, T, H x D], lse [B, H, T, 1])."""
   b, t, hd = q.shape
   d = hd // num_heads
@@ -497,6 +533,8 @@ def _flash_forward(q, k, v, num_heads, causal, block_q, block_k, valid_len,
   kernel = functools.partial(
       _flash_fwd_kernel, head_dim=d, block_k=block_k, causal=causal,
       q_block=block_q, valid_len=valid_len)
+  if window is not None:
+    kernel = functools.partial(kernel, window=window)
   tile, whole, column = _block_specs(t, block_q, lanes, d)
   out, lse = pl.pallas_call(
       kernel,
@@ -508,23 +546,23 @@ def _flash_forward(q, k, v, num_heads, causal, block_q, block_k, valid_len,
           jax.ShapeDtypeStruct((b, num_heads, t, 1), jnp.float32),
       ],
       interpret=interpret,
-      name="flash_fwd",
+      name="flash_fwd" if window is None else "flash_window_fwd",
   )(q, k, v)
   return out, lse
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2, 3, 4, 5))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2, 3, 4, 5, 6))
 def _flash(num_heads: int, causal: bool, block_q: int, block_k: int,
-           valid_len: int, interpret: bool, q, k, v):
+           valid_len: int, interpret: bool, window: Optional[int], q, k, v):
   out, _ = _flash_forward(q, k, v, num_heads, causal, block_q, block_k,
-                          valid_len, interpret)
+                          valid_len, interpret, window)
   return out
 
 
 def _flash_fwd(num_heads, causal, block_q, block_k, valid_len, interpret,
-               q, k, v):
+               window, q, k, v):
   out, lse = _flash_forward(q, k, v, num_heads, causal, block_q, block_k,
-                            valid_len, interpret)
+                            valid_len, interpret, window)
   return out, (q, k, v, out, lse)
 
 
@@ -550,7 +588,7 @@ def _bwd_vmem_bytes(t: int, lanes: int, head_dim: int, block_q: int,
 
 
 def _flash_bwd(num_heads, causal, block_q, block_k, valid_len, interpret,
-               residuals, g):
+               window, residuals, g):
   q, k, v, out, lse = residuals
   b, t, hd = q.shape
   d = hd // num_heads
@@ -571,6 +609,8 @@ def _flash_bwd(num_heads, causal, block_q, block_k, valid_len, interpret,
   kernel = functools.partial(
       _flash_bwd_kernel, head_dim=d, block_q=block_q, causal=causal,
       k_block=block_k, valid_len=valid_len)
+  if window is not None:
+    kernel = functools.partial(kernel, window=window)
   tile, whole, _ = _block_specs(t, block_k, lanes, d)
   rows_spec = pl.BlockSpec((None, lanes // d) + rows[2:],
                            lambda b, g, kb: (b, g, 0, 0, 0))
@@ -589,7 +629,7 @@ def _flash_bwd(num_heads, causal, block_q, block_k, valid_len, interpret,
           vmem_limit_bytes=_bwd_vmem_bytes(t, lanes, d, block_q, block_k,
                                            q.dtype.itemsize)),
       interpret=interpret,
-      name="flash_bwd",
+      name="flash_bwd" if window is None else "flash_window_bwd",
   )(q, k, v, g, lse.reshape(rows), delta)
   return dq, dk, dv
 
@@ -628,6 +668,16 @@ _MIN_BLOCK = 8
 # kernel alone), large ones hold more float32 tile in VMEM than they save.
 # At T 16384 the step is refused whatever the blocks: the forward's
 # whole-T k and v no longer fit (ROADMAP B1).
+#
+# The windowed kernels take the same blocks. Smaller ones visit less of
+# what lies outside a band (at W 512 and T 4096 the tiles visited hold
+# 2.00 x the band's entries at 512-blocks, 1.50 x at 256, 1.25 x at 128),
+# but pay a tile's fixed costs more often, and that wins: v5e, 1 x 4096 x
+# 64 heads of 128, W 512, ms a call, forward / forward and backward (the
+# op alone, wall clock over 20 calls; PERF.md section 6): 512x512
+# 1.58 / 8.08, 256x512 1.59 / 8.52, 512x256 - / 8.83, 256x256 2.02 / 9.23,
+# 128x256 2.83 / 10.50, 128x128 4.06 / 12.99; causal at the same shape and
+# 512x512 2.81 / 11.85.
 _DEFAULT_BLOCKS = (512, 512)
 
 
@@ -636,9 +686,19 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
                     causal: bool = False,
                     block_q: Optional[int] = None,
                     block_k: Optional[int] = None,
-                    interpret: Optional[bool] = None) -> jnp.ndarray:
+                    interpret: Optional[bool] = None,
+                    window: Optional[int] = None) -> jnp.ndarray:
   """Pallas flash attention over `num_heads` heads, [B, T, H x D] in and
   out. Fully differentiable (one custom backward kernel, `flash_bwd`).
+
+  `window` (causal only): query i sees keys i - window + 1 .. i, `window`
+  keys counting its own (a sliding-window layer's band). The kernels then
+  skip every tile wholly outside the band, the forward's key loop starting
+  at the band's lower edge and the backward's query loop ending at it, and
+  mask the tiles that straddle it; they are named `flash_window_fwd` and
+  `flash_window_bwd`. A
+  window that reaches the first key from every row (window >= T) is plain
+  causal attention and runs the causal kernels.
 
   The layout is the projections' own, as `nn.Dense` writes and reads it,
   and stays whole: the kernels index heads through their `BlockSpec`s
@@ -659,13 +719,19 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
   if hd % num_heads:
     raise ValueError(f"{num_heads} heads do not divide the last dimension "
                      f"of {q.shape}")
+  if window is not None:
+    if not causal or window < 1:
+      raise ValueError(f"a window ({window}) needs causal attention and at "
+                       "least one key a row")
+    if window >= k.shape[1]:
+      window = None
   block_q = _DEFAULT_BLOCKS[0] if block_q is None else block_q
   block_k = _DEFAULT_BLOCKS[1] if block_k is None else block_k
   if k.shape[1] != t:
     heads = lambda x: x.reshape(b, -1, num_heads, hd // num_heads).transpose(
         0, 2, 1, 3)
-    return attention(heads(q), heads(k), heads(v),
-                     causal=causal).transpose(0, 2, 1, 3).reshape(b, t, hd)
+    return attention(heads(q), heads(k), heads(v), causal=causal,
+                     window=window).transpose(0, 2, 1, 3).reshape(b, t, hd)
   if interpret is None:
     # lax.platform_dependent, NOT jax.default_backend(): the process
     # backend bakes the HOST platform into the trace, so AOT-lowering a
@@ -682,10 +748,12 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
         q, k, v,
         tpu=functools.partial(flash_attention, num_heads=num_heads,
                               causal=causal, block_q=block_q,
-                              block_k=block_k, interpret=False),
+                              block_k=block_k, interpret=False,
+                              window=window),
         default=functools.partial(flash_attention, num_heads=num_heads,
                                   causal=causal, block_q=block_q,
-                                  block_k=block_k, interpret=True)))
+                                  block_k=block_k, interpret=True,
+                                  window=window)))
   # Normalize blocks to powers of two in [_MIN_BLOCK, next_pow2(T)]: the
   # padding arithmetic below relies on lcm(bq, bk) == max(bq, bk), which
   # only holds for powers of two.
@@ -710,7 +778,8 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     # kernels get the same protection. Pinned by TestFlashMosaicLowering
     # test_long_context_train_graph_compiles.
     q, k, v = jax.lax.optimization_barrier((q, k, v))
-  out = _flash(num_heads, causal, eff_bq, eff_bk, t, interpret, q, k, v)
+  out = _flash(num_heads, causal, eff_bq, eff_bk, t, interpret, window, q, k,
+               v)
   if not interpret:
     out = jax.lax.optimization_barrier(out)  # see the entry barrier
   return out[:, :t] if t_pad != t else out

@@ -67,6 +67,14 @@ TINY_EXPERTS = (
     "HybridDecoderLM.expert_buffer_factor = 2.0",
     "DefaultRandomInputGenerator.batch_size = 2",
 )
+# Windowed attention at the gin's head width and window over 256 tokens:
+# blocks of 128 (the length's), so the band's lower edge lies inside tiles
+# and the loops skip the tiles below it.
+TINY_WINDOW = (
+    "HybridDecoderLM.sequence_length = 256",
+    "HybridDecoderLM.sliding_window = 40",
+    "DefaultRandomInputGenerator.batch_size = 1",
+)
 CPU8 = ("cpu", 8)
 
 
@@ -191,6 +199,16 @@ class TestPhaseRehearsal:
           <= result["tolerance"]["cotangents"]
       # the cotangents leave rounded to bfloat16: not the float32 sum again
       assert errors["drhs"] > 10 * errors["values"]
+
+  def test_window_phase(self, out_dir):
+    result = chip_smoke.phase_window(out_dir, TINY_WINDOW, device=CPU8)
+    assert result["shape"] == {"batch": 1, "length": 256, "heads": 8,
+                               "head_dim": 128, "window": 40}
+    errors = result["relative_error"]
+    assert set(errors) == {"out", "dq", "dk", "dv"}
+    assert max(errors.values()) <= result["tolerance"]
+    # bfloat16 operands against a float32 reference: not equal by accident
+    assert min(errors.values()) > 1e-4
 
   def test_barrier_phase(self, trained, out_dir):
     result = chip_smoke.phase_barrier(out_dir, TINY_CRITIC, device=CPU8)

@@ -142,6 +142,19 @@ def _flash_grads(h, **kwargs):
       argnums=(0, 1, 2))
 
 
+def _flash_calls(text: str) -> dict:
+  """Flash kernel -> its custom calls in a compiled text, by instruction
+  name (`%flash_window_fwd.3 = ...`, or `%jvp_flash_window_fwd_.1 = ...`
+  where the op is compiled alone); the metadata, which names the Python
+  functions (`_flash_fwd`), is not read."""
+  calls = {}
+  for kernel in re.findall(
+      r"%[\w.-]*?(flash_(?:window_)?(?:fwd|bwd))_*\.\d+ = [^\n]*"
+      r"custom-call\(", text):
+    calls[kernel] = calls.get(kernel, 0) + 1
+  return calls
+
+
 @pytest.mark.usefixtures("tpu_lowering")
 class TestFlashMosaicLowering:
 
@@ -249,6 +262,33 @@ class TestFlashMosaicLowering:
     text = jax.jit(_flash_grads(32, causal=True)).lower(
         shape, shape, shape).compile().as_text()
     assert "flash_fwd" in text and "flash_bwd" in text
+
+  @pytest.mark.parametrize("shape,window,bq,bk", [
+      ((1, 4, 256, 32), 40, 64, 64),     # four heads a program
+      ((1, 2, 300, 128), 17, 128, 64),   # padded; 17 divides no block
+      ((1, 2, 64, 128), 16, 64, 64)])    # sub-128 blocks
+  def test_windowed_kernels_lower(self, shape, window, bq, bk):
+    s, h = _flash_shapes(shape)
+    text = _export_for_tpu(_flash_grads(h, causal=True, window=window,
+                                        block_q=bq, block_k=bk),
+                           s, s, s).mlir_module()
+    assert text.count('kernel_name = "flash_window_fwd"') == 1
+    assert text.count('kernel_name = "flash_window_bwd"') == 1
+    assert text.count("kernel_name = ") == 2
+
+  def test_laguna_layers_flash_compiles_at_t4096(self, one_chip):
+    """The attention of `configs/train_laguna_xs2_ep16share.gin`'s layers
+    through the chip's whole compiler, forward and the one backward kernel:
+    a sliding layer's 64 heads of 128 over a window of 512 (the windowed
+    kernels alone) and a global layer's 48 heads causal."""
+    for heads, window, present in (
+        (64, 512, ("flash_window_fwd", "flash_window_bwd")),
+        (48, None, ("flash_fwd", "flash_bwd"))):
+      shape = jax.ShapeDtypeStruct((1, 4096, heads * 128), jnp.bfloat16,
+                                   sharding=one_chip)
+      text = jax.jit(_flash_grads(heads, causal=True, window=window)).lower(
+          shape, shape, shape).compile().as_text()
+      assert set(_flash_calls(text)) == set(present), heads
 
   def test_f32_inputs_lower(self):
     s, h = _flash_shapes((1, 2, 256, 64), jnp.float32)
@@ -930,6 +970,27 @@ class TestShippedStepsCompileForV5e:
     assert "gdn_inverse" not in text
     _assert_convs_in_place(text, 4, (1, 4096, 10304), 6144, "ssm_conv")
     _assert_scans_in_place(text, 4, (1, 4096, 6144))
+
+  def test_window_and_global_decoder_train_step_fits_one_chip(
+      self, v5e_devices):
+    """`configs/train_laguna_xs2_ep16share.gin` as shipped (1 x T 4096, 565 M
+    parameters under Adam): the step compiles for one v5e, state and
+    temporaries under the chip's 16 GB (6.78 + 2.13 GB, described v5e),
+    the three sliding layers on the windowed kernels and the two
+    global ones on the causal kernels (a forward, a recomputed forward and
+    a backward each), the experts' grouped products as `grouped_matmul` /
+    `grouped_matmul_t` and one sort a sparse layer."""
+    model, batch = _model_from_config(
+        "configs/train_laguna_xs2_ep16share.gin")
+    compiled = _lower_step_for_mesh(
+        model, _trainer_mesh(v5e_devices[:1]), batch, donate=True).compile()
+    memory = compiled.memory_analysis()
+    assert 6.7e9 < memory.argument_size_in_bytes < 6.9e9   # 565 M x 12 bytes
+    assert memory.temp_size_in_bytes <= 2.5e9
+    text = compiled.as_text()
+    assert _flash_calls(text) == {"flash_window_fwd": 6, "flash_window_bwd": 3,
+                                  "flash_fwd": 4, "flash_bwd": 2}
+    assert _expert_products(text) == (16, 8, 8, 0) and " sort(" in text
 
   @pytest.mark.parametrize("config_file,traffic,layers,mixers", [
       ("configs/train_qwen3next_ep16share.gin", "pool_b1_T4096", 4, 3),
