@@ -1071,12 +1071,14 @@ BARRIER_STEPS = 4
 
 
 def phase_window(out_dir: str, extra_bindings=(), device=("tpu", 1)) -> dict:
-  """Phase 7: the sliding-window flash kernels at the window, head width and
-  length `train_laguna_xs2_ep16share.gin` gives its sliding layers (8 of
-  their 64 heads: the reference holds a head's whole [T, T] scores), the
-  op the model calls (Mosaic on the TPU) against `attention(..., window=W)`:
-  the output and dq, dk, dv, and the compiled op holds the two windowed
-  kernels and no causal one."""
+  """Phase 7: the sliding-window flash kernels at the window, head width,
+  group and length `train_laguna_xs2_ep16share.gin` gives its sliding layers
+  (8 of their 64 query heads and the one key/value head those share: the
+  reference holds a head's whole [T, T] scores), the op the model calls
+  (Mosaic on the TPU) with k and v at their own head count against
+  `attention(..., window=W)` over k and v repeated to the query heads: the
+  output and dq, dk, dv, and the compiled op holds the two windowed kernels
+  and no causal one."""
   del out_dir  # leaves nothing on disk
   device = _device_record(device)
   import jax
@@ -1089,23 +1091,30 @@ def phase_window(out_dir: str, extra_bindings=(), device=("tpu", 1)) -> dict:
   config.clear_config()
   try:
     config.parse_config_files_and_bindings([LAGUNA_GIN], list(extra_bindings))
-    t, b, d, window = [config.query_parameter(name) for name in (
-        "HybridDecoderLM.sequence_length",
-        "DefaultRandomInputGenerator.batch_size",
-        "HybridDecoderLM.head_dim", "HybridDecoderLM.sliding_window")]
+    t, b, d, window, kv_heads, layer_heads, kinds = [
+        config.query_parameter(name) for name in (
+            "HybridDecoderLM.sequence_length",
+            "DefaultRandomInputGenerator.batch_size",
+            "HybridDecoderLM.head_dim", "HybridDecoderLM.sliding_window",
+            "HybridDecoderLM.num_key_value_heads",
+            "HybridDecoderLM.num_attention_heads_per_layer",
+            "HybridDecoderLM.layer_types")]
   finally:
     config.clear_config()
   heads = 8
+  group = layer_heads[list(kinds).index("sliding")] // kv_heads
   rng = np.random.default_rng(42)
-  q, k, v, do = (jnp.asarray(rng.normal(size=(b, t, heads * d)), jnp.float32)
-                 for _ in range(4))
+  q, do = (jnp.asarray(rng.normal(size=(b, t, heads * d)), jnp.float32)
+           for _ in range(2))
+  k, v = (jnp.asarray(rng.normal(size=(b, t, heads // group * d)),
+                      jnp.float32) for _ in range(2))
 
   def split(x):
-    return x.reshape(b, t, heads, d).transpose(0, 2, 1, 3)
+    return x.reshape(b, t, -1, d).transpose(0, 2, 1, 3)
 
   def reference(q, k, v):
-    out = attention.attention(split(q), split(k), split(v), causal=True,
-                              window=window)
+    k, v = (jnp.repeat(split(x), group, axis=1) for x in (k, v))
+    out = attention.attention(split(q), k, v, causal=True, window=window)
     return out.transpose(0, 2, 1, 3).reshape(b, t, heads * d)
 
   def kernel(q, k, v):
@@ -1136,7 +1145,8 @@ def phase_window(out_dir: str, extra_bindings=(), device=("tpu", 1)) -> dict:
          "custom call, or a causal one")
   return {"phase": "window", "ok": True, "device": device,
           "config": os.path.relpath(LAGUNA_GIN, ROOT),
-          "shape": {"batch": b, "length": t, "heads": heads, "head_dim": d,
+          "shape": {"batch": b, "length": t, "heads": heads,
+                    "kv_heads": heads // group, "head_dim": d,
                     "window": window},
           "relative_error": errors, "tolerance": WINDOW_TOL,
           "mosaic": mosaic, "peak_device_bytes": _peak_device_bytes()}

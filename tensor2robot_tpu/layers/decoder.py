@@ -37,10 +37,10 @@ source's config names. Written for this repository's layouts:
   (`rope_parameters`): YaRN on the leading half of each head on `global`
   layers, the plain rotary on the whole head on `sliding` ones, whose
   attention sees `sliding_window` keys a row. The attention itself is
-  `ops/attention.flash_attention` on [B, T, H x D]; its kernels take as
-  many key/value heads as query heads, so k and v are repeated to the
-  query heads ahead of it (their gradients sum over the group): a
-  departure in layout, not in mathematics.
+  `ops/attention.flash_attention` on q [B, T, H x D] and k, v [B, T,
+  H_kv x D] as the projections wrote them: its kernels read key/value head
+  h // (H / H_kv) for query head h through their index maps and sum dK and
+  dV over each group inside the backward.
 * `DenseMLP`: (silu(x W_gate) * (x W_up)) W_down at `intermediate_size`,
   the feed-forward of a `laguna` layer that `mlp_layer_types` calls `dense`
   (a `sparse` one has the routed experts).
@@ -298,15 +298,13 @@ def apply_partial_rotary(x, cos, sin):
 def _grouped_flash(q, k, v, cfg: DecoderConfig, window=None):
   """Causal attention of q [B, T, heads, d] over k, v [B, T, kv_heads, d],
   over the last `window` keys a row where one is given; key/value head j
-  serves query heads j x group .. (j + 1) x group - 1. Returns
-  [B, T, heads x d]."""
+  serves query heads j x group .. (j + 1) x group - 1. k and v go to the
+  kernels at their own head count. Returns [B, T, heads x d]."""
   b, t, heads, d = q.shape
-  group = heads // k.shape[2]
-  k, v = jnp.repeat(k, group, axis=2), jnp.repeat(v, group, axis=2)
+  kv = k.shape[2] * d
   return attention_ops.flash_attention(
-      q.reshape(b, t, heads * d), k.reshape(b, t, heads * d),
-      v.reshape(b, t, heads * d), heads, causal=True,
-      interpret=cfg.flash_interpret, window=window)
+      q.reshape(b, t, heads * d), k.reshape(b, t, kv), v.reshape(b, t, kv),
+      heads, causal=True, interpret=cfg.flash_interpret, window=window)
 
 
 class GatedAttention(nn.Module):

@@ -245,6 +245,25 @@ def _normalize(l, o, d: int):
 # Two heads in one loop body give the scheduler one head's products to
 # run under the other's passes over lanes; a slice at lane 64 costs a
 # lane shift a tile, a select on a [block, 128] operand nothing.
+#
+# Grouped-query heads. k and v may carry fewer heads than q, H_kv dividing
+# H: query head h reads key/value head h // (H / H_kv). Where a program
+# holds one head (`lane_block(H, D) == D`: heads of 128 and 256) the
+# kernels read k and v at their own width through the `BlockSpec` index
+# maps, so no repeat of them to the query heads runs ahead of the kernels
+# and no sum of their cotangents over a group runs after. The forward's
+# grid is the one above; its whole-T k and v strips take the block
+# (b, 0, h // group), the same for the consecutive heads of a group, so
+# Pallas fetches each strip once a group. The backward's grid is (B, H_kv,
+# group member, T / block_k), in order on one core: q, dO, `lse` and
+# `delta` are those of query head j x group + m, the k and v tiles those of
+# (j, k block); dQ keeps its whole-T accumulator over the k blocks, and dK
+# and dV add up in whole-T float32 strips over (member, k block), cast once
+# into their output blocks (b, 0, j) at the last member (`_sum_over_group`).
+# So dK and dV are summed over the group in float32 before their one
+# rounding. At H_kv == H, and where a program holds several heads (which
+# then see k and v repeated to the query heads by `flash_attention`), the
+# kernels are those above.
 
 
 def _valid_mask(q_start, k_start, q_block, k_block, causal: bool,
@@ -439,9 +458,10 @@ def _delta(do, o):
 
 
 def _flash_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                      dq_ref, dk_ref, dv_ref, dq_acc, *, head_dim: int,
-                      block_q: int, causal: bool, k_block: int,
-                      valid_len: int, window: Optional[int] = None):
+                      dq_ref, dk_ref, dv_ref, dq_acc, *kv_acc,
+                      head_dim: int, block_q: int, causal: bool,
+                      k_block: int, valid_len: int,
+                      window: Optional[int] = None):
   """The whole backward for one k block, from one recomputation of each
   score tile: dV = P^T.dO, dK = scale * dS^T.Q, and this k block's term of
   dQ = scale * dS.K.
@@ -461,9 +481,14 @@ def _flash_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
   lane block), zeroed at the first k block, cast into `dq_ref` (whose
   block index ignores the k block) at the last. The k-block axis must run
   in order on one core: it carries no `parallel` dimension semantics.
+
+  With grouped-query heads (`kv_acc`, the whole-T float32 strips of dK and
+  dV) the grid is (batch, key/value head, group member, k block): this
+  program's dK and dV tiles add into the strips (`_sum_over_group`).
   """
   scale = 1.0 / math.sqrt(head_dim)
-  tk_idx = pl.program_id(2)
+  k_axis = 3 if kv_acc else 2
+  tk_idx = pl.program_id(k_axis)
   seq_len, lanes = q_ref.shape
   start_q, num_q_blocks = _q_block_range(
       tk_idx, k_block, block_q, seq_len // block_q, causal, window)
@@ -506,12 +531,39 @@ def _flash_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         ((zeros, zeros),) * len(group))
     for (_, in_head), (dk_h, dv_h) in zip(group, carries):
       dk, dv = _only(in_head, dk_h, dk), _only(in_head, dv_h, dv)
-  dk_ref[:] = dk.astype(dk_ref.dtype)
-  dv_ref[:] = dv.astype(dv_ref.dtype)
+  if kv_acc:
+    _sum_over_group(pl.ds(tk_idx * k_block, k_block), (dk, dv),
+                    (dk_ref, dv_ref), kv_acc)
+  else:
+    dk_ref[:] = dk.astype(dk_ref.dtype)
+    dv_ref[:] = dv.astype(dv_ref.dtype)
 
-  @pl.when(tk_idx == pl.num_programs(2) - 1)
+  @pl.when(tk_idx == pl.num_programs(k_axis) - 1)
   def _():
     dq_ref[:] = dq_acc[...].astype(dq_ref.dtype)
+
+
+def _sum_over_group(rows, tiles, out_refs, accs):
+  """Adds one group member's dK and dV `tiles` for the key rows `rows` into
+  the whole-T float32 strips `accs`; the last member (grid axis 2) casts
+  the sum into `out_refs`, the whole-T output blocks of the key/value head,
+  which stay resident over the member and k-block axes."""
+  member, last = pl.program_id(2), pl.num_programs(2) - 1
+
+  @pl.when(member == 0)
+  def _():
+    for acc, tile in zip(accs, tiles):
+      acc[rows, :] = tile
+
+  @pl.when((member > 0) & (member < last))
+  def _():
+    for acc, tile in zip(accs, tiles):
+      acc[rows, :] += tile
+
+  @pl.when(member == last)
+  def _():
+    for out, acc, tile in zip(out_refs, accs, tiles):
+      out[rows, :] = (acc[rows, :] + tile).astype(out.dtype)
 
 
 def _block_specs(t: int, block: int, lanes: int, head_dim: int):
@@ -524,22 +576,35 @@ def _block_specs(t: int, block: int, lanes: int, head_dim: int):
                        lambda b, g, i: (b, g, i, 0)))
 
 
+def _group(q, k) -> int:
+  """Query heads a key/value head serves: H x D over H_kv x D."""
+  return q.shape[-1] // k.shape[-1]
+
+
 def _flash_forward(q, k, v, num_heads, causal, block_q, block_k, valid_len,
                    interpret, window=None):
-  """q, k, v [B, T, H x D] -> (out [B, T, H x D], lse [B, H, T, 1])."""
+  """q [B, T, H x D], k, v [B, T, H_kv x D] -> (out [B, T, H x D], lse
+  [B, H, T, 1]). At H_kv < H a program holds one head."""
   b, t, hd = q.shape
   d = hd // num_heads
   lanes = lane_block(num_heads, d)
+  group = _group(q, k)
   kernel = functools.partial(
       _flash_fwd_kernel, head_dim=d, block_k=block_k, causal=causal,
       q_block=block_q, valid_len=valid_len)
   if window is not None:
     kernel = functools.partial(kernel, window=window)
   tile, whole, column = _block_specs(t, block_q, lanes, d)
+  kv_whole = whole
+  if group > 1:
+    # Query head h reads key/value head h // group: the same strip for the
+    # heads of a group, fetched once.
+    kv_whole = pl.BlockSpec((None, t, lanes),
+                            lambda b, h, i: (b, 0, h // group))
   out, lse = pl.pallas_call(
       kernel,
       grid=(b, hd // lanes, t // block_q),
-      in_specs=[tile, whole, whole],
+      in_specs=[tile, kv_whole, kv_whole],
       out_specs=[tile, column],
       out_shape=[
           jax.ShapeDtypeStruct((b, t, hd), q.dtype),
@@ -571,10 +636,12 @@ _SCOPED_VMEM_BYTES = 16 * 2 ** 20
 
 
 def _bwd_vmem_bytes(t: int, lanes: int, head_dim: int, block_q: int,
-                    block_k: int, itemsize: int) -> int:
+                    block_k: int, itemsize: int, grouped: bool = False) -> int:
   """The backward kernel's VMEM limit, from its operands' shapes: the
   blocks it keeps resident, and on top of them the default limit, which
-  then has only the tile loop's own float32 tiles to hold."""
+  then has only the tile loop's own float32 tiles to hold. `grouped`: dK
+  and dV also stand whole-T, as two-buffer output blocks and float32
+  strips."""
   strip = t * lanes
   resident = (
       2 * 2 * strip * itemsize               # q and dO, whole T, two buffers
@@ -584,6 +651,8 @@ def _bwd_vmem_bytes(t: int, lanes: int, head_dim: int, block_q: int,
       # unit dimension is padded to 8 sublanes, the row to 128 lanes.
       + 2 * 2 * (lanes // head_dim) * (t // block_q) * 8
       * max(block_q, 128) * 4)
+  if grouped:
+    resident += 2 * (2 * itemsize + 4) * strip
   return resident + _SCOPED_VMEM_BYTES
 
 
@@ -611,23 +680,38 @@ def _flash_bwd(num_heads, causal, block_q, block_k, valid_len, interpret,
       k_block=block_k, valid_len=valid_len)
   if window is not None:
     kernel = functools.partial(kernel, window=window)
-  tile, whole, _ = _block_specs(t, block_k, lanes, d)
-  rows_spec = pl.BlockSpec((None, lanes // d) + rows[2:],
-                           lambda b, g, kb: (b, g, 0, 0, 0))
+  group = _group(q, k)
+  if group == 1:
+    tile, whole, _ = _block_specs(t, block_k, lanes, d)
+    grid = (b, hd // lanes, t // block_k)
+    q_spec, kv_in, kv_out = whole, tile, tile
+    rows_spec = pl.BlockSpec((None, lanes // d) + rows[2:],
+                             lambda b, g, kb: (b, g, 0, 0, 0))
+    scratch = [pltpu.VMEM((t, lanes), jnp.float32)]
+  else:
+    # One head a program over (B, H_kv, group member, T / block_k): q, dO,
+    # `lse` and `delta` of query head j x group + m, the k and v tiles of
+    # key/value head j, and its dK and dV whole-T, summed over the group
+    # in the float32 strips `kv_acc` of the kernel.
+    head = lambda j, m: j * group + m  # noqa: E731
+    grid = (b, k.shape[-1] // d, group, t // block_k)
+    q_spec = pl.BlockSpec((None, t, d),
+                          lambda b, j, m, kb: (b, 0, head(j, m)))
+    kv_in = pl.BlockSpec((None, block_k, d), lambda b, j, m, kb: (b, kb, j))
+    kv_out = pl.BlockSpec((None, t, d), lambda b, j, m, kb: (b, 0, j))
+    rows_spec = pl.BlockSpec((None, 1) + rows[2:],
+                             lambda b, j, m, kb: (b, head(j, m), 0, 0, 0))
+    scratch = [pltpu.VMEM((t, d), jnp.float32)] * 3
   dq, dk, dv = pl.pallas_call(
       kernel,
-      grid=(b, hd // lanes, t // block_k),
-      in_specs=[whole, tile, tile, whole, rows_spec, rows_spec],
-      out_specs=[whole, tile, tile],
-      out_shape=[
-          jax.ShapeDtypeStruct((b, t, hd), q.dtype),
-          jax.ShapeDtypeStruct((b, t, hd), k.dtype),
-          jax.ShapeDtypeStruct((b, t, hd), v.dtype),
-      ],
-      scratch_shapes=[pltpu.VMEM((t, lanes), jnp.float32)],
+      grid=grid,
+      in_specs=[q_spec, kv_in, kv_in, q_spec, rows_spec, rows_spec],
+      out_specs=[q_spec, kv_out, kv_out],
+      out_shape=[jax.ShapeDtypeStruct(x.shape, x.dtype) for x in (q, k, v)],
+      scratch_shapes=scratch,
       compiler_params=pltpu.CompilerParams(
           vmem_limit_bytes=_bwd_vmem_bytes(t, lanes, d, block_q, block_k,
-                                           q.dtype.itemsize)),
+                                           q.dtype.itemsize, group > 1)),
       interpret=interpret,
       name="flash_bwd" if window is None else "flash_window_bwd",
   )(q, k, v, g, lse.reshape(rows), delta)
@@ -691,6 +775,14 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
   """Pallas flash attention over `num_heads` heads, [B, T, H x D] in and
   out. Fully differentiable (one custom backward kernel, `flash_bwd`).
 
+  k and v are [B, T, H_kv x D], H_kv dividing H (grouped-query heads; H_kv
+  == H is plain multi-head attention): query head h reads key/value head
+  h // (H / H_kv). Where a program holds one head (D 128 and D 256) the
+  kernels read them at that width through their index maps and return dK
+  and dV there, summed over each group in float32 inside the backward;
+  elsewhere k and v are repeated to the query heads here, and autodiff sums
+  their cotangents back.
+
   `window` (causal only): query i sees keys i - window + 1 .. i, `window`
   keys counting its own (a sliding-window layer's band). The kernels then
   skip every tile wholly outside the band, the forward's key loop starting
@@ -719,6 +811,15 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
   if hd % num_heads:
     raise ValueError(f"{num_heads} heads do not divide the last dimension "
                      f"of {q.shape}")
+  d = hd // num_heads
+  if (k.shape != v.shape or k.shape[-1] % d
+      or num_heads % (k.shape[-1] // d)):
+    raise ValueError(f"k {k.shape} and v {v.shape} hold no whole number of "
+                     f"heads of {d} that divides {num_heads}")
+  group = num_heads // (k.shape[-1] // d)
+  if group > 1 and (k.shape[1] != t or lane_block(num_heads, d) != d):
+    k, v = (jnp.repeat(x.reshape(x.shape[:2] + (-1, d)), group, axis=2)
+            .reshape(x.shape[:2] + (hd,)) for x in (k, v))
   if window is not None:
     if not causal or window < 1:
       raise ValueError(f"a window ({window}) needs causal attention and at "
@@ -728,8 +829,7 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
   block_q = _DEFAULT_BLOCKS[0] if block_q is None else block_q
   block_k = _DEFAULT_BLOCKS[1] if block_k is None else block_k
   if k.shape[1] != t:
-    heads = lambda x: x.reshape(b, -1, num_heads, hd // num_heads).transpose(
-        0, 2, 1, 3)
+    heads = lambda x: x.reshape(b, -1, num_heads, d).transpose(0, 2, 1, 3)
     return attention(heads(q), heads(k), heads(v), causal=causal,
                      window=window).transpose(0, 2, 1, 3).reshape(b, t, hd)
   if interpret is None:

@@ -203,7 +203,7 @@ class TestPhaseRehearsal:
   def test_window_phase(self, out_dir):
     result = chip_smoke.phase_window(out_dir, TINY_WINDOW, device=CPU8)
     assert result["shape"] == {"batch": 1, "length": 256, "heads": 8,
-                               "head_dim": 128, "window": 40}
+                               "kv_heads": 1, "head_dim": 128, "window": 40}
     errors = result["relative_error"]
     assert set(errors) == {"out", "dq", "dk", "dv"}
     assert max(errors.values()) <= result["tolerance"]

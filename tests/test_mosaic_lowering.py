@@ -155,6 +155,37 @@ def _flash_calls(text: str) -> dict:
   return calls
 
 
+def _assert_kv_at_their_heads(text, kv_heads, groups, d, t=4096):
+  """Every flash call of a compiled program reads k and v (its second and
+  third operands) as [1, T, H_kv x d], the width the projections write, and
+  no broadcast or reduce of a [..., H_kv, group, d] shape stands beside
+  them: the kernels serve a group of query heads from one key/value head,
+  with no repeat to the query heads ahead of them in XLA and no sum of
+  their cotangents over the group after."""
+  shapes = dict(re.findall(r"^\s*(?:ROOT )?%([\w.\-]+) = (\w+\[[\d,]*\])",
+                           text, re.M))
+  calls = [line for line in text.splitlines() if "custom-call(" in line
+           and re.search(r"%[\w.-]*?flash_(?:window_)?(?:fwd|bwd)_*\.\d+ = ",
+                         line)]
+  assert calls
+  kv = f"bf16[1,{t},{kv_heads * d}]"
+  for line in calls:
+    operands = re.findall(r"%([\w.\-]+)",
+                          line.split("custom-call(")[1].split(")")[0])
+    assert [shapes[name] for name in operands[1:3]] == [kv, kv], line
+  group = "|".join(str(g) for g in groups)
+  grouped = re.compile(rf"\[(?:\d+,)*{kv_heads},(?:{group}),{d}\]")
+  repeats = []
+  for line in text.splitlines():
+    op = re.search(r"= (\w+\[[\d,]*\])\S* (?:broadcast|reduce)\(([^)]*)\)",
+                   line)
+    if op and any(grouped.search(shape) for shape in [op.group(1)] + [
+        shapes.get(name, "") for name in re.findall(r"%([\w.\-]+)",
+                                                    op.group(2))]):
+      repeats.append(line.strip())
+  assert repeats == [], repeats
+
+
 @pytest.mark.usefixtures("tpu_lowering")
 class TestFlashMosaicLowering:
 
@@ -280,15 +311,19 @@ class TestFlashMosaicLowering:
     """The attention of `configs/train_laguna_xs2_ep16share.gin`'s layers
     through the chip's whole compiler, forward and the one backward kernel:
     a sliding layer's 64 heads of 128 over a window of 512 (the windowed
-    kernels alone) and a global layer's 48 heads causal."""
+    kernels alone) and a global layer's 48 heads causal, k and v at the
+    8 key/value heads the projections write."""
     for heads, window, present in (
         (64, 512, ("flash_window_fwd", "flash_window_bwd")),
         (48, None, ("flash_fwd", "flash_bwd"))):
       shape = jax.ShapeDtypeStruct((1, 4096, heads * 128), jnp.bfloat16,
                                    sharding=one_chip)
+      kv = jax.ShapeDtypeStruct((1, 4096, 8 * 128), jnp.bfloat16,
+                                sharding=one_chip)
       text = jax.jit(_flash_grads(heads, causal=True, window=window)).lower(
-          shape, shape, shape).compile().as_text()
+          shape, kv, kv).compile().as_text()
       assert set(_flash_calls(text)) == set(present), heads
+      _assert_kv_at_their_heads(text, 8, [heads // 8], 128)
 
   def test_f32_inputs_lower(self):
     s, h = _flash_shapes((1, 2, 256, 64), jnp.float32)
@@ -943,6 +978,7 @@ class TestShippedStepsCompileForV5e:
                 and re.search(r"= f32\[[\d,]*64,64\]", line)]
     assert products == [], products
     assert memory.temp_size_in_bytes <= 5.30e9   # 5.30 GB before PR 34
+    _assert_kv_at_their_heads(text, 2, [8], 256)
     _assert_convs_in_place(text, 3, (1, 4096, 12288), 8192, "gdn_conv")
     _assert_rules_in_place(text, 3, (1, 4096, 8192))
 
@@ -968,6 +1004,7 @@ class TestShippedStepsCompileForV5e:
     assert "flash_fwd" in text and "flash_bwd" in text
     assert _expert_products(text) == (16, 8, 8, 0) and " sort(" in text
     assert "gdn_inverse" not in text
+    _assert_kv_at_their_heads(text, 2, [16], 128)
     _assert_convs_in_place(text, 4, (1, 4096, 10304), 6144, "ssm_conv")
     _assert_scans_in_place(text, 4, (1, 4096, 6144))
 
@@ -990,6 +1027,7 @@ class TestShippedStepsCompileForV5e:
     text = compiled.as_text()
     assert _flash_calls(text) == {"flash_window_fwd": 6, "flash_window_bwd": 3,
                                   "flash_fwd": 4, "flash_bwd": 2}
+    _assert_kv_at_their_heads(text, 8, [8, 6], 128)
     assert _expert_products(text) == (16, 8, 8, 0) and " sort(" in text
 
   @pytest.mark.parametrize("config_file,traffic,layers,mixers", [
